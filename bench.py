@@ -1,13 +1,16 @@
 """Benchmark runner (BASELINE.json scenarios).
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
+Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline", ...},
+which names the device it ran on ("platform", "device_kind",
+"device_count" as jax reports them).  A scenario that raises, a headline
+that does not finish, or a failed gate exits non-zero.
 Headline: the north-star C2M-1M shape at its ACTUAL size — 10K nodes /
 1M allocations (10,000 jobs x 10 task groups x count 10) through the
 FULL server spine: job register -> eval broker -> 48 concurrent
 scheduler workers -> batched device dispatch (PlacementEngine) -> plan
 queue -> batched pipelined applier -> state store.  vs_baseline compares
 against the north-star C2M rate (1M allocs / 30 s = 33,333 allocs/s on a
-v5e-8; this runs on ONE chip).
+v5e-8; this runs on whatever `jax.devices()` reports, and says so).
 
 `--smoke` runs the same shape shrunk to seconds (small world) for CI —
 tests/test_commit_pipeline.py invokes it so commit-path throughput
@@ -23,14 +26,26 @@ import sys
 import tempfile
 import threading
 import time
-
-if os.environ.get("BENCH_FORCE_CPU") == "1":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
+import traceback
 
 
 def log(*a):
     print(*a, file=sys.stderr, flush=True)
+
+
+def device_info() -> dict:
+    """The device this process runs on, as jax reports it.  Merged into
+    every JSON line so a number can never be read without its device."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
+def _on_devices() -> str:
+    d = device_info()
+    return f"on {d['device_count']} x {d['device_kind']} ({d['platform']})"
 
 
 # per-scenario plan.submit/plan.evaluate latency summaries, folded into
@@ -159,6 +174,18 @@ def _wait_allocs(store, jobs, want, timeout=300.0):
     return sum(len(store.allocs_by_job("default", j.id)) for j in jobs)
 
 
+def _require_complete(scenario: str, placed: int, want: int) -> None:
+    """A scenario that did not place exactly what it asked for has no
+    rate to report: raise, so the run exits non-zero."""
+    if placed < want:
+        raise RuntimeError(
+            f"{scenario} INCOMPLETE: {placed}/{want} allocs before the "
+            f"deadline")
+    if placed > want:
+        raise RuntimeError(
+            f"{scenario} OVER-PLACED: {placed} allocs for {want} asked")
+
+
 def bench_e2e_spine(n_nodes=1000, n_jobs=50, count=100, workers=48):
     """configs[1]: 1K nodes / 5K batch allocs, binpack, through the spine."""
     from nomad_tpu import mock
@@ -201,7 +228,7 @@ def bench_e2e_spine(n_nodes=1000, n_jobs=50, count=100, workers=48):
         f"({placed/dt:.0f} allocs/s, {n_jobs/dt:.1f} evals/s, "
         f"{workers} workers)")
     _log_plan_submit("e2e_spine")
-    assert placed == n_jobs * count, placed
+    _require_complete("e2e_spine", placed, n_jobs * count)
     return placed / dt
 
 
@@ -213,6 +240,25 @@ def _batch_job(count, cpu=100, mem=64):
     tg.tasks[0].resources.cpu = cpu
     tg.tasks[0].resources.memory_mb = mem
     tg.ephemeral_disk.size_mb = 0
+    return j
+
+
+def _c2m_job(groups=10, count=10):
+    """The C2M-1M job shape: `groups` task groups x `count`, allocs sized
+    so a 10K-node cluster holds 1M of them (30 cpu / 60 mb each)."""
+    from nomad_tpu import mock
+    j = mock.batch_job()
+    base = j.task_groups[0]
+    base.count = count
+    base.tasks[0].resources.cpu = 30
+    base.tasks[0].resources.memory_mb = 60
+    base.ephemeral_disk.size_mb = 0
+    tgs = []
+    for k in range(groups):
+        tg = base.copy() if k else base
+        tg.name = f"g{k}"
+        tgs.append(tg)
+    j.task_groups = tgs
     return j
 
 
@@ -345,6 +391,7 @@ def bench_c2m(n_nodes=10000, n_batch=96, batch_count=1000,
         log(f"C2M spine: {placed}/{want} allocs in {dt:.1f}s "
             f"({placed/dt:.0f} allocs/s)")
         _log_plan_submit("c2m")
+        _require_complete("c2m", placed, want)
         return placed / dt
     finally:
         s.stop()
@@ -357,8 +404,6 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
     north_star): 1M allocations over 100K task groups on 10K nodes,
     through the full spine.  10,000 jobs x 10 task groups x count 10;
     allocs sized so the cluster holds them (30 cpu / 60 mb each)."""
-    from nomad_tpu import mock
-
     s = _server(workers=workers)
     try:
         t0 = time.time()
@@ -367,19 +412,7 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
             f"{time.time()-t0:.1f}s")
 
         def make_job():
-            j = mock.batch_job()
-            base = j.task_groups[0]
-            base.count = group_count
-            base.tasks[0].resources.cpu = 30
-            base.tasks[0].resources.memory_mb = 60
-            base.ephemeral_disk.size_mb = 0
-            tgs = []
-            for k in range(groups_per_job):
-                tg = base.copy() if k else base
-                tg.name = f"g{k}"
-                tgs.append(tg)
-            j.task_groups = tgs
-            return j
+            return _c2m_job(groups_per_job, group_count)
 
         t0 = time.time()
         _warm_engine(s, scan_job=make_job(), bulk_job=make_job())
@@ -406,10 +439,18 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
                 placed = len(s.store._allocs) - base_allocs
                 if placed >= want:
                     break
+                failed = sum(w.stats["failed"] for w in s.workers)
+                if failed:
+                    # a kernel the device refuses fails the eval; its
+                    # allocs never appear, so waiting only burns the
+                    # deadline (the reason is in the worker's log)
+                    raise RuntimeError(
+                        f"{scenario}: {failed} eval(s) FAILED after "
+                        f"{placed}/{want} allocs")
                 time.sleep(0.2 if deadline_s < 600 else 1.0)
         dt = time.time() - t0
         log(f"{scenario} spine: {placed}/{want} allocs in {dt:.1f}s "
-            f"({placed/dt:.0f} allocs/s on one chip; "
+            f"({placed/dt:.0f} allocs/s {_on_devices()}; "
             f"{n_jobs * groups_per_job} task groups)")
         if s.applier.stats.get("coalesced"):
             log(f"{scenario} applier stats: {s.applier.stats}")
@@ -421,35 +462,32 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
             # stage attribution runs strictly AFTER the steady gate has
             # exited: the probe compiles its own kernels and moves data,
             # which must not count against the gate's purity budgets
-            try:
-                from nomad_tpu.ops.place import fill_grid_for
-                from nomad_tpu.parallel import stage_probe
-                # tentpole metric: host upload/dispatch windows for wave
-                # N+1 hidden under wave N's in-flight device windows
-                pipe_overlap = stage_probe.interval_overlap_s(
-                    list(eng.upload_windows),
-                    list(eng.device_windows))
-                # device time the commit pipeline hid under raft
-                # append + fsync: engine device-blocked windows against
-                # the applier's commit windows
-                commit_overlap = stage_probe.interval_overlap_s(
-                    list(eng.device_windows),
-                    list(s.applier.commit_windows))
-                ds = stage_probe.device_stages(
-                    eng.stats, n_nodes,
-                    fill_grid=fill_grid_for(group_count),
-                    pipeline_overlap_s=pipe_overlap,
-                    commit_overlap_s=commit_overlap,
-                    wave=eng.stats)
-                if ds is not None:
-                    _DEVICE_STAGES[scenario] = ds
-                    log(f"{scenario} device stages: dominant="
-                        f"{ds['dominant_stage']} {ds['stages_s']} "
-                        f"pipeline_overlap={ds['pipeline_overlap_s']}s "
-                        f"commit_overlap={ds['commit_overlap_s']}s "
-                        f"wave={ds.get('wave')} fused={ds['fused']}")
-            except Exception as e:  # noqa: BLE001
-                log(f"{scenario} stage probe failed: {e}")
+            from nomad_tpu.ops.place import fill_grid_for
+            from nomad_tpu.parallel import stage_probe
+            # tentpole metric: host upload/dispatch windows for wave
+            # N+1 hidden under wave N's in-flight device windows
+            pipe_overlap = stage_probe.interval_overlap_s(
+                list(eng.upload_windows),
+                list(eng.device_windows))
+            # device time the commit pipeline hid under raft
+            # append + fsync: engine device-blocked windows against
+            # the applier's commit windows
+            commit_overlap = stage_probe.interval_overlap_s(
+                list(eng.device_windows),
+                list(s.applier.commit_windows))
+            ds = stage_probe.device_stages(
+                eng.stats, n_nodes,
+                fill_grid=fill_grid_for(group_count),
+                pipeline_overlap_s=pipe_overlap,
+                commit_overlap_s=commit_overlap,
+                wave=eng.stats)
+            if ds is not None:
+                _DEVICE_STAGES[scenario] = ds
+                log(f"{scenario} device stages: dominant="
+                    f"{ds['dominant_stage']} {ds['stages_s']} "
+                    f"pipeline_overlap={ds['pipeline_overlap_s']}s "
+                    f"commit_overlap={ds['commit_overlap_s']}s "
+                    f"wave={ds.get('wave')} fused={ds['fused']}")
         _log_plan_submit(scenario)
         return placed / dt, placed, want
     finally:
@@ -742,6 +780,7 @@ def bench_scan_spread(n_nodes=10000, n_jobs=60, count=100, workers=48):
         if eng:
             log(f"scan-spread engine stats: {eng.stats}")
         _log_plan_submit("scan_spread")
+        _require_complete("scan_spread", placed, want)
         return placed / dt
     finally:
         s.stop()
@@ -783,6 +822,7 @@ def bench_device_constrained(n_nodes=10000, n_jobs=20, count=100,
         log(f"device-constrained: {placed}/{want} GPU allocs in {dt:.1f}s "
             f"({placed/dt:.0f} allocs/s)")
         _log_plan_submit("device")
+        _require_complete("device", placed, want)
         return placed / dt
     finally:
         s.stop()
@@ -822,6 +862,7 @@ def bench_preemption_heavy(n_nodes=10000, workers=48, n_service=10,
         log(f"preemption-heavy: {placed}/{want} high-prio allocs in "
             f"{dt:.1f}s ({placed/dt:.0f} allocs/s, {preempted} preempted)")
         _log_plan_submit("preemption")
+        _require_complete("preemption", placed, want)
         return placed / dt
     finally:
         s.stop()
@@ -853,7 +894,7 @@ def bench_kernel_c2m_scale():
     dt = time.time() - t0
     placed = int((res.node[:1024] >= 0).sum())
     log(f"kernel: {placed} placements over 10K nodes in {dt:.3f}s "
-        f"({placed/dt:.0f} placements/s on one chip)")
+        f"({placed/dt:.0f} placements/s {_on_devices()})")
     return placed / dt
 
 
@@ -924,7 +965,6 @@ def bench_kernel_100k_nodes(n_nodes=100_000, waves=12, per_wave=8,
                     eng.complete(ticket)
         dt = time.time() - t_run
 
-        import jax
         lat_ms = sorted(v * 1000.0 for v in lat_s)
         p50 = lat_ms[len(lat_ms) // 2]
         p99 = lat_ms[min(len(lat_ms) - 1, int(len(lat_ms) * 0.99))]
@@ -935,7 +975,7 @@ def bench_kernel_100k_nodes(n_nodes=100_000, waves=12, per_wave=8,
             "value": round(placed_total / dt, 1),
             "unit": "allocs/s",
             "n_nodes": n_nodes, "padded_rows": int(N),
-            "devices": jax.device_count(),
+            **device_info(),
             "waves": waves, "evals_per_wave": per_wave, "count": count,
             "placed": placed_total,
             "p50_ms": round(p50, 2), "p99_ms": round(p99, 2),
@@ -946,7 +986,7 @@ def bench_kernel_100k_nodes(n_nodes=100_000, waves=12, per_wave=8,
             f.write("\n")
         log(f"kernel_100k_nodes: {placed_total} allocs in {dt:.1f}s "
             f"({placed_total/dt:.0f} allocs/s; wave p50 {p50:.0f} ms / "
-            f"p99 {p99:.0f} ms on {traj['devices']} devices)")
+            f"p99 {p99:.0f} ms {_on_devices()})")
         log(f"kernel_100k engine stats: {eng.stats}")
         return traj
     finally:
@@ -970,6 +1010,7 @@ def main():
         summary = run_matrix(FLEET_CELLS, seed=seed, log=log)
         print(json.dumps({
             "metric": "fleet_soak",
+            **device_info(),
             "seed": seed,
             "agents": int(os.environ.get("NOMAD_TPU_FLEET_AGENTS",
                                          "10000")),
@@ -1001,6 +1042,7 @@ def main():
         summary = run_matrix(cells, seed=seed, log=log)
         print(json.dumps({
             "metric": "scenario_matrix",
+            **device_info(),
             "seed": seed,
             "cells": len(summary["cells"]),
             "passed": summary["passed"],
@@ -1079,12 +1121,8 @@ def main():
             want_kernels = [("place.bulk_batch_donate", "place.bulk_batch")]
         else:
             want_kernels = [("place.bulk_batch",)]
-        try:
-            import jax
-            if jax.device_count() > 1:
-                want_kernels.append(("sharded.bulk",))
-        except Exception:   # noqa: BLE001
-            pass
+        if device_info()["device_count"] > 1:
+            want_kernels.append(("sharded.bulk",))
         for alts in want_kernels:
             if all(kernel_sizes.get(k) is None for k in alts):
                 fused_violations.append(
@@ -1101,6 +1139,7 @@ def main():
             "value": round(rate, 1),
             "unit": "allocs/s",
             "vs_baseline": round(rate / target, 4),
+            **device_info(),
             "placed": placed,
             "want": want,
             "plan_latency_ms": _PLAN_STATS,
@@ -1114,6 +1153,9 @@ def main():
                       "violations": fused_violations},
             "tracing": trace_checks,
         }), flush=True)
+        if placed < want:
+            log(f"smoke INCOMPLETE: {placed}/{want} before deadline")
+            sys.exit(1)
         if steady.get("violations"):
             log("steady-state violations:", steady["violations"])
             sys.exit(1)
@@ -1147,30 +1189,32 @@ def main():
         print(json.dumps(traj), flush=True)
         return
 
-    # headline: the REAL north-star number — C2M-1M at full size
+    # headline: the REAL north-star number — C2M-1M at full size.
+    # Every scenario runs even when an earlier one failed (an hour-long
+    # run should report all it can), but any failure is named in the
+    # JSON and makes the process exit non-zero.
+    failures = []
+
+    def run(name, fn):
+        try:
+            return fn()
+        except Exception as e:          # noqa: BLE001 — recorded, exits 1
+            log(f"scenario {name} FAILED:\n{traceback.format_exc()}")
+            failures.append(f"{name}: {type(e).__name__}: {e}")
+            return None
+
     rate = 0.0
-    try:
-        rate, placed, want = bench_c2m_1m()
+    headline = run("c2m_1m", bench_c2m_1m)
+    if headline is not None:
+        rate, placed, want = headline
         if placed < want:
-            log(f"c2m_1m INCOMPLETE: {placed}/{want} before deadline")
-    except Exception as e:          # noqa: BLE001
-        log("c2m_1m headline failed:", e)
-    try:
-        kernel_rate = bench_kernel_c2m_scale()
-    except Exception as e:          # noqa: BLE001
-        log("kernel bench failed:", e)
-        kernel_rate = 0.0
-
-    try:
-        bench_kernel_100k_nodes()
-    except Exception as e:          # noqa: BLE001
-        log("kernel_100k bench failed:", e)
-
-    serving = {}
-    try:
-        serving = bench_serving_plane()
-    except Exception as e:          # noqa: BLE001
-        log("serving_plane bench failed:", e)
+            failures.append(
+                f"c2m_1m INCOMPLETE: {placed}/{want} before deadline")
+        for v in _STEADY_STATE.get("c2m_1m", {}).get("violations", ()):
+            failures.append(f"c2m_1m steady-state gate: {v}")
+    run("kernel_c2m_scale", bench_kernel_c2m_scale)
+    run("kernel_100k_nodes", bench_kernel_100k_nodes)
+    serving = run("serving_plane", bench_serving_plane) or {}
 
     if os.environ.get("BENCH_ALL") == "1":
         # the full BASELINE.json scenario suite (tens of minutes)
@@ -1180,21 +1224,24 @@ def main():
                          ("scan_spread", bench_scan_spread),
                          ("device", bench_device_constrained),
                          ("preemption", bench_preemption_heavy)):
-            try:
-                fn()
-            except Exception as e:  # noqa: BLE001
-                log(f"scenario {name} failed: {e}")
+            run(name, fn)
 
     print(json.dumps({
         "metric": "c2m_1m_allocs_per_sec_10knodes_1mallocs",
         "value": round(rate, 1),
         "unit": "allocs/s",
         "vs_baseline": round(rate / target, 4),
+        **device_info(),
         "plan_latency_ms": _PLAN_STATS,
         "steady_state": _STEADY_STATE,
         "serving_plane": serving,
         "device_stages": _DEVICE_STAGES.get("c2m_1m"),
+        "failures": failures,
     }), flush=True)
+    if failures:
+        for f in failures:
+            log("FAILED:", f)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -57,7 +57,7 @@ ALLOW_RE = re.compile(
     r"#\s*analysis:\s*allow\(([^)]*)\)[ \t]*(?:[—:–-]+[ \t]*)?(.*)")
 
 # directories never scanned, wherever the root points
-EXCLUDED_PARTS = {"__pycache__", ".git", "build", ".scratch", ".jax_cache"}
+EXCLUDED_PARTS = {"__pycache__", ".git", "build", ".jax_cache"}
 
 
 @dataclass

@@ -200,11 +200,9 @@ KNOBS: Dict[str, Knob] = {
         "task template re-render poll interval (s)"),
     "NOMAD_TPU_JAX_CACHE": Knob(
         "1", "bool",
-        "`0` disables the persistent jax compilation cache"),
-    "NOMAD_TPU_JAX_CACHE_DIR": Knob(
-        "", "str",
-        "persistent jax compilation cache root (empty = "
-        "`<repo>/.jax_cache`)"),
+        "`0` disables the persistent jax compilation cache (it lives "
+        "where `JAX_COMPILATION_CACHE_DIR` says, else at "
+        "`<checkout>/.jax_cache`)"),
 }
 
 _FALSE_STRINGS = ("", "0", "false", "no", "off")

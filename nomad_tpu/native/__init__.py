@@ -1,7 +1,7 @@
 """Native host-runtime kernels: build + ctypes binding.
 
 Compiles native/nomad_native.cpp with g++ on first use (cached by source
-mtime under native/build/), exposing:
+content hash under native/build/), exposing:
 
   allocs_fit(capacity, used, demand) -> bool[N]
   score_fit(capacity, used, demand, spread=False) -> f32[N]
@@ -13,13 +13,18 @@ mtime under native/build/), exposing:
   expand_pairs(rows, counts, scores) -> (i32[K], f32[K])
   format_uuids(n) -> list[str]      (batch generate_uuid)
 
-Falls back to numpy implementations when no C++ toolchain is available
-(`NATIVE_AVAILABLE` tells you which path is live).
+A missing or unbuildable library is an ERROR (`NativeBuildError`, carrying
+the compiler's stderr), never a quiet switch of implementation:
+`NATIVE_AVAILABLE` is true once the library is loaded.  The numpy twins
+below stay as the oracles tests compare the C++ against, and as the
+per-call path once the circuit breaker has opened on repeated faults —
+every fault and the trip itself are logged.
 """
 from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -28,6 +33,8 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from nomad_tpu import chaos, knobs
+
+log = logging.getLogger("nomad_tpu.native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.normpath(os.path.join(_HERE, "..", "..", "native",
@@ -44,16 +51,24 @@ _i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
-def _build() -> Optional[str]:
+class NativeBuildError(RuntimeError):
+    """The native library could not be built or loaded."""
+
+
+def _build() -> str:
     """Compile the native library, cached by source *content hash* (an
     mtime check could silently prefer a stale or foreign-toolchain binary
     after a checkout).  NOMAD_TPU_NATIVE_LIB overrides with a prebuilt
-    .so (the sanitizer CI leg points this at an ASan/UBSan build)."""
+    .so (the sanitizer CI leg points this at an ASan/UBSan build).
+    Raises NativeBuildError with the compiler's output on failure."""
     override = knobs.get_str("NOMAD_TPU_NATIVE_LIB")
     if override:
-        return override if os.path.exists(override) else None
+        if not os.path.exists(override):
+            raise NativeBuildError(
+                f"NOMAD_TPU_NATIVE_LIB={override}: no such file")
+        return override
     if not os.path.exists(_SRC):
-        return None
+        raise NativeBuildError(f"native source missing: {_SRC}")
     os.makedirs(_BUILD_DIR, exist_ok=True)
     with open(_SRC, "rb") as fh:
         digest = hashlib.sha256(fh.read()).hexdigest()[:16]
@@ -64,8 +79,12 @@ def _build() -> Optional[str]:
            "-o", lib_path + ".tmp", _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return None
+    except subprocess.CalledProcessError as e:
+        raise NativeBuildError(
+            f"{' '.join(cmd)} exited {e.returncode}:\n"
+            f"{e.stderr.decode(errors='replace')}") from e
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeBuildError(f"{' '.join(cmd)}: {e}") from e
     os.replace(lib_path + ".tmp", lib_path)
     # prune superseded digests so the build dir doesn't grow unboundedly
     for name in os.listdir(_BUILD_DIR):
@@ -78,18 +97,18 @@ def _build() -> Optional[str]:
     return lib_path
 
 
-def _load() -> Optional[ctypes.CDLL]:
+def _load() -> ctypes.CDLL:
+    """The loaded library; builds it on first use.  Raises
+    NativeBuildError when it cannot be built or opened."""
     global _lib, NATIVE_AVAILABLE
     with _lock:
         if _lib is not None:
             return _lib
         path = _build()
-        if path is None:
-            return None
         try:
             lib = ctypes.CDLL(path)
-        except OSError:
-            return None
+        except OSError as e:
+            raise NativeBuildError(f"cannot load {path}: {e}") from e
         lib.nomad_native_abi_version.restype = ctypes.c_int32
         got = lib.nomad_native_abi_version()
         if got != 2:
@@ -138,9 +157,11 @@ def _load() -> Optional[ctypes.CDLL]:
 
 
 class CircuitBreaker:
-    """Trips to the Python fallback after `threshold` consecutive native
-    failures — a bad build or ABI drift fails on every call, and one trip
-    beats paying an exception (or a crash risk) per call.  `reset()`
+    """Trips to the numpy twins after `threshold` consecutive native
+    call faults — a library that faults on every call costs an exception
+    (or a crash risk) per call, and one trip beats that.  Nothing here is
+    quiet: each fault is logged with its traceback and the trip is an
+    error record; `open` stays readable for health checks.  `reset()`
     closes the circuit again (e.g. after a rebuild)."""
 
     def __init__(self, threshold: int = 3):
@@ -156,12 +177,20 @@ class CircuitBreaker:
                 self._consecutive = 0
 
     def record_failure(self) -> None:
+        """Called from the `except` block of a faulted native call."""
+        log.warning("native call faulted; this call runs its numpy twin",
+                    exc_info=True)
         with self._lock:
             self._consecutive += 1
             self.stats["failures"] += 1
-            if not self.open and self._consecutive >= self.threshold:
+            tripped = not self.open and self._consecutive >= self.threshold
+            if tripped:
                 self.open = True
                 self.stats["trips"] += 1
+        if tripped:
+            log.error("native circuit breaker OPEN after %d consecutive "
+                      "faults: every native call now runs its numpy twin "
+                      "until reset()", self.threshold)
 
     def reset(self) -> None:
         with self._lock:
@@ -174,7 +203,9 @@ breaker = CircuitBreaker(knobs.get_int("NOMAD_TPU_NATIVE_BREAKER"))
 
 def _native_lib() -> Optional[ctypes.CDLL]:
     """The library iff the circuit is closed; every native call site goes
-    through here so an open breaker routes everything to Python."""
+    through here so an open breaker routes everything to Python.  A
+    library that cannot be built raises (see _load) — None means only
+    "breaker open"."""
     if breaker.open:
         return None
     return _load()

@@ -224,11 +224,11 @@ def _place_step(inp: PlaceInputs, spread_algorithm: bool, carry, slot):
 
 def _pack_outputs(node, score, fit_s, n_eval, n_exh, top_n, top_s) -> jax.Array:
     """Pack the per-slot outputs into ONE f32 array [..., S, 5 + 2*TOP_K]
-    so the host fetches a single leaf — on high-latency runtimes every
-    device->host leaf is a ~20-35 ms round trip, so 7 leaves vs 1 is the
-    difference between ~240 ms and ~25 ms per dispatch.  Integers are
-    VALUE-encoded as floats (exact below 2^24) — bitcasting them would
-    produce denormals that TPU hardware flushes to zero."""
+    so the host fetches a single leaf: every device->host leaf is its
+    own transfer with its own fixed cost, and the engine's resolve path
+    unpacks one buffer.  Integers are VALUE-encoded as floats (exact
+    below 2^24) — bitcasting them would produce denormals that TPU
+    hardware flushes to zero."""
     as_f = lambda x: x.astype(jnp.float32)
     return jnp.concatenate([
         as_f(node)[..., None], score[..., None], fit_s[..., None],
@@ -278,11 +278,11 @@ def place_eval_jit(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceRes
 # --------------------------------------------------------------------------
 # Packed H2D transport.
 #
-# The D2H side already ships ONE leaf (_pack_outputs) because every
-# device<->host leaf on a high-latency runtime is its own ~20-35 ms round
-# trip; the H2D side of a batch dispatch used to ship an ~18-leaf
-# per-eval-field pytree and paid the same per-leaf tax 18x.  Here every
-# eval's placement inputs flatten into two f32 vectors:
+# The D2H side ships ONE leaf (_pack_outputs) because every
+# device<->host leaf is its own transfer; the H2D side of a batch
+# dispatch would otherwise ship an ~18-leaf per-eval-field pytree and
+# pay the per-leaf cost 18x.  Here every eval's placement inputs flatten
+# into two f32 vectors:
 #
 #   heavy[Lh]: the G x N-scale tensors (feasibility, affinity, penalty,
 #       co-placement counts, place capacity, spread programs).  These are
@@ -720,9 +720,9 @@ def pack_bulk_light(has_affinity, desired, count, demand, deltas,
 
 # sparse bulk output: assignments of a count<=SPARSE_CAP eval fit in
 # SPARSE_CAP (row, count) pairs + the scores AT those rows.  A dense
-# [N] assign+scores row is ~2N floats of D2H per eval — on a
-# high-latency/low-bandwidth runtime link that transfer, not the
-# kernel, dominated C2M-1M serving.
+# [N] assign+scores row is ~2N floats of D2H per eval (128 KB at 16K
+# rows, x up to 512 evals per dispatch), all of which the host then
+# has to scan for the few nonzero rows.
 SPARSE_CAP = 128
 
 
@@ -905,8 +905,7 @@ def place_eval(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceResult:
 
     All outputs come back in ONE single-leaf D2H transfer (the packed
     output array); the f32[N, R] `used` matrix stays device-resident (no
-    caller reads it on host — transferring it per eval dominated e2e wall
-    time on high-latency runtimes).
+    caller reads it on host, so fetching it per eval is pure waste).
     """
     packed, used = place_eval_packed_jit(inp,
                                          spread_algorithm=spread_algorithm)

@@ -108,8 +108,10 @@ def preempt_for_task_group_np(cand_res, cand_prio, cand_valid, remaining,
     """Numpy twin of preempt_for_task_group, used on the scheduler-worker
     host path: worker threads must not issue device work concurrently
     with the PlacementEngine's dispatcher (single-dispatch-thread
-    discipline — concurrent fetches can wedge on tunneled runtimes), and
-    at N x A x steps this selection is trivial host math anyway."""
+    discipline: one thread owns every launch and fetch, so the
+    steady-state transfer guard and the donated-carry protocol have one
+    place to hold), and at N x A x steps this selection is trivial host
+    math anyway."""
     import numpy as np
 
     N, A, R = cand_res.shape

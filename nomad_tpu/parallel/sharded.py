@@ -77,20 +77,6 @@ def _put_host(mesh, spec, x):  # analysis: allow(transfer-purity) — per-wave d
     return x
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version-portable shard_map: `jax.shard_map` (jax >= 0.6, kwarg
-    `check_vma`) falls back to `jax.experimental.shard_map` (jax 0.4.x,
-    kwarg `check_rep`).  Every shard_map in this package routes through
-    here — calling `jax.shard_map` directly breaks on the pinned 0.4.x
-    toolchain (the symbol simply doesn't exist there)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def wave_mesh_shape(n_devices: int,
                     wave_shards: Optional[int] = None) -> Tuple[int, int]:
     """Factor a device count into the (node_shard, wave) grid.
@@ -306,8 +292,8 @@ def place_eval_batch_sharded(mesh: Mesh, stacked: PlaceInputs,
     key = ("eval_batch", mesh_key(mesh), spread_algorithm)
     fn = _SERVING_FN_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                               out_specs=out_specs, check_vma=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                                   out_specs=out_specs, check_vma=False))
         recompile.register("sharded.eval_batch", fn)
         _SERVING_FN_CACHE[key] = fn
     return fn(stacked)
@@ -379,11 +365,11 @@ def serving_update_fns(mesh: Mesh):
     fns = _SERVING_FN_CACHE.get(key)
     if fns is None:
         NS = NODE_AXIS_NAME
-        set_fn = jax.jit(shard_map(
+        set_fn = jax.jit(jax.shard_map(
             _set_rows_local, mesh=mesh,
             in_specs=(P(NS, None), P(None), P(None, None)),
             out_specs=P(NS, None), check_vma=False))
-        add_fn = jax.jit(shard_map(
+        add_fn = jax.jit(jax.shard_map(
             _add_rank1_local, mesh=mesh,
             in_specs=(P(NS, None), P(None), P(None), P(None)),
             out_specs=P(NS, None), check_vma=False))
@@ -473,7 +459,7 @@ def place_batch_sharded(mesh: Mesh, capacity, used0, fields: dict,
                     _field_specs_batched(), P(None, None),
                     P(None, None, None))
         out_specs = (P(None, None, None), P(NS, None))
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                                    out_specs=out_specs, check_vma=False))
         recompile.register("sharded.scan", fn)
         _SERVING_FN_CACHE[key] = fn
@@ -653,8 +639,8 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
     if fn is None:
         out_specs = (P(W, None, NS), P(W, None, NS), P(W, None),
                      P(W, None), P(W, None), P(W, None), P(NS, None))
-        mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
         # donate_argnums=(1,): used0 and used_final share shape [N, R]
         # and sharding P('node_shard', None), so XLA aliases the carry
         # in place of a fresh allocation + a host re-upload next wave

@@ -6,71 +6,37 @@ import os
 from nomad_tpu import knobs
 
 
-_cache_enabled = False
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 
-def _machine_cache_key() -> str:
-    """Short digest of the TARGET MACHINE's features, used to partition
-    the persistent compile cache: an AOT-cached executable deserialized
-    on a host with a different ISA/accelerator can SIGILL or miscompute
-    (observed as cross-host reuse warnings in multichip runs).  Keyed on
-    arch + CPU feature flags + accelerator selection, all readable
-    without forcing JAX backend init."""
-    import hashlib
-    import platform
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache so a fresh process
+    deserializes the placement-kernel variant grid instead of
+    recompiling it.  The reference keeps scheduler workers hot at
+    leadership (nomad/worker.go); for an XLA-compiled scheduler the
+    equivalent serving-readiness lever is a persistent compile cache +
+    AOT warmup.
 
-    parts = [platform.machine(), platform.system()]
-    try:
-        with open("/proc/cpuinfo") as fh:
-            for line in fh:
-                if line.startswith(("flags", "Features")):
-                    parts.append(" ".join(sorted(line.split(":", 1)[1]
-                                                 .split())))
-                    break
-    except OSError:
-        pass
-    # accelerator identity without initializing a backend: the env vars
-    # that select it are what distinguishes cache-incompatible hosts
-    for var in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME", "TPU_ACCELERATOR_TYPE",
-                "TPU_VERSION", "TPU_CHIPS_PER_HOST_BOUNDS"):
-        parts.append(f"{var}={os.environ.get(var, '')}")
-    return hashlib.sha256("|".join(parts).encode()).hexdigest()[:12]
-
-
-def enable_compile_cache(path: str | None = None) -> str | None:
-    """Point JAX at an on-disk compilation cache so a fresh process
-    deserializes the placement-kernel variant grid (~100ms/executable)
-    instead of recompiling it (~3-5s/variant, ~46s total on TPU).  The
-    reference keeps scheduler workers hot at leadership (nomad/worker.go);
-    for an XLA-compiled scheduler the equivalent serving-readiness lever
-    is a persistent compile cache + AOT warmup.
-
-    The cache lives in a per-machine-feature subdirectory (see
-    _machine_cache_key) so executables never cross incompatible hosts.
-
-    Defaults to `<repo root>/.jax_cache/<machine-key>`; override the root
-    with NOMAD_TPU_JAX_CACHE_DIR, disable with NOMAD_TPU_JAX_CACHE=0.
-    Returns the cache dir in use (None when disabled)."""
-    global _cache_enabled
+    The directory is placed from OUTSIDE: when `JAX_COMPILATION_CACHE_DIR`
+    is set JAX has already read it and nothing here touches
+    `jax_compilation_cache_dir`; unset, the cache lives at the fixed
+    `<checkout>/.jax_cache` (the path is part of the cache key, so it
+    must not move between runs).  NOMAD_TPU_JAX_CACHE=0 disables it
+    (tests do: a CPU test run must not share AOT executables across
+    hosts).  Returns the directory in use (None when disabled); errors
+    propagate — a cache that silently fails to engage costs a full cold
+    compile of the variant grid on every start."""
     if not knobs.get_bool("NOMAD_TPU_JAX_CACHE"):
         return None
-    if _cache_enabled:
-        import jax
-        return jax.config.jax_compilation_cache_dir
-    root = (path or knobs.get_str("NOMAD_TPU_JAX_CACHE_DIR")
-            or os.path.join(os.path.dirname(os.path.dirname(
-                os.path.dirname(os.path.abspath(__file__)))), ".jax_cache"))
-    path = os.path.join(root, _machine_cache_key())
-    try:
-        import jax
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        _cache_enabled = True
-        return path
-    except Exception:               # noqa: BLE001 — cache is best-effort
-        return None
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def requires_lock(lockname: str = "_lock"):

@@ -1,7 +1,7 @@
 """Crash-safe durability tests: checksummed WAL, persisted term/vote,
 hardened snapshots, client state DB recovery, and the seeded hard-kill /
 restart soak (reference analogs: raft-boltdb's torture tests plus the
-crash-consistency failure taxonomy of Pillai et al., OSDI 2014).
+crash-consistency failure classes of Pillai et al., OSDI 2014).
 
 Unit legs pin one contract each: WAL record framing + torn-tail repair,
 mid-stream corruption refusal, legacy pickle migration, fsync policy

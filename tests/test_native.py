@@ -1,5 +1,5 @@
 """Native C++ kernel tests — and parity between the native and numpy
-fallback paths (reference analogs: structs/funcs_test.go AllocsFit/
+twin paths (reference analogs: structs/funcs_test.go AllocsFit/
 ScoreFit tests, plan_apply_test.go node validation)."""
 import numpy as np
 import pytest
@@ -17,6 +17,31 @@ def test_native_library_builds(lib_available):
     # the toolchain is part of the environment contract; the native
     # path must actually be exercised in CI, not silently skipped
     assert lib_available, "g++ build of native/nomad_native.cpp failed"
+
+
+def _unbuilt(monkeypatch, tmp_path, source: str):
+    src = tmp_path / "nomad_native.cpp"
+    src.write_text(source)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.delenv("NOMAD_TPU_NATIVE_LIB", raising=False)
+
+
+def test_failed_build_raises_with_the_compilers_message(monkeypatch,
+                                                        tmp_path):
+    _unbuilt(monkeypatch, tmp_path, "int broken( { return 0; }\n")
+    with pytest.raises(native.NativeBuildError, match=r"(?s)g\+\+.*error"):
+        native.allocs_fit(np.ones((1, 3), np.float32),
+                          np.zeros((1, 3), np.float32),
+                          np.zeros(3, np.float32))
+
+
+def test_missing_compiler_raises(monkeypatch, tmp_path):
+    _unbuilt(monkeypatch, tmp_path, "int f() { return 0; }\n")
+    monkeypatch.setenv("PATH", str(tmp_path))
+    with pytest.raises(native.NativeBuildError, match=r"g\+\+"):
+        native._load()
 
 
 def test_allocs_fit():
