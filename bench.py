@@ -58,11 +58,6 @@ _PLAN_STATS: dict = {}
 # violation fails the --smoke leg.  NOMAD_TPU_BENCH_GUARD=0 opts out.
 _STEADY_STATE: dict = {}
 
-# per-scenario kernel-stage attribution (stage_probe.device_stages):
-# measured device_s split across feasibility/fit/score/argmax/scatter,
-# folded into the BENCH JSON so BENCH_r06 names the stage to fuse first
-_DEVICE_STAGES: dict = {}
-
 # per-scenario engine-stats snapshot taken before the server stops; the
 # --smoke fused-path gate reads it after the run (fused dispatch means
 # one device dispatch per wave group: bulk_parts == bulk_groups)
@@ -459,35 +454,6 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
         if eng:
             log(f"{scenario} engine stats: {eng.stats}")
             _ENGINE_SNAP[scenario] = dict(eng.stats)
-            # stage attribution runs strictly AFTER the steady gate has
-            # exited: the probe compiles its own kernels and moves data,
-            # which must not count against the gate's purity budgets
-            from nomad_tpu.ops.place import fill_grid_for
-            from nomad_tpu.parallel import stage_probe
-            # tentpole metric: host upload/dispatch windows for wave
-            # N+1 hidden under wave N's in-flight device windows
-            pipe_overlap = stage_probe.interval_overlap_s(
-                list(eng.upload_windows),
-                list(eng.device_windows))
-            # device time the commit pipeline hid under raft
-            # append + fsync: engine device-blocked windows against
-            # the applier's commit windows
-            commit_overlap = stage_probe.interval_overlap_s(
-                list(eng.device_windows),
-                list(s.applier.commit_windows))
-            ds = stage_probe.device_stages(
-                eng.stats, n_nodes,
-                fill_grid=fill_grid_for(group_count),
-                pipeline_overlap_s=pipe_overlap,
-                commit_overlap_s=commit_overlap,
-                wave=eng.stats)
-            if ds is not None:
-                _DEVICE_STAGES[scenario] = ds
-                log(f"{scenario} device stages: dominant="
-                    f"{ds['dominant_stage']} {ds['stages_s']} "
-                    f"pipeline_overlap={ds['pipeline_overlap_s']}s "
-                    f"commit_overlap={ds['commit_overlap_s']}s "
-                    f"wave={ds.get('wave')} fused={ds['fused']}")
         _log_plan_submit(scenario)
         return placed / dt, placed, want
     finally:
@@ -505,29 +471,38 @@ def bench_smoke(workers=8):
                         scenario="smoke")
 
 
+# ns per tracing.span() with the profiler off and no tracer installed:
+# 1,930-2,400 measured in this sandbox (CPU, 10^6 and 3x10^5 loops,
+# PR 25); the bound is three times the median so that a CI host loaded
+# by six test workers does not trip it
+_SPAN_NS_BOUND = 6000.0
+
+
 def _smoke_trace_checks() -> dict:
-    """Tracing leg of --smoke (r12): (1) with no tracer installed the
-    guard every hot site uses must cost one module-attribute load —
-    measured here and capped at 1 us/op, which is "nil" against a
-    multi-ms plan submit; (2) a fully sampled run through the real spine
-    must produce causally linked spans that export as well-formed
-    Chrome-trace JSON (the file Perfetto loads)."""
+    """Tracing leg of --smoke: (1) with the profiler off and no tracer
+    installed, one `tracing.span()` (the primitive every layer boundary
+    opens: two clock reads, one flag test, one counter update) must stay
+    under `_SPAN_NS_BOUND` — "nil" against a multi-ms plan submit;
+    (2) a fully sampled run through the real spine must produce causally
+    linked spans that export as well-formed Chrome-trace JSON (the file
+    Perfetto loads)."""
     from nomad_tpu import mock, tracing
 
     out = {"disabled_overhead_ns_per_op": None, "spans": 0,
            "perfetto_file": "", "perfetto_events": 0, "violations": []}
     if tracing.active is not None:
         tracing.uninstall()
-    n = 1_000_000
+    n = 200_000
     t0 = time.perf_counter()
     for _ in range(n):
-        if tracing.active is not None:  # the exact hot-site idiom
-            raise AssertionError("tracer installed mid-check")
+        with tracing.span("bench.smoke_probe"):
+            pass
     per_ns = (time.perf_counter() - t0) / n * 1e9
     out["disabled_overhead_ns_per_op"] = round(per_ns, 1)
-    if per_ns > 1000.0:
+    if per_ns > _SPAN_NS_BOUND:
         out["violations"].append(
-            f"disabled-tracing guard costs {per_ns:.0f} ns/op (> 1 us)")
+            f"tracing.span() with everything off costs {per_ns:.0f} ns/op "
+            f"(> {_SPAN_NS_BOUND:.0f} ns)")
 
     tracing.install(tracing.Tracer(sample_rate=1.0, seed=7))
     s = _server(workers=4)
@@ -540,13 +515,8 @@ def _smoke_trace_checks() -> dict:
         # bench drives the server directly (no HTTP front), so open the
         # root span the agent's HTTP layer would normally start
         ctx = tracer.new_context()
-        root = tracer.start(ctx, "bench.register_job", s.name)
-        prev = tracing.bind(tracer.child_ctx(ctx, root))
-        try:
+        with tracing.span("bench.register_job", ctx=ctx, node=s.name):
             s.register_job(j)
-        finally:
-            tracer.finish(root)
-            tracing.bind(prev)
         _wait_allocs(s.store, [j], 8, timeout=60)
         time.sleep(0.2)     # let the applier's observe-time spans land
         spans = tracer.spans(ctx["t"])
@@ -1145,7 +1115,6 @@ def main():
             "plan_latency_ms": _PLAN_STATS,
             "steady_state": steady,
             "serving_plane": serving,
-            "device_stages": _DEVICE_STAGES.get("smoke"),
             "fused": {"bulk_groups": groups, "bulk_parts": parts,
                       "kernels": {k: kernel_sizes.get(k)
                                   for alts in want_kernels
@@ -1235,7 +1204,6 @@ def main():
         "plan_latency_ms": _PLAN_STATS,
         "steady_state": _STEADY_STATE,
         "serving_plane": serving,
-        "device_stages": _DEVICE_STAGES.get("c2m_1m"),
         "failures": failures,
     }), flush=True)
     if failures:

@@ -108,8 +108,10 @@ class HTTPServer:
             do_GET = do_PUT = do_POST = do_DELETE = _dispatch
 
             def _reply(self, code: int, obj, index: Optional[int] = None,
-                       ctx=None, retry_after: Optional[float] = None):
-                body = json.dumps(obj).encode()
+                       ctx=None, retry_after: Optional[float] = None,
+                       body: Optional[bytes] = None):
+                if body is None:
+                    body = json.dumps(obj).encode()
                 try:
                     self.send_response(code)
                     self.send_header("Content-Type", "application/json")
@@ -255,45 +257,43 @@ class HTTPServer:
         # a stale read must shed LAST, not as a default read
         self._read_local.local_mode = mode_from_query(q) \
             if read_ctx is not None else None
-        # trace ingress: one sampling decision per request; unsampled
-        # requests (and a disabled tracer) skip everything below
-        tracer = tracing.active
-        tspan = tprev = None
-        if tracer is not None and parts[0] != "traces":
-            tctx = tracer.new_context()
-            if tctx is not None:
-                node = server.name if server is not None else "agent"
-                tspan = tracer.start(
-                    tctx, f"http.{method} /v1/{parts[0]}", node)
-                tprev = tracing.bind(tracer.child_ctx(tctx, tspan))
         try:
-            if store is not None and "index" in q and region is None:
-                min_index = int(q["index"])
-                wait = _parse_wait(q.get("wait", "5s"))
-                # a deadline-bound blocking query parks for at most its
-                # remaining budget, then serves the current state
-                rem = deadline.remaining()
-                if rem is not None:
-                    wait = min(wait, rem)
-                store.wait_for_index(min_index + 1, timeout=min(wait, 600.0))
-
             m = method.lower()
-            candidates = []
-            if len(parts) >= 2:
-                candidates.append(f"_h_{m}_{parts[0]}_id")
-            candidates.append(f"_h_{m}_{parts[0]}")
             handler = None
-            for name in candidates:
+            for name in ([f"_h_{m}_{parts[0]}_id"] if len(parts) >= 2
+                         else []) + [f"_h_{m}_{parts[0]}"]:
                 handler = getattr(self, name, None)
                 if handler is not None:
                     break
             if handler is None:
                 raise HTTPError(404, f"no handler for {method} {url.path}")
-            result = handler(h, parts, q)
+            # trace ingress: one sampling decision per request; the span
+            # is named for the route's handler (`http.put.jobs`,
+            # `http.get.job_id`), never for a path the caller made up
+            tracer = tracing.active
+            tctx = tracer.new_context() \
+                if tracer is not None and parts[0] != "traces" else None
+            with tracing.span(
+                    "http." + name[3:].replace("_", ".", 1), ctx=tctx,
+                    node=server.name if server is not None else "agent"):
+                if store is not None and "index" in q and region is None:
+                    min_index = int(q["index"])
+                    wait = _parse_wait(q.get("wait", "5s"))
+                    # a deadline-bound blocking query parks for at most
+                    # its remaining budget, then serves the current state
+                    rem = deadline.remaining()
+                    if rem is not None:
+                        wait = min(wait, rem)
+                    with tracing.span("http.park", wait=True):
+                        store.wait_for_index(min_index + 1,
+                                             timeout=min(wait, 600.0))
+                result = handler(h, parts, q)
+                if result is not _STREAMED:
+                    # serialising the reply is the request's work too;
+                    # the socket write stays after the span, as close to
+                    # the admission slot's release as it was
+                    body = json.dumps(to_wire(result)).encode()
         finally:
-            if tspan is not None:
-                tracer.finish(tspan)
-                tracing.bind(tprev)
             self._read_local.ctx = None
             self._read_local.region = None
             self._read_local.mode = None
@@ -307,7 +307,7 @@ class HTTPServer:
                 # a blocking query must never return an index lower than
                 # the one it was given (reference blockingRPC contract)
                 index = max(index, int(q["index"]))
-            h._reply(200, to_wire(result), index=index, ctx=read_ctx)
+            h._reply(200, None, index=index, ctx=read_ctx, body=body)
 
     def _rpc(self, method: str, args: dict):
         server = self.agent.server
@@ -1127,7 +1127,10 @@ class HTTPServer:
                               + chunk + b"\r\n")
                 h.wfile.flush()
 
-            streamer.run(write, float(q.get("timeout", 5.0)))
+            # the stream is held open, not worked on: a 45 s "work" span
+            # here would overlap every idle gap of a profiler trace
+            with tracing.span("http.park", wait=True):
+                streamer.run(write, float(q.get("timeout", 5.0)))
             h.wfile.write(b"0\r\n\r\n")
         except (BrokenPipeError, ConnectionResetError):
             pass

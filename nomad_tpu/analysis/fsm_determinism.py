@@ -7,7 +7,7 @@ store state.  This checker walks the shared interprocedural cone
 (common.walk_cone) from the FSM's apply/restore methods and flags:
 
 - wall-clock reads (`time.time`, `monotonic`, `perf_counter`, datetime
-  now/utcnow)
+  now/utcnow, and the span primitive `tracing.span` / `tracing.record`)
 - entropy (`random.*` draws, `uuid4`/`uuid1`, `os.urandom`) — including
   transitively, e.g. a helper that formats uuids
 - iteration over unordered sets (set literals / `set()` constructions),
@@ -51,6 +51,10 @@ def _sink(call: ast.Call) -> Optional[str]:
         if f.attr in _DATETIME_ATTRS and base and \
                 base.split(".")[-1] in ("datetime", "date"):
             return f"wall-clock read `{base}.{f.attr}()`"
+        if f.attr in ("span", "record") and base == "tracing":
+            # the span primitive reads the clock in __enter__/__exit__,
+            # which the bare-name call graph cannot follow
+            return f"wall-clock read via `tracing.{f.attr}()`"
         if f.attr in _ENTROPY_NAMES:
             return f"entropy source `.{f.attr}()`"
         if f.attr in _RANDOM_FNS and base is not None and \

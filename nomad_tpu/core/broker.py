@@ -267,20 +267,22 @@ class EvalBroker:
         race.write("EvalBroker._unack", self)
         self._unack[token] = _Lease(ev, token, expires)
         self.stats["dequeued"] += 1
-        tracer = tracing.active
-        if tracer is not None:
-            # queue-wait span, stitched from the propose-time
-            # note (the FSM's leader hook enqueues inside the
-            # apply cone, so nothing is stamped there); the
-            # context is re-noted for the dequeuing worker
-            note = tracer.take_eval_note(ev.id)
-            if note is not None:
-                ctx, enq_ts = note
-                tracer.emit(
-                    ctx, "broker.wait", enq_ts, _time.time(),
-                    node=getattr(self, "node_name", ""),
-                    eval_id=ev.id, sched=ev.type)
-                tracer.note_eval(ev.id, ctx)
+        # queue wait of EVERY eval, from its last write to now: the
+        # FSM's leader hook enqueues inside the apply cone, where
+        # nothing may read the clock, so the start is the modify_time
+        # that create_evals/update_eval stamped at propose time (wall
+        # clock, since it rides the log).  A sampled eval's context is
+        # re-noted for the dequeuing worker.
+        ctx = tracing.take_eval_ctx(ev.id)
+        if ev.modify_time:
+            now = _time.perf_counter()
+            tracing.record(
+                "broker.wait",
+                now - max(0.0, _time.time() - ev.modify_time), now,
+                wait=True, ctx=ctx, node=getattr(self, "node_name", ""),
+                eval_id=ev.id, sched=ev.type)
+        if ctx is not None:
+            tracing.note_evals((ev.id,), ctx)
         return ev, token
 
     def dequeue(self, schedulers: List[str], timeout: float = 0.0

@@ -28,7 +28,6 @@ from __future__ import annotations
 import os
 import threading
 import time as _time
-from collections import deque
 from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
@@ -79,12 +78,6 @@ class PlanApplier:
         self._overlay_lock = threading.Lock()
         self._overlay: Dict[int, tuple] = {}
         self._overlay_seq = 0
-        # (t0, t1) wall windows where the commit thread held the raft
-        # append + fsync in flight; bench intersects these with the
-        # engine's device-blocked windows to report pipeline_overlap_s
-        # (device time hidden under durability waits).  Appends happen
-        # only on the single commit thread; readers tolerate staleness.
-        self.commit_windows = deque(maxlen=8192)
         self.stats = {"applied": 0, "rejected_nodes": 0, "partial": 0,
                       "pipelined": 0}
 
@@ -140,21 +133,19 @@ class PlanApplier:
                         pending.evaluated.set_exception(err)
                     continue
                 try:
-                    tracer = tracing.active
-                    tnote = pending.trace if tracer is not None else None
-                    t0 = _time.time()
-                    if tnote is not None:
-                        tracer.emit(tnote[0], "plan.queue_wait",
-                                    tnote[1], t0,
-                                    node=getattr(self, "node_name", ""))
+                    node = getattr(self, "node_name", "")
+                    tracing.record("plan.queue_wait", pending.enqueued,
+                                   _time.perf_counter(), wait=True,
+                                   ctx=pending.ctx, node=node)
                     # snapshot BEFORE evaluating: if the commit finishes
                     # while _evaluate reads the double-counted window, an
                     # after-the-fact is_alive() check would skip the
                     # second look and let the stale rejection stand
                     commit_in_flight = (commit_t is not None
                                         and commit_t.is_alive())
-                    result = self._evaluate(pending.plan)
-                    global_metrics.measure_since("nomad.plan.evaluate", t0)
+                    with tracing.span("plan.evaluate", ctx=pending.ctx,
+                                      node=node):
+                        result = self._evaluate(pending.plan)
                     if commit_in_flight and \
                             self._result_rejected_something(pending.plan,
                                                             result):
@@ -165,16 +156,14 @@ class PlanApplier:
                         # the plan one clean second look before failing it
                         # back to the scheduler (a full eval recompute).
                         # Plans staged in THIS batch are overlay-only, so
-                        # they are never double-counted.
+                        # they are never double-counted.  (The second
+                        # look is counted in `revalidated`, not timed:
+                        # `plan.evaluate` stays one per plan.)
                         commit_t.join()
                         self.stats["revalidated"] = \
                             self.stats.get("revalidated", 0) + 1
                         result = self._evaluate(pending.plan)
                     token = self._overlay_add(pending.plan, result)
-                    if tnote is not None:
-                        tracer.emit(tnote[0], "plan.evaluate",
-                                    t0, _time.time(),
-                                    node=getattr(self, "node_name", ""))
                 except Exception as e:            # noqa: BLE001
                     pending.future.set_exception(e)
                     if not pending.evaluated.done():
@@ -223,23 +212,19 @@ class PlanApplier:
             if applied_list:
                 if chaos.active is not None:
                     chaos.fire("plan.crash_before_commit")
-                # a coalesced batch commits as ONE raft apply: bind the
-                # first sampled plan's context so the synchronous raft
-                # write path on this thread emits append/commit spans
-                # into that trace
-                tprev, tbound = None, False
-                if tracing.active is not None:
-                    for pending, _r, _ap in entries:
-                        if pending.trace is not None:
-                            tprev = tracing.bind(pending.trace[0])
-                            tbound = True
-                            break
-                t0c = _time.time()
-                if chaos.active is not None:
-                    # slow fsync: stretch the durability wait the next
-                    # wave is evaluating (and dispatching) under
-                    chaos.maybe_delay("plan.commit_stall")
-                try:
+                # a coalesced batch commits as ONE raft apply: the commit
+                # span opens under the first sampled plan's context, so
+                # the synchronous raft write path on this thread emits
+                # append/commit spans into that trace
+                ctx = next((p.ctx for p, _r, _ap in entries
+                            if p.ctx is not None), None)
+                with tracing.span("plan.commit", ctx=ctx,
+                                  node=getattr(self, "node_name", ""),
+                                  plans=len(applied_list)):
+                    if chaos.active is not None:
+                        # slow fsync: stretch the durability wait the
+                        # next wave is evaluating (and dispatching) under
+                        chaos.maybe_delay("plan.commit_stall")
                     with self._commit_lock:
                         if self._commit_fn is not None:
                             index = self._commit_fn(
@@ -249,10 +234,6 @@ class PlanApplier:
                             index = self.store.latest_index + 1
                             self.store.upsert_plan_results_many(
                                 index, applied_list)
-                finally:
-                    if tbound:
-                        tracing.bind(tprev)
-                self.commit_windows.append((t0c, _time.time()))
                 if chaos.active is not None:
                     # the write landed but futures have not resolved: the
                     # submitter sees an error, retries, and the plan-id

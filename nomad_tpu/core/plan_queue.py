@@ -23,8 +23,10 @@ class LeadershipLostError(Exception):
 
 
 class PendingPlan:
-    # trace: (ctx, enqueue_ts) for a sampled submission, else None —
-    # the applier stitches queue-wait/evaluate/raft spans from it
+    # enqueued: perf_counter at enqueue, always — the applier records
+    # the queue wait of every plan from it.  ctx: the submitter's
+    # sampled trace context, else None — the applier stitches
+    # queue-wait/evaluate/raft spans under it
     #
     # `evaluated` resolves with the PlanResult as soon as the applier has
     # validated the plan and registered its overlay — before the raft
@@ -35,18 +37,16 @@ class PendingPlan:
     # deadline: the submitter's absolute monotonic deadline (or None),
     # stamped at enqueue — the applier refuses an already-expired plan
     # BEFORE paying the raft append + fsync for it
-    __slots__ = ("plan", "future", "evaluated", "trace", "deadline")
+    __slots__ = ("plan", "future", "evaluated", "enqueued", "ctx",
+                 "deadline")
 
     def __init__(self, plan: Plan):
         self.plan = plan
         self.future: Future = Future()
         self.evaluated: Future = Future()
-        self.trace = None
         self.deadline = deadline.current()
-        if tracing.active is not None:
-            ctx = tracing.current()
-            if ctx is not None:
-                self.trace = (ctx, time.time())
+        self.enqueued = time.perf_counter()
+        self.ctx = tracing.current()
 
 
 class PlanQueue:

@@ -239,17 +239,13 @@ class Server:
         target; raises NotLeaderError if a follower is asked directly."""
         if self.raft is not None:
             return self.raft.apply(msg_type, payload)
-        tracer = tracing.active
-        ctx = tracing.current() if tracer is not None else None
-        t0 = _time.time() if ctx is not None else 0.0
         with self._raft_lock:
             index = self.store.latest_index + 1
-            self.fsm.apply(index, msg_type, payload)
-        if ctx is not None:
-            # dev mode (no raft): observe-time apply span — timestamps
-            # taken outside the FSM, which never reads the clock
-            tracer.emit(ctx, "raft.fsm_apply", t0, _time.time(),
-                        node=self.name, msg_type=msg_type, index=index)
+            # dev mode (no raft): the span opens around the FSM call,
+            # never under it — the FSM does not read the clock
+            with tracing.span("raft.fsm_apply", node=self.name,
+                              msg_type=msg_type, index=index):
+                self.fsm.apply(index, msg_type, payload)
         return index
 
     def rpc_leader(self, method: str, args: dict):
@@ -810,16 +806,11 @@ class Server:
             if not c.create_time:
                 c.create_time = now
             copies.append(c)
-        tracer = tracing.active
-        if tracer is not None:
-            # propose-time trace note: the broker enqueue happens inside
-            # the FSM apply cone where nothing may stamp the clock, so
-            # the queue-wait span's start is noted here and emitted at
-            # dequeue (see EvalBroker.dequeue)
-            ctx = tracing.current()
-            if ctx is not None:
-                for c in copies:
-                    tracer.note_eval(c.id, ctx, ts=now)
+        # propose-time trace note: the broker enqueue happens inside the
+        # FSM apply cone where nothing may stamp the clock, so a sampled
+        # context crosses the broker through the tracer's note table
+        # (see EvalBroker._pick_locked)
+        tracing.note_evals(c.id for c in copies)
         self.apply(MessageType.EVAL_UPDATE, {"evals": copies})
 
     def register_job(self, job: Job) -> Evaluation:

@@ -41,6 +41,14 @@ TRANSIENT_ERRORS = (NotLeaderError, LeadershipLostError, RpcError,
                     TimeoutError)
 
 
+def _submit_by_namespace(plan: Plan, seconds: float) -> None:
+    """Per-namespace plan-submit latency: the fairness gate in the
+    multi-tenant scenarios asserts on victim-tenant p99, not the global
+    mix."""
+    ns = (plan.job.namespace or "default") if plan.job else "default"
+    global_metrics.add_sample(f"nomad.plan.submit.ns.{ns}", seconds * 1e3)
+
+
 class Worker:
     def __init__(self, server, worker_id: int = 0,
                  enabled_schedulers: Optional[List[str]] = None):
@@ -195,12 +203,7 @@ class Worker:
             if ev is None:
                 return None
         self._wait_index = self.server.store.latest_index
-        self._trace_ctx = None
-        tracer = tracing.active
-        if tracer is not None:
-            note = tracer.take_eval_note(ev.id)
-            if note is not None:
-                self._trace_ctx = note[0]
+        self._trace_ctx = tracing.take_eval_ctx(ev.id)
         return ev, token
 
     def _ack(self, eval_id: str, token: str) -> bool:
@@ -226,24 +229,15 @@ class Worker:
         self._token = token
         self._eval_pendings = []
         ev = ev.copy()
-        # sampled eval: the scheduler invocation is a span, and the trace
-        # context stays bound for its duration so plan submission (and
+        # the scheduler invocation is a span; a sampled eval's trace
+        # context stays bound for its duration, so plan submission (and
         # any follow-up evals it creates) joins the trace
-        tracer = tracing.active
-        tctx = getattr(self, "_trace_ctx", None)
-        tspan = tprev = None
-        if tracer is not None and tctx is not None:
-            tspan = tracer.start(
-                tctx, f"worker.invoke_scheduler.{ev.type}",
-                self.server.name)
-            tprev = tracing.bind(tracer.child_ctx(tctx, tspan))
-        try:
+        with tracing.span(f"worker.invoke_scheduler.{ev.type}",
+                          ctx=getattr(self, "_trace_ctx", None),
+                          node=server.name):
             try:
                 sched = factory.new_scheduler(ev.type, snap, self)
-                t0 = time.time()
                 sched.process(ev)
-                global_metrics.measure_since(
-                    f"nomad.worker.invoke_scheduler.{ev.type}", t0)
             except TRANSIENT_ERRORS:
                 raise
             except Exception as e:                      # noqa: BLE001
@@ -254,10 +248,6 @@ class Worker:
                 server.update_eval(ev)  # raises TRANSIENT -> run() nacks
                 self._nack(ev.id, token)
                 return
-        finally:
-            if tspan is not None:
-                tracer.finish(tspan)
-                tracing.bind(tprev)
         ev.status = EvalStatus.COMPLETE
         pendings, self._eval_pendings = self._eval_pendings, []
         if pendings:
@@ -274,14 +264,8 @@ class Worker:
 
     def submit_plan(self, plan: Plan) -> PlanResult:
         plan.eval_token = getattr(self, "_token", "")
-        t0 = time.time()
-        tracer = tracing.active
-        tctx = tracing.current() if tracer is not None else None
-        tspan = tprev = None
-        if tctx is not None:
-            tspan = tracer.start(tctx, "plan.submit", self.server.name)
-            tprev = tracing.bind(tracer.child_ctx(tctx, tspan))
-        try:
+        with tracing.span("plan.submit", wait=True,
+                          node=self.server.name) as sp:
             pending = self.server.enqueue_plan(plan)
             if self.pipeline_depth > 0:
                 # pipelined: return as soon as the applier has validated
@@ -301,15 +285,7 @@ class Worker:
                 # retried from scratch even though its plan still
                 # commits — pure wasted recompute
                 res = pending.future.result(timeout=600.0)
-        finally:
-            if tspan is not None:
-                tracer.finish(tspan)
-                tracing.bind(tprev)
-        global_metrics.measure_since("nomad.plan.submit", t0)
-        # per-namespace latency: the fairness gate in the multi-tenant
-        # scenarios asserts on victim-tenant p99, not the global mix
-        ns = (plan.job.namespace or "default") if plan.job else "default"
-        global_metrics.measure_since(f"nomad.plan.submit.ns.{ns}", t0)
+        _submit_by_namespace(plan, sp.seconds)
         return res
 
     def create_evals(self, evals: List[Evaluation]) -> None:
@@ -403,26 +379,14 @@ class RemoteWorker(Worker):
 
     def submit_plan(self, plan: Plan) -> PlanResult:
         plan.eval_token = getattr(self, "_token", "")
-        t0 = time.time()
-        args = {"plan": plan}
-        tracer = tracing.active
-        tctx = tracing.current() if tracer is not None else None
-        tspan = None
-        if tctx is not None:
-            # the submit span covers RPC + leader-side queue + apply;
-            # its child context rides the args so the leader's
-            # Plan.Submit handler (endpoints.handle) pops it and binds
-            # it for the enqueue → applier → raft chain
-            tspan = tracer.start(tctx, "plan.submit", self.server.name)
-            args[tracing.TRACE_KEY] = tracer.child_ctx(tctx, tspan)
-        try:
-            res = self._rpc("Plan.Submit", args)
-        finally:
-            if tspan is not None:
-                tracer.finish(tspan)
-        global_metrics.measure_since("nomad.plan.submit", t0)
-        ns = (plan.job.namespace or "default") if plan.job else "default"
-        global_metrics.measure_since(f"nomad.plan.submit.ns.{ns}", t0)
+        # the submit span covers RPC + leader-side queue + apply; a
+        # sampled span's child context rides the args (restamp) so the
+        # leader's Plan.Submit handler binds it for the enqueue ->
+        # applier -> raft chain
+        with tracing.span("plan.submit", wait=True,
+                          node=self.server.name) as sp:
+            res = self._rpc("Plan.Submit", {"plan": plan})
+        _submit_by_namespace(plan, sp.seconds)
         return res
 
     def reblock_eval(self, ev: Evaluation) -> None:

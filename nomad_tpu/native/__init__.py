@@ -23,6 +23,7 @@ every fault and the trip itself are logged.
 from __future__ import annotations
 
 import ctypes
+import functools
 import hashlib
 import logging
 import os
@@ -32,7 +33,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from nomad_tpu import chaos, knobs
+from nomad_tpu import chaos, knobs, tracing
 
 log = logging.getLogger("nomad_tpu.native")
 
@@ -214,6 +215,20 @@ def _native_lib() -> Optional[ctypes.CDLL]:
 _EMPTY_I32 = np.zeros(0, np.int32)
 
 
+def _spanned(fn):
+    """Every call of the wrapper is the span `native.<fn>` (the C++
+    call, its argument marshalling and, with the breaker open, the numpy
+    twin)."""
+    name = "native." + fn.__name__
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with tracing.span(name):
+            return fn(*args, **kwargs)
+    return wrapper
+
+
+@_spanned
 def allocs_fit(capacity: np.ndarray, used: np.ndarray,
                demand: np.ndarray) -> np.ndarray:
     """bool[N]: demand fits in capacity-used per row
@@ -236,6 +251,7 @@ def allocs_fit(capacity: np.ndarray, used: np.ndarray,
     return np.all(used + demand <= capacity + 1e-6, axis=1)
 
 
+@_spanned
 def score_fit(capacity: np.ndarray, used: np.ndarray,
               demand: np.ndarray, spread: bool = False) -> np.ndarray:
     """f32[N] binpack/spread score (structs.ScoreFitBinPack/Spread)."""
@@ -262,6 +278,7 @@ def score_fit(capacity: np.ndarray, used: np.ndarray,
     return np.clip((20.0 - total) / 18.0, 0.0, 1.0).astype(np.float32)
 
 
+@_spanned
 def ports_check(port_words: np.ndarray, row: int,
                 ports: Sequence[int],
                 freed: Sequence[int] = ()) -> bool:
@@ -295,6 +312,7 @@ def ports_check(port_words: np.ndarray, row: int,
     return True
 
 
+@_spanned
 def ports_set(port_words: np.ndarray, row: int,
               ports: Sequence[int], value: bool) -> None:
     ports_a = np.asarray(list(ports), np.int32)
@@ -321,6 +339,7 @@ def ports_set(port_words: np.ndarray, row: int,
             port_words[row, p >> 5] &= ~np.uint32(1 << (p & 31))
 
 
+@_spanned
 def scatter_add(used: np.ndarray, rows: Sequence[int],
                 deltas: np.ndarray) -> None:
     """used[rows[k]] += deltas[k] in place."""
@@ -343,6 +362,7 @@ def scatter_add(used: np.ndarray, rows: Sequence[int],
     np.add.at(used, rows_a, deltas)
 
 
+@_spanned
 def validate_plan(capacity: np.ndarray, used: np.ndarray,
                   port_words: np.ndarray,
                   rows: Sequence[int],
@@ -395,6 +415,7 @@ def validate_plan(capacity: np.ndarray, used: np.ndarray,
     return out
 
 
+@_spanned
 def expand_pairs(rows: np.ndarray, counts: np.ndarray,
                  scores: Optional[np.ndarray] = None
                  ) -> Tuple[np.ndarray, np.ndarray]:
@@ -429,6 +450,7 @@ def expand_pairs(rows: np.ndarray, counts: np.ndarray,
             np.repeat(scores_a[keep], counts_a[keep]))
 
 
+@_spanned
 def format_uuids(n: int) -> List[str]:
     """n fresh uuid strings in one call, byte-identical in format to
     utils.generate_uuid (hex of os.urandom(16), 8-4-4-4-12)."""
@@ -453,6 +475,7 @@ def format_uuids(n: int) -> List[str]:
             for s in (h[i * 32:(i + 1) * 32] for i in range(n))]
 
 
+@_spanned
 def scatter_add_rank1(used: np.ndarray, rows: np.ndarray,
                       counts: np.ndarray, demand: np.ndarray) -> None:
     """used[rows[k]] += counts[k] * demand in place, without building

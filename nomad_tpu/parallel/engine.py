@@ -25,6 +25,7 @@ the window in which the next batch accumulates.
 """
 from __future__ import annotations
 
+import logging
 import os
 import threading
 import time as _time
@@ -58,6 +59,8 @@ from nomad_tpu.ops.place import (
 )
 
 from nomad_tpu.parallel.world import DeviceWorld, mesh_key
+
+log = logging.getLogger(__name__)
 
 # transfer-purity (nomad_tpu.analysis): the dispatch loop is hot-path —
 # implicit host<->device movement is a finding; the few sanctioned
@@ -211,7 +214,8 @@ class _Request:
     deltas: List[Tuple[int, np.ndarray]]   # (row, f32[R]) sparse usage deltas
     spread_algorithm: bool
     future: Future
-    trace: object = None            # (ctx, submit_ts) for sampled evals
+    enq: float = 0.0                # perf_counter at submit (0: not queued)
+    ctx: object = None              # sampled trace context, else None
 
     def shape_key(self):
         i = self.inputs
@@ -238,7 +242,8 @@ class _BulkRequest:
     deltas: List[Tuple[int, np.ndarray]]
     spread_algorithm: bool
     future: Future
-    trace: object = None            # (ctx, submit_ts) for sampled evals
+    enq: float = 0.0                # perf_counter at submit (0: not queued)
+    ctx: object = None              # sampled trace context, else None
     # lane affinity on the 2-D mesh: requests sharing a wave_key (the
     # eval's namespace) chain in ONE lane; distinct keys spread across
     # the mesh's 'wave' columns and score concurrently
@@ -260,7 +265,6 @@ class _PendingBulk:
     deltas_per: List
     mapping: object                 # sharded lane mapping or None
     donated: bool
-    t_dispatch: float
 
 
 class PlacementEngine:
@@ -336,14 +340,6 @@ class PlacementEngine:
         self.overlap = self.donate and \
             knobs.get_bool("NOMAD_TPU_OVERLAP")
         self._pending: Optional[_PendingBulk] = None
-        # (t0, t1) wall windows of in-flight device compute (bulk:
-        # dispatch -> fetch complete) — intersected with upload_windows
-        # (host-side stack/update/dispatch prep) for the bench's
-        # pipeline_overlap_s, and with the applier's commit-fsync
-        # windows for commit_overlap_s, in the device_stages block
-        from collections import deque
-        self.device_windows = deque(maxlen=8192)
-        self.upload_windows = deque(maxlen=8192)
         self._serving_mesh = None
         self._mesh_checked = False
         self._queue: List[_Request] = []
@@ -415,16 +411,18 @@ class PlacementEngine:
         will never be), releasing its in-flight usage contribution."""
         req = _Request(cm=cm, inputs=inputs, deltas=list(deltas or ()),
                        spread_algorithm=spread_algorithm, future=Future())
-        if tracing.active is not None:
-            ctx = tracing.current()
-            if ctx is not None:
-                req.trace = (ctx, _time.time())
+        self._submit(req)
+        with tracing.span("sched.wait_engine", wait=True):
+            return req.future.result()
+
+    def _submit(self, req) -> None:
+        req.ctx = tracing.current()
         with self._cv:
             if self._stop:
                 raise RuntimeError("placement engine stopped")
+            req.enq = _time.perf_counter()
             self._queue.append(req)
             self._cv.notify()
-        return req.future.result()
 
     def place_bulk_begin(self, cm, *, feasible, affinity, has_affinity,
                          desired, penalty, coll0, demand, count,
@@ -451,15 +449,7 @@ class PlacementEngine:
             demand=np.asarray(demand, np.float32), count=int(count),
             deltas=list(deltas or ()), spread_algorithm=spread_algorithm,
             future=Future(), wave_key=str(wave_key))
-        if tracing.active is not None:
-            ctx = tracing.current()
-            if ctx is not None:
-                req.trace = (ctx, _time.time())
-        with self._cv:
-            if self._stop:
-                raise RuntimeError("placement engine stopped")
-            self._queue.append(req)
-            self._cv.notify()
+        self._submit(req)
         return req.future
 
     def place_bulk(self, cm, *, feasible, affinity, has_affinity, desired,
@@ -473,12 +463,13 @@ class PlacementEngine:
         Callers derive usage from `assign` (sparse) — the engine returns
         no usage matrix.  The caller MUST `complete(ticket)` once the
         plan is submitted (ticket may be None if nothing placed)."""
-        return self.place_bulk_begin(
+        fut = self.place_bulk_begin(
             cm, feasible=feasible, affinity=affinity,
             has_affinity=has_affinity, desired=desired, penalty=penalty,
             coll0=coll0, demand=demand, count=count, deltas=deltas,
-            spread_algorithm=spread_algorithm,
-            wave_key=wave_key).result()
+            spread_algorithm=spread_algorithm, wave_key=wave_key)
+        with tracing.span("sched.wait_engine", wait=True):
+            return fut.result()
 
     def warmup(self, cm, inputs: Optional[PlaceInputs] = None,
                bulk: Optional[dict] = None) -> None:
@@ -575,11 +566,19 @@ class PlacementEngine:
             chunk = self._bulk_chunk(cm.n_rows)
             thunks += [(bulk_variant, (E,))
                        for E in self.BULK_E_BUCKETS if E <= chunk]
+        def warm(fn, a):
+            # one span per compiled variant: compile + first execution
+            kind = f"{fn.__name__}:E={a[0]}" + (
+                f":S={a[1].demand.shape[0]}" if len(a) > 1 else "")
+            with tracing.span("engine.warmup", kind=kind) as sp:
+                fn(*a)
+            log.info("engine.warmup %s: %.2fs", kind, sp.seconds)
+
         workers = knobs.get_int("NOMAD_TPU_WARM_THREADS")
         from concurrent.futures import ThreadPoolExecutor
         with ThreadPoolExecutor(
                 max_workers=max(1, min(workers, len(thunks)))) as ex:
-            futs = [ex.submit(fn, *a) for fn, a in thunks]
+            futs = [ex.submit(warm, fn, a) for fn, a in thunks]
             for f in futs:
                 f.result()
         # world scatter pair: the measured window's first dirty-row
@@ -853,13 +852,21 @@ class PlacementEngine:
     def _run(self) -> None:
         while True:
             with self._cv:
-                while not self._queue and not self._stop \
+                if not self._queue and not self._stop \
                         and self._pending is None:
-                    self._cv.wait()
+                    # nothing to dispatch and nothing in flight: this
+                    # thread's idleness IS the device's
+                    with tracing.span("engine.idle", wait=True):
+                        while not self._queue and not self._stop:
+                            self._cv.wait()
                 if self._stop and not self._queue:
                     break
                 batch, self._queue = (self._queue[:self.max_batch],
                                       self._queue[self.max_batch:])
+            now = _time.perf_counter()
+            for r in batch:
+                tracing.record("engine.queue_wait", r.enq, now, wait=True,
+                               ctx=r.ctx)
             if not batch:
                 # idle with a bulk dispatch in flight: nothing arrived
                 # to chain behind it, so fetch + resolve it now
@@ -930,7 +937,6 @@ class PlacementEngine:
                            and world.shape == expected_shape)
                 if self._pending is not None and not chained:
                     self._drain_pending()
-                tp0 = _time.time()
                 if mesh is not None:
                     out, _w, dper, mapping, donated = \
                         self._dispatch_bulk_group_sharded(
@@ -940,13 +946,11 @@ class PlacementEngine:
                     out, _w, dper, donated = self._dispatch_bulk_group(
                         part, world=world, force_scatter=chained)
                     mapping = None
-                tp1 = _time.time()
-                self.upload_windows.append((tp0, tp1))
                 if chained:
                     self.stats["overlap_chained"] += 1
                 prev, self._pending = self._pending, _PendingBulk(
                     reqs=part, out=out, world=world, deltas_per=dper,
-                    mapping=mapping, donated=donated, t_dispatch=tp1)
+                    mapping=mapping, donated=donated)
                 if prev is not None:
                     self._drain_record(prev)
                 if not (self.overlap and donated):
@@ -1013,9 +1017,12 @@ class PlacementEngine:
     def _drain_record(self, p: _PendingBulk) -> None:
         import jax
 
-        t0 = _time.time()
+        ctx = self._ctx_of(p.reqs)
         try:
-            fetched = jax.device_get(p.out)
+            # a host wait: the device works, this thread blocks
+            with tracing.span("engine.device_get", wait=True,
+                              ctx=ctx) as got:
+                fetched = jax.device_get(p.out)
         except Exception as e:                  # noqa: BLE001
             if p.donated and p.world is not None:
                 # the adopted carry is suspect (failed dispatch): the
@@ -1025,21 +1032,18 @@ class PlacementEngine:
                 if not r.future.done():
                     r.future.set_exception(e)
             return
-        t1 = _time.time()
-        dev_s = t1 - t0
-        self.stats["device_s"] += dev_s
-        self.device_windows.append((p.t_dispatch, t1))
-        t0 = _time.time()
+        self.stats["device_s"] += got.seconds
         try:
-            self._resolve_bulk(p.reqs, fetched, p.world, p.deltas_per,
-                               mapping=p.mapping, donated=p.donated)
+            with tracing.span("engine.resolve", ctx=ctx) as sp:
+                self._resolve_bulk(p.reqs, fetched, p.world, p.deltas_per,
+                                   mapping=p.mapping, donated=p.donated)
         except Exception as e:                  # noqa: BLE001
             for r in p.reqs:
                 if not r.future.done():
                     r.future.set_exception(e)
             return
-        self.stats["resolve_s"] += _time.time() - t0
-        self._emit_dispatch_spans(p.reqs, dev_s, "bulk")
+        self.stats["resolve_s"] += sp.seconds
+        self._record_dispatch(p.reqs, got.seconds, "bulk")
         if len(p.reqs) > 1:
             self.stats["batched_evals"] += len(p.reqs)
         else:
@@ -1048,40 +1052,44 @@ class PlacementEngine:
     def _fetch_resolve_scan(self, reqs: List[_Request], packed) -> None:
         import jax
 
-        t0 = _time.time()
-        fetched = jax.device_get(packed)
-        t1 = _time.time()
-        dev_s = t1 - t0
-        self.stats["device_s"] += dev_s
-        self.device_windows.append((t0, t1))
-        t0 = _time.time()
-        node, score, fit_s, n_eval, n_exh, top_n, top_s = \
-            unpack_outputs(np.asarray(fetched))
-        for i, r in enumerate(reqs):
-            res = PlaceResult(
-                node=node[i], score=score[i], fit_score=fit_s[i],
-                nodes_evaluated=n_eval[i], nodes_exhausted=n_exh[i],
-                top_nodes=top_n[i], top_scores=top_s[i], used=None)
-            ticket = self._register(r, res)
-            r.future.set_result((res, ticket))
-        self.stats["resolve_s"] += _time.time() - t0
-        self._emit_dispatch_spans(reqs, dev_s, "scan")
+        ctx = self._ctx_of(reqs)
+        with tracing.span("engine.device_get", wait=True, ctx=ctx) as got:
+            fetched = jax.device_get(packed)
+        self.stats["device_s"] += got.seconds
+        with tracing.span("engine.resolve", ctx=ctx) as sp:
+            node, score, fit_s, n_eval, n_exh, top_n, top_s = \
+                unpack_outputs(np.asarray(fetched))
+            for i, r in enumerate(reqs):
+                res = PlaceResult(
+                    node=node[i], score=score[i], fit_score=fit_s[i],
+                    nodes_evaluated=n_eval[i], nodes_exhausted=n_exh[i],
+                    top_nodes=top_n[i], top_scores=top_s[i], used=None)
+                ticket = self._register(r, res)
+                r.future.set_result((res, ticket))
+        self.stats["resolve_s"] += sp.seconds
+        self._record_dispatch(reqs, got.seconds, "scan")
 
     @staticmethod
-    def _emit_dispatch_spans(reqs: List, dev_s: float, kind: str) -> None:
-        """Per-request device-dispatch spans for sampled evals: the span
-        covers submit -> resolve on the engine thread, with the shared
-        device_get window carried as an attribute (the whole group rides
-        one chained device dispatch)."""
-        tracer = tracing.active
-        if tracer is None:
-            return
-        now = _time.time()
+    def _ctx_of(reqs: List) -> Optional[dict]:
+        """The first sampled request's trace context: a group rides one
+        dispatch, so the engine thread's spans for it join that trace."""
+        if tracing.active is not None:
+            for r in reqs:
+                if r.ctx is not None:
+                    return r.ctx
+        return None
+
+    @staticmethod
+    def _record_dispatch(reqs: List, dev_s: float, kind: str) -> None:
+        """Per request, submit -> resolved, with the shared device_get
+        wait as an attribute (the whole group rides one chained device
+        dispatch)."""
+        now = _time.perf_counter()
         for r in reqs:
-            if r.trace is not None:
-                tracer.emit(r.trace[0], "engine.dispatch", r.trace[1],
-                            now, kind=kind, batch=len(reqs),
-                            device_get_s=round(dev_s, 6))
+            if r.enq:
+                tracing.record("engine.dispatch", r.enq, now, ctx=r.ctx,
+                               kind=kind, batch=len(reqs),
+                               device_get_s=round(dev_s, 6))
 
     # ------------------------------------------------------- sharded path
 
@@ -1138,50 +1146,48 @@ class PlacementEngine:
         N = reqs[0].inputs.capacity.shape[0]
         E = next(b for b in self.E_BUCKETS if b >= len(reqs))
         S = _s_bucket(reqs[0].inputs.demand.shape[0])
-        t0 = _time.time()
-        fields = {}
-        for f in self._SHARD_FIELDS:
-            arrs = [np.asarray(getattr(r.inputs, f)) for r in reqs]
-            if f in ("demand", "slot_tg", "slot_active"):
-                # slot axis padded to the canonical bucket (pads inactive)
-                arrs = [np.concatenate(
-                    [a, np.zeros((S - a.shape[0],) + a.shape[1:],
-                                 a.dtype)]) if a.shape[0] < S else a
-                        for a in arrs]
-            if E > len(reqs):
-                pad = (np.zeros_like(arrs[0])
-                       if f == "slot_active" else arrs[0])
-                arrs += [pad] * (E - len(reqs))
-            fields[f] = np.stack(arrs)
-        basis = self._basis_for(cm)
-        deltas_per = [r.deltas for r in reqs]
-        if fold_deltas:
-            assert len(reqs) == 1
-            deltas_per = [_fold_overflow(basis, reqs[0].deltas)]
-        drows, dvals = self._stack_deltas(
-            deltas_per + [[]] * (E - len(reqs)), E, N)
-        self.stats["stack_s"] += _time.time() - t0
-        t0 = _time.time()
-        # content-addressed sharded placement: identical job-state
-        # batches (the common case) ship zero bytes; basis/deltas always
-        # ship (they change every dispatch and are small)
-        from jax.sharding import NamedSharding
-        from nomad_tpu.parallel.sharded import _field_specs_batched
-        fshard = {k: NamedSharding(mesh, s)
-                  for k, s in _field_specs_batched().items()}
-        fields_dev = self._cache.sharded("scan", mesh, fields, fshard)
-        t1 = _time.time()
-        # device-resident world: capacity/basis live sharded across the
-        # mesh; update() ships only the rows that changed since the last
-        # dispatch (the overlay contributions of the previous cycle)
-        cap_dev, basis_dev = self._world(cm, N, mesh).update(
-            cm.capacity, basis)
-        self.stats["put_basis_s"] = self.stats.get("put_basis_s", 0.0) \
-            + (_time.time() - t1)
-        packed, _used = place_batch_sharded(
-            mesh, cap_dev, basis_dev, fields_dev,
-            drows, dvals, spread_algorithm=reqs[0].spread_algorithm)
-        self.stats["put_s"] += _time.time() - t0
+        ctx = self._ctx_of(reqs)
+        with tracing.span("engine.stack", ctx=ctx) as sp:
+            fields = {}
+            for f in self._SHARD_FIELDS:
+                arrs = [np.asarray(getattr(r.inputs, f)) for r in reqs]
+                if f in ("demand", "slot_tg", "slot_active"):
+                    # slot axis padded to the canonical bucket (pads inactive)
+                    arrs = [np.concatenate(
+                        [a, np.zeros((S - a.shape[0],) + a.shape[1:],
+                                     a.dtype)]) if a.shape[0] < S else a
+                            for a in arrs]
+                if E > len(reqs):
+                    pad = (np.zeros_like(arrs[0])
+                           if f == "slot_active" else arrs[0])
+                    arrs += [pad] * (E - len(reqs))
+                fields[f] = np.stack(arrs)
+            basis = self._basis_for(cm)
+            deltas_per = [r.deltas for r in reqs]
+            if fold_deltas:
+                assert len(reqs) == 1
+                deltas_per = [_fold_overflow(basis, reqs[0].deltas)]
+            drows, dvals = self._stack_deltas(
+                deltas_per + [[]] * (E - len(reqs)), E, N)
+        self.stats["stack_s"] += sp.seconds
+        with tracing.span("engine.put", ctx=ctx) as sp:
+            # content-addressed sharded placement: identical job-state
+            # batches (the common case) ship zero bytes; basis/deltas always
+            # ship (they change every dispatch and are small)
+            from jax.sharding import NamedSharding
+            from nomad_tpu.parallel.sharded import _field_specs_batched
+            fshard = {k: NamedSharding(mesh, s)
+                      for k, s in _field_specs_batched().items()}
+            fields_dev = self._cache.sharded("scan", mesh, fields, fshard)
+            # device-resident world: capacity/basis live sharded across the
+            # mesh; update() ships only the rows that changed since the last
+            # dispatch (the overlay contributions of the previous cycle)
+            cap_dev, basis_dev = self._world(cm, N, mesh).update(
+                cm.capacity, basis)
+            packed, _used = place_batch_sharded(
+                mesh, cap_dev, basis_dev, fields_dev,
+                drows, dvals, spread_algorithm=reqs[0].spread_algorithm)
+        self.stats["put_s"] += sp.seconds
         self.stats["sharded_evals"] = (
             self.stats.get("sharded_evals", 0) + len(reqs))
         return packed
@@ -1244,102 +1250,95 @@ class PlacementEngine:
         self.stats["lane_evals"] += len(reqs)
         self.stats["lane_slots"] += W * E
 
-        t0 = _time.time()
-        # content key from per-request digests (packbits + zero-marker
-        # fast paths) — cheaper than hashing the stacked [W, E, N]
-        # tensors, and a hit skips even BUILDING the host stacks.  The
-        # per-lane tuples make the key sensitive to lane layout.
-        r00 = reqs[0]
-        digs = tuple(tuple(
-            bulk_heavy_digest(r.feasible, r.affinity, r.penalty, r.coll0)
-            for r in b) for b in bins)
-        meta = tuple(tuple(
-            (np.asarray(r.demand, np.float32).tobytes(),
-             bool(r.has_affinity), int(r.desired)) for r in b)
-            for b in bins)
+        ctx = self._ctx_of(reqs)
+        with tracing.span("engine.stack", ctx=ctx) as sp:
+            # content key from per-request digests (packbits + zero-marker
+            # fast paths) — cheaper than hashing the stacked [W, E, N]
+            # tensors, and a hit skips even BUILDING the host stacks.  The
+            # per-lane tuples make the key sensitive to lane layout.
+            r00 = reqs[0]
+            digs = tuple(tuple(
+                bulk_heavy_digest(r.feasible, r.affinity, r.penalty, r.coll0)
+                for r in b) for b in bins)
+            meta = tuple(tuple(
+                (np.asarray(r.demand, np.float32).tobytes(),
+                 bool(r.has_affinity), int(r.desired)) for r in b)
+                for b in bins)
 
-        def build_stacks():
-            def lane_stack(get, dt, pad_val=None):
-                rows = []
-                for b in bins:
-                    fill = b[0] if b else r00
-                    lane = [np.asarray(get(r), dt) for r in b]
-                    pad_a = np.asarray(get(fill), dt) \
-                        if pad_val is None else pad_val
-                    lane += [pad_a] * (E - len(b))
-                    rows.append(np.stack(lane) if lane[0].ndim
-                                else np.array(lane, dt))
-                return np.stack(rows)
-            feas = lane_stack(lambda r: r.feasible, bool)
-            aff = lane_stack(lambda r: r.affinity, np.float32)
-            pen = lane_stack(lambda r: r.penalty, bool)
-            coll = lane_stack(lambda r: r.coll0, np.int32)
-            dem = lane_stack(lambda r: r.demand, np.float32)
-            hasa = np.stack([np.array(
-                [r.has_affinity for r in b] + [False] * (E - len(b)),
-                bool) for b in bins])
-            des = np.stack([np.array(
-                [r.desired for r in b] + [1] * (E - len(b)), np.int32)
+            def build_stacks():
+                def lane_stack(get, dt, pad_val=None):
+                    rows = []
+                    for b in bins:
+                        fill = b[0] if b else r00
+                        lane = [np.asarray(get(r), dt) for r in b]
+                        pad_a = np.asarray(get(fill), dt) \
+                            if pad_val is None else pad_val
+                        lane += [pad_a] * (E - len(b))
+                        rows.append(np.stack(lane) if lane[0].ndim
+                                    else np.array(lane, dt))
+                    return np.stack(rows)
+                feas = lane_stack(lambda r: r.feasible, bool)
+                aff = lane_stack(lambda r: r.affinity, np.float32)
+                pen = lane_stack(lambda r: r.penalty, bool)
+                coll = lane_stack(lambda r: r.coll0, np.int32)
+                dem = lane_stack(lambda r: r.demand, np.float32)
+                hasa = np.stack([np.array(
+                    [r.has_affinity for r in b] + [False] * (E - len(b)),
+                    bool) for b in bins])
+                des = np.stack([np.array(
+                    [r.desired for r in b] + [1] * (E - len(b)), np.int32)
+                    for b in bins])
+                return feas, aff, pen, coll, dem, hasa, des
+
+            # padded evals have count=0: the wavefront exits immediately
+            cnt = np.stack([np.array(
+                [r.count for r in b] + [0] * (E - len(b)), np.int32)
                 for b in bins])
-            return feas, aff, pen, coll, dem, hasa, des
-
-        # padded evals have count=0: the wavefront exits immediately
-        cnt = np.stack([np.array(
-            [r.count for r in b] + [0] * (E - len(b)), np.int32)
-            for b in bins])
-        lane_drows, lane_dvals = [], []
-        for db in deltas_bins:
-            dr, dv = self._stack_deltas(
-                list(db) + [[]] * (E - len(db)), E, N)
-            lane_drows.append(dr)
-            lane_dvals.append(dv)
-        drows = np.stack(lane_drows)
-        dvals = np.stack(lane_dvals)
-        self.stats["stack_s"] += _time.time() - t0
-        t0 = _time.time()
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as _P
-        lane3 = NamedSharding(
-            mesh, _P(WAVE_AXIS_NAME, None, NODE_AXIS_NAME))
-        lane2 = NamedSharding(mesh, _P(WAVE_AXIS_NAME, None))
-        lane2r = NamedSharding(mesh, _P(WAVE_AXIS_NAME, None, None))
-        feas, aff, pen, coll, dem, hasa, des = self._cache.sharded(
-            "bulk", mesh, build_stacks,
-            (lane3, lane3, lane3, lane3, lane2r, lane2, lane2),
-            key=("bulkstack", N, W, E, digs, meta))
-        self.stats["put_heavy_s"] = self.stats.get("put_heavy_s", 0.0) \
-            + (_time.time() - t0)
-        t1 = _time.time()
-        # device-resident world: one full upload per cluster epoch, then
-        # dirty-row scatters; steady state ships zero basis bytes because
-        # _resolve_bulk pre-applied the placements (apply_rank1, or the
-        # donated carry + apply_rank1_host)
-        world = world if world is not None else self._world(cm, N, mesh)
-        cap_dev, basis_dev = world.update(capacity, basis,
-                                          force_scatter=force_scatter)
-        if donate:
-            loaned = world.loan_basis()
-            if loaned is not None:
-                basis_dev = loaned
-            else:
-                donate = False
-        self.stats["put_basis_s"] = self.stats.get("put_basis_s", 0.0) \
-            + (_time.time() - t1)
-        t1 = _time.time()
-        from nomad_tpu.ops.place import fill_grid_for
-        out = place_bulk_batch_sharded(
-            mesh, cap_dev, basis_dev,
-            feas, aff, hasa, des, pen, coll, dem, cnt,
-            drows, dvals, spread_algorithm=reqs[0].spread_algorithm,
-            fill_grid=fill_grid_for(max(r.count for r in reqs)),
-            donate=donate)
-        assign, scores, placed, n_eval, n_exh, waves, used_tot = out
-        if donate:
-            world.adopt_basis(used_tot)
-            self.stats["donated_carries"] += 1
-        self.stats["put_kernel_s"] = self.stats.get("put_kernel_s", 0.0) \
-            + (_time.time() - t1)
-        self.stats["put_s"] += _time.time() - t0
+            lane_drows, lane_dvals = [], []
+            for db in deltas_bins:
+                dr, dv = self._stack_deltas(
+                    list(db) + [[]] * (E - len(db)), E, N)
+                lane_drows.append(dr)
+                lane_dvals.append(dv)
+            drows = np.stack(lane_drows)
+            dvals = np.stack(lane_dvals)
+        self.stats["stack_s"] += sp.seconds
+        with tracing.span("engine.put", ctx=ctx) as sp:
+            from jax.sharding import NamedSharding
+            from jax.sharding import PartitionSpec as _P
+            lane3 = NamedSharding(
+                mesh, _P(WAVE_AXIS_NAME, None, NODE_AXIS_NAME))
+            lane2 = NamedSharding(mesh, _P(WAVE_AXIS_NAME, None))
+            lane2r = NamedSharding(mesh, _P(WAVE_AXIS_NAME, None, None))
+            feas, aff, pen, coll, dem, hasa, des = self._cache.sharded(
+                "bulk", mesh, build_stacks,
+                (lane3, lane3, lane3, lane3, lane2r, lane2, lane2),
+                key=("bulkstack", N, W, E, digs, meta))
+            # device-resident world: one full upload per cluster epoch, then
+            # dirty-row scatters; steady state ships zero basis bytes because
+            # _resolve_bulk pre-applied the placements (apply_rank1, or the
+            # donated carry + apply_rank1_host)
+            world = world if world is not None else self._world(cm, N, mesh)
+            cap_dev, basis_dev = world.update(capacity, basis,
+                                              force_scatter=force_scatter)
+            if donate:
+                loaned = world.loan_basis()
+                if loaned is not None:
+                    basis_dev = loaned
+                else:
+                    donate = False
+            from nomad_tpu.ops.place import fill_grid_for
+            out = place_bulk_batch_sharded(
+                mesh, cap_dev, basis_dev,
+                feas, aff, hasa, des, pen, coll, dem, cnt,
+                drows, dvals, spread_algorithm=reqs[0].spread_algorithm,
+                fill_grid=fill_grid_for(max(r.count for r in reqs)),
+                donate=donate)
+            assign, scores, placed, n_eval, n_exh, waves, used_tot = out
+            if donate:
+                world.adopt_basis(used_tot)
+                self.stats["donated_carries"] += 1
+        self.stats["put_s"] += sp.seconds
         self.stats["sharded_evals"] = (
             self.stats.get("sharded_evals", 0) + len(reqs))
         return (assign, scores, placed, n_eval, n_exh, waves), \
@@ -1419,74 +1418,67 @@ class PlacementEngine:
         # case; _split_bulk separates delta-free parts)
         D = _DELTA_BUCKET if any(deltas_per) else 0
 
-        t0 = _time.time()
-        lights = [pack_bulk_light(r.has_affinity, r.desired, r.count,
-                                  r.demand, ds, N, D)
-                  for r, ds in zip(reqs, deltas_per)]
-        Ll = lights[0].shape[0]
-        if E > len(reqs):
-            # padded evals have count=0: the wavefront loop exits at once
-            lights += [np.zeros(Ll, np.float32)] * (E - len(reqs))
-        dyn = np.concatenate(lights)
-        self.stats["stack_s"] += _time.time() - t0
-        t0 = _time.time()
-        # device-resident world: epoch upload once, dirty-row scatters
-        # after; steady state ships zero basis bytes (apply_rank1 in
-        # _resolve_bulk keeps device and host snapshot in lockstep; on
-        # the donated path the kernel's exact carry IS the new resident
-        # basis and only the host snapshot catches up)
-        world = world if world is not None else self._world(cm, N)
-        cap_dev, used_dev = world.update(capacity, basis,
-                                         force_scatter=force_scatter)
-        if donate:
-            loaned = world.loan_basis()
-            if loaned is not None:
-                used_dev = loaned
+        ctx = self._ctx_of(reqs)
+        with tracing.span("engine.stack", ctx=ctx) as sp:
+            lights = [pack_bulk_light(r.has_affinity, r.desired, r.count,
+                                      r.demand, ds, N, D)
+                      for r, ds in zip(reqs, deltas_per)]
+            Ll = lights[0].shape[0]
+            if E > len(reqs):
+                # padded evals have count=0: the wavefront loop exits at once
+                lights += [np.zeros(Ll, np.float32)] * (E - len(reqs))
+            dyn = np.concatenate(lights)
+        self.stats["stack_s"] += sp.seconds
+        with tracing.span("engine.put", ctx=ctx) as sp:
+            # device-resident world: epoch upload once, dirty-row scatters
+            # after; steady state ships zero basis bytes (apply_rank1 in
+            # _resolve_bulk keeps device and host snapshot in lockstep; on
+            # the donated path the kernel's exact carry IS the new resident
+            # basis and only the host snapshot catches up)
+            world = world if world is not None else self._world(cm, N)
+            cap_dev, used_dev = world.update(capacity, basis,
+                                             force_scatter=force_scatter)
+            if donate:
+                loaned = world.loan_basis()
+                if loaned is not None:
+                    used_dev = loaned
+                else:
+                    donate = False
+            digs = tuple(bulk_heavy_digest(r.feasible, r.affinity, r.penalty,
+                                           r.coll0) for r in reqs)
+            heavy = [self._cache.bulk_heavy(r, dig)
+                     for r, dig in zip(reqs, digs)]
+            heavy += [heavy[0]] * (E - len(reqs))
+            # the stacked [E, 4N] chain is itself content-addressed: C2M's
+            # identical-content evals re-dispatch the same stack every wave,
+            # and the jnp.stack launch was the dominant put_kernel_s cost
+            import jax.numpy as jnp
+            hstack = self._cache.stack(("hstack", N, E, digs),
+                                       lambda: jnp.stack(heavy))
+            self.stats["cache_hits"] = self._cache.hits
+            self.stats["cache_misses"] = self._cache.misses
+            dyn_dev = jax.device_put(dyn)  # analysis: allow(transfer-purity) — per-dispatch dynamic leaf, shipped explicitly
+            sparse = all(r.count <= SPARSE_CAP for r in reqs)
+            from nomad_tpu.ops.place import fill_grid_for
+            fill_grid = fill_grid_for(max(r.count for r in reqs))
+            if donate:
+                # exact_out: the adopted basis is the rank-1 reconstruction
+                # (bitwise what apply_rank1 would have scattered), while the
+                # scan's own carry keeps chain-scoring parity
+                packed, _used_final, used_exact = place_bulk_batch_donate_jit(
+                    cap_dev, used_dev, hstack, dyn_dev, D,
+                    sparse_out=sparse,
+                    spread_algorithm=reqs[0].spread_algorithm,
+                    fill_grid=fill_grid, exact_out=True)
+                world.adopt_basis(used_exact)
+                self.stats["donated_carries"] += 1
             else:
-                donate = False
-        self.stats["put_basis_s"] = self.stats.get("put_basis_s", 0.0) \
-            + (_time.time() - t0)
-        t1 = _time.time()
-        digs = tuple(bulk_heavy_digest(r.feasible, r.affinity, r.penalty,
-                                       r.coll0) for r in reqs)
-        heavy = [self._cache.bulk_heavy(r, dig)
-                 for r, dig in zip(reqs, digs)]
-        heavy += [heavy[0]] * (E - len(reqs))
-        # the stacked [E, 4N] chain is itself content-addressed: C2M's
-        # identical-content evals re-dispatch the same stack every wave,
-        # and the jnp.stack launch was the dominant put_kernel_s cost
-        import jax.numpy as jnp
-        hstack = self._cache.stack(("hstack", N, E, digs),
-                                   lambda: jnp.stack(heavy))
-        self.stats["put_heavy_s"] = self.stats.get("put_heavy_s", 0.0) \
-            + (_time.time() - t1)
-        self.stats["cache_hits"] = self._cache.hits
-        self.stats["cache_misses"] = self._cache.misses
-        t1 = _time.time()
-        dyn_dev = jax.device_put(dyn)  # analysis: allow(transfer-purity) — per-dispatch dynamic leaf, shipped explicitly
-        sparse = all(r.count <= SPARSE_CAP for r in reqs)
-        from nomad_tpu.ops.place import fill_grid_for
-        fill_grid = fill_grid_for(max(r.count for r in reqs))
-        if donate:
-            # exact_out: the adopted basis is the rank-1 reconstruction
-            # (bitwise what apply_rank1 would have scattered), while the
-            # scan's own carry keeps chain-scoring parity
-            packed, _used_final, used_exact = place_bulk_batch_donate_jit(
-                cap_dev, used_dev, hstack, dyn_dev, D,
-                sparse_out=sparse,
-                spread_algorithm=reqs[0].spread_algorithm,
-                fill_grid=fill_grid, exact_out=True)
-            world.adopt_basis(used_exact)
-            self.stats["donated_carries"] += 1
-        else:
-            packed, _used_final = place_bulk_batch_jit(
-                cap_dev, used_dev, hstack, dyn_dev, D,
-                sparse_out=sparse,
-                spread_algorithm=reqs[0].spread_algorithm,
-                fill_grid=fill_grid)
-        self.stats["put_kernel_s"] = self.stats.get("put_kernel_s", 0.0) \
-            + (_time.time() - t1)
-        self.stats["put_s"] += _time.time() - t0
+                packed, _used_final = place_bulk_batch_jit(
+                    cap_dev, used_dev, hstack, dyn_dev, D,
+                    sparse_out=sparse,
+                    spread_algorithm=reqs[0].spread_algorithm,
+                    fill_grid=fill_grid)
+        self.stats["put_s"] += sp.seconds
         return packed, world, deltas_per, donate
 
     def _resolve_bulk(self, reqs: List[_BulkRequest], packed: np.ndarray,
@@ -1557,7 +1549,6 @@ class PlacementEngine:
         """Lone request: packed E=1 dispatch through the same device
         cache.  Still scores against the in-flight overlay basis so
         concurrent-but-unbatched evals don't collide."""
-        import jax
         try:
             if r.cm.used.shape[0] == r.inputs.used.shape[0]:
                 basis = self._basis_for(r.cm)
@@ -1575,14 +1566,7 @@ class PlacementEngine:
             packed = self._dispatch_packed(
                 [r], E=1, basis=basis, deltas_per_req=[deltas],
                 capacity=cap_src)
-            node, score, fit_s, n_eval, n_exh, top_n, top_s = \
-                unpack_outputs(np.asarray(jax.device_get(packed)))
-            res = PlaceResult(
-                node=node[0], score=score[0], fit_score=fit_s[0],
-                nodes_evaluated=n_eval[0], nodes_exhausted=n_exh[0],
-                top_nodes=top_n[0], top_scores=top_s[0], used=None)
-            ticket = self._register(r, res)
-            r.future.set_result((res, ticket))
+            self._fetch_resolve_scan([r], packed)
         except Exception as e:                  # noqa: BLE001
             r.future.set_exception(e)
 
@@ -1609,29 +1593,30 @@ class PlacementEngine:
         R = NUM_RESOURCE_DIMS
         D = _DELTA_BUCKET
 
-        t0 = _time.time()
-        lights = [pack_light(r.inputs, d, D, S)
-                  for r, d in zip(reqs, deltas_per_req)]
-        Ll = lights[0].shape[0]
-        if E > len(reqs):
-            lights += [np.zeros(Ll, np.float32)] * (E - len(reqs))
-        basis = np.ascontiguousarray(basis, dtype=np.float32)
-        dyn = np.concatenate(lights)
-        self.stats["stack_s"] += _time.time() - t0
+        ctx = self._ctx_of(reqs)
+        with tracing.span("engine.stack", ctx=ctx) as sp:
+            lights = [pack_light(r.inputs, d, D, S)
+                      for r, d in zip(reqs, deltas_per_req)]
+            Ll = lights[0].shape[0]
+            if E > len(reqs):
+                lights += [np.zeros(Ll, np.float32)] * (E - len(reqs))
+            basis = np.ascontiguousarray(basis, dtype=np.float32)
+            dyn = np.concatenate(lights)
+        self.stats["stack_s"] += sp.seconds
         # cache resolution inside the put window: misses device_put the
         # heavy bytes, and that transfer cost belongs in put_s
-        t0 = _time.time()
-        cap_dev, used_dev = self._world(
-            reqs[0].cm, basis.shape[0]).update(capacity, basis)
-        heavy = [self._cache.heavy(r.inputs) for r in reqs]
-        heavy += [heavy[0]] * (E - len(reqs))   # pads place nothing
-        self.stats["cache_hits"] = self._cache.hits
-        self.stats["cache_misses"] = self._cache.misses
-        dyn_dev = jax.device_put(dyn)  # analysis: allow(transfer-purity) — per-dispatch dynamic leaf (basis deltas + light blocks): payload that must ship, sent explicitly so the runtime guard stays armed
-        packed, _used_final = place_batch_packed_jit(
-            cap_dev, used_dev, tuple(heavy), dyn_dev, (G, N, K, Vp1, S, D),
-            spread_algorithm=reqs[0].spread_algorithm)
-        self.stats["put_s"] += _time.time() - t0
+        with tracing.span("engine.put", ctx=ctx) as sp:
+            cap_dev, used_dev = self._world(
+                reqs[0].cm, basis.shape[0]).update(capacity, basis)
+            heavy = [self._cache.heavy(r.inputs) for r in reqs]
+            heavy += [heavy[0]] * (E - len(reqs))   # pads place nothing
+            self.stats["cache_hits"] = self._cache.hits
+            self.stats["cache_misses"] = self._cache.misses
+            dyn_dev = jax.device_put(dyn)  # analysis: allow(transfer-purity) — per-dispatch dynamic leaf (basis deltas + light blocks): payload that must ship, sent explicitly so the runtime guard stays armed
+            packed, _used_final = place_batch_packed_jit(
+                cap_dev, used_dev, tuple(heavy), dyn_dev, (G, N, K, Vp1, S, D),
+                spread_algorithm=reqs[0].spread_algorithm)
+        self.stats["put_s"] += sp.seconds
         return packed
 
 

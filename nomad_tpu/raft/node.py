@@ -609,8 +609,6 @@ class RaftNode:
               timeout: float = 10.0) -> int:
         """Append + replicate + commit + FSM-apply one entry; returns its
         log index (reference raft.Apply)."""
-        tracer = tracing.active
-        tctx = tracing.current() if tracer is not None else None
         with self._lock:
             if self.state != LEADER:
                 raise NotLeaderError(self.leader_id)
@@ -625,14 +623,14 @@ class RaftNode:
             # caller-side mutation of the proposal can never alias FSM state.
             entry = LogEntry(index, self.term, msg_type,
                              pickle.loads(pickle.dumps(payload)))
-            t0 = time.time() if tctx is not None else 0.0
-            self.log.append(entry)
+            # propose-time: the WAL append (including its fsync) is a
+            # span, and the index->context note lets _run_apply open the
+            # fsm-apply span under the proposer's sampled context
+            # without touching the payload
+            with tracing.span("raft.append", node=self.name, index=index):
+                self.log.append(entry)
+            tctx = tracing.current()
             if tctx is not None:
-                # propose-time: the WAL append (including its fsync) is
-                # a span, and the index->context note lets _run_apply
-                # emit the fsm-apply span without touching the payload
-                tracer.emit(tctx, "raft.append", t0, time.time(),
-                            node=self.name, index=index)
                 if len(self._trace_notes) > 1024:
                     self._trace_notes.clear()   # leadership-churn strays
                 self._trace_notes[index] = tctx
@@ -640,13 +638,11 @@ class RaftNode:
             fut: concurrent.futures.Future = concurrent.futures.Future()
             self._futures[index] = fut
             self._advance_commit()    # sole-voter clusters commit locally
-        t1 = time.time() if tctx is not None else 0.0
-        self._replicate_all()
-        fut.result(timeout=timeout)
-        if tctx is not None:
-            # replicate + quorum commit + local FSM apply wait
-            tracer.emit(tctx, "raft.commit", t1, time.time(),
-                        node=self.name, index=index)
+        # replicate + quorum commit + local FSM apply wait
+        with tracing.span("raft.commit", wait=True, node=self.name,
+                          index=index):
+            self._replicate_all()
+            fut.result(timeout=timeout)
         return index
 
     def proposal_depth(self) -> int:
@@ -1408,27 +1404,30 @@ class RaftNode:
                     if i <= self.last_applied:   # snapshot raced us
                         continue
                     tctx = self._trace_notes.pop(i, None)
-                tracer = tracing.active
-                ta = time.time() if tctx is not None else 0.0
-                try:
-                    if chaos.active is not None \
-                            and e.msg_type not in _APPLY_SKIP_EXEMPT \
-                            and e.index > self._boot_log_end \
-                            and chaos.should("fsm.apply_skip", self.name):
-                        # injected divergence: the committed entry is
-                        # silently NOT applied while last_applied still
-                        # advances — the log says it happened, the state
-                        # says it didn't.  Invisible to raft; only the
-                        # integrity plane's digest checkpoints can tell.
-                        log.warning("chaos: %s skipped fsm apply of %s "
-                                    "at %d", self.name, e.msg_type,
-                                    e.index)
-                    else:
-                        self.fsm.apply(e.index, e.msg_type, e.payload)
-                    err = None
-                except Exception as exc:           # noqa: BLE001
-                    log.exception("fsm apply failed at %d", e.index)
-                    err = exc
+                # the span brackets the FSM call from outside: the FSM
+                # itself never reads the clock
+                with tracing.span("raft.fsm_apply", ctx=tctx,
+                                  node=self.name, index=i,
+                                  msg_type=e.msg_type):
+                    try:
+                        if chaos.active is not None \
+                                and e.msg_type not in _APPLY_SKIP_EXEMPT \
+                                and e.index > self._boot_log_end \
+                                and chaos.should("fsm.apply_skip", self.name):
+                            # injected divergence: the committed entry is
+                            # silently NOT applied while last_applied still
+                            # advances — the log says it happened, the state
+                            # says it didn't.  Invisible to raft; only the
+                            # integrity plane's digest checkpoints can tell.
+                            log.warning("chaos: %s skipped fsm apply of %s "
+                                        "at %d", self.name, e.msg_type,
+                                        e.index)
+                        else:
+                            self.fsm.apply(e.index, e.msg_type, e.payload)
+                        err = None
+                    except Exception as exc:           # noqa: BLE001
+                        log.exception("fsm apply failed at %d", e.index)
+                        err = exc
                 if err is None and chaos.active is not None \
                         and e.index > self._boot_log_end \
                         and chaos.should("store.bitflip", self.name):
@@ -1450,12 +1449,6 @@ class RaftNode:
                     except Exception:               # noqa: BLE001
                         log.exception("integrity checkpoint at %d "
                                       "failed", e.index)
-                if tctx is not None and tracer is not None:
-                    # observe-time: timestamps taken around the FSM call,
-                    # never inside it (the FSM must not read the clock)
-                    tracer.emit(tctx, "raft.fsm_apply", ta, time.time(),
-                                node=self.name, index=i,
-                                msg_type=e.msg_type)
                 with self._lock:
                     self.last_applied = max(self.last_applied, i)
                     fut = self._futures.pop(i, None)

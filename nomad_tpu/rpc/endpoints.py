@@ -122,72 +122,66 @@ class Endpoints:
         # thread for the duration of the dispatch so downstream code —
         # plan enqueue, raft apply — can attach child spans
         tctx = args.pop(tracing.TRACE_KEY, None)
-        tracer = tracing.active
-        tspan = tprev = None
-        if tracer is not None and tctx is not None:
-            tspan = tracer.start(tctx, f"rpc.{method}", self.server.name)
-            tprev = tracing.bind(tracer.child_ctx(tctx, tspan))
-        # per-request consistency on read RPCs (reference QueryOptions
-        # riding every RPC): establish the read point before dispatch so
-        # the handler's plain store reads serve at it
-        mode = args.pop("consistency", None)
-        # a read point the HTTP tier already established rides along as
-        # `_read_mode`: it classifies the request for brownout shedding
-        # (stale sheds last) without triggering a second begin_read
-        shed_mode = args.pop("_read_mode", None) or mode
-        # request deadline (absent = unbounded): decode the relative
-        # wire budget into a local monotonic deadline and bind it for
-        # the dispatch so every queueing stage downstream can check it
-        dwire = args.pop(deadline.DEADLINE_KEY, None)
-        dprev = None
-        dbound = dwire is not None
-        if dbound:
-            dprev = deadline.bind(deadline.from_wire(dwire))
-        try:
-            if deadline.check("rpc"):
-                raise RpcError(
-                    "deadline_exceeded",
-                    f"{method}: budget exhausted before dispatch")
-            # leader brownout: refuse sheddable classes with an honest
-            # 503 before any queueing or raft work happens for them
-            brownout = getattr(self.server, "brownout", None)
-            if brownout is not None:
-                retry = brownout.shed(method, shed_mode or "default")
-                if retry is not None:
-                    raise RpcError(
-                        "brownout",
-                        f"{method}: leader shedding load",
-                        retry_after=retry)
-            if mode is not None:
-                from nomad_tpu.serving.gate import READ_METHODS
-                if method in READ_METHODS:
-                    # the read gate is a queueing stage: a bound request
-                    # budget caps how long establishing the read point
-                    # may retry across vacant leadership (the gate's own
-                    # 5s cap otherwise outlives a 1s request many times)
-                    rem = deadline.remaining()
-                    try:
-                        if rem is not None:
-                            self.server.serving_gate.begin_read(
-                                mode, timeout=min(5.0, max(0.05, rem)))
-                        else:
-                            self.server.serving_gate.begin_read(mode)
-                    except TimeoutError:
-                        if deadline.check("read_gate"):
-                            raise RpcError(
-                                "deadline_exceeded",
-                                f"{method}: read point not established "
-                                f"inside the request budget")
-                        raise
-            return fn(args)
-        except NotLeaderError as e:
-            raise RpcError("not_leader", leader=e.leader)
-        finally:
+        with tracing.span(f"rpc.{method}", ctx=tctx,
+                          node=self.server.name):
+            # per-request consistency on read RPCs (reference QueryOptions
+            # riding every RPC): establish the read point before dispatch so
+            # the handler's plain store reads serve at it
+            mode = args.pop("consistency", None)
+            # a read point the HTTP tier already established rides along as
+            # `_read_mode`: it classifies the request for brownout shedding
+            # (stale sheds last) without triggering a second begin_read
+            shed_mode = args.pop("_read_mode", None) or mode
+            # request deadline (absent = unbounded): decode the relative
+            # wire budget into a local monotonic deadline and bind it for
+            # the dispatch so every queueing stage downstream can check it
+            dwire = args.pop(deadline.DEADLINE_KEY, None)
+            dprev = None
+            dbound = dwire is not None
             if dbound:
-                deadline.bind(dprev)
-            if tspan is not None:
-                tracer.finish(tspan)
-                tracing.bind(tprev)
+                dprev = deadline.bind(deadline.from_wire(dwire))
+            try:
+                if deadline.check("rpc"):
+                    raise RpcError(
+                        "deadline_exceeded",
+                        f"{method}: budget exhausted before dispatch")
+                # leader brownout: refuse sheddable classes with an honest
+                # 503 before any queueing or raft work happens for them
+                brownout = getattr(self.server, "brownout", None)
+                if brownout is not None:
+                    retry = brownout.shed(method, shed_mode or "default")
+                    if retry is not None:
+                        raise RpcError(
+                            "brownout",
+                            f"{method}: leader shedding load",
+                            retry_after=retry)
+                if mode is not None:
+                    from nomad_tpu.serving.gate import READ_METHODS
+                    if method in READ_METHODS:
+                        # the read gate is a queueing stage: a bound request
+                        # budget caps how long establishing the read point
+                        # may retry across vacant leadership (the gate's own
+                        # 5s cap otherwise outlives a 1s request many times)
+                        rem = deadline.remaining()
+                        try:
+                            if rem is not None:
+                                self.server.serving_gate.begin_read(
+                                    mode, timeout=min(5.0, max(0.05, rem)))
+                            else:
+                                self.server.serving_gate.begin_read(mode)
+                        except TimeoutError:
+                            if deadline.check("read_gate"):
+                                raise RpcError(
+                                    "deadline_exceeded",
+                                    f"{method}: read point not established "
+                                    f"inside the request budget")
+                            raise
+                return fn(args)
+            except NotLeaderError as e:
+                raise RpcError("not_leader", leader=e.leader)
+            finally:
+                if dbound:
+                    deadline.bind(dprev)
 
     def methods(self):
         return sorted(self._methods)
@@ -580,14 +574,12 @@ class Endpoints:
         # (reference eval_endpoint.go Dequeue GetWaitIndex).
         resp = {"eval": ev, "token": token,
                 "wait_index": self.server.store.latest_index}
-        tracer = tracing.active
-        if tracer is not None:
-            # hand the eval's sampled trace context (re-noted by the
-            # broker at dequeue, after the queue-wait span) to the
-            # remote worker so scheduling spans join the trace
-            note = tracer.take_eval_note(ev.id)
-            if note is not None:
-                resp["trace"] = note[0]
+        # hand the eval's sampled trace context (re-noted by the broker
+        # at dequeue, after the queue-wait span) to the remote worker so
+        # scheduling spans join the trace
+        ctx = tracing.take_eval_ctx(ev.id)
+        if ctx is not None:
+            resp["trace"] = ctx
         return resp
 
     def rpc_Eval__Ack(self, args):

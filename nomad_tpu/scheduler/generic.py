@@ -16,6 +16,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from nomad_tpu import tracing
 from nomad_tpu.encode.matrixizer import comparable_vec
 
 from nomad_tpu.scheduler import factory
@@ -140,7 +141,8 @@ class GenericScheduler:
             batch=self.batch,
             eval_priority=ev.priority,
         )
-        results = reconciler.compute()
+        with tracing.span("sched.reconcile"):
+            results = reconciler.compute()
 
         # follow-up (delayed) evals must exist before allocs reference them
         for evs in results.desired_followup_evals.values():
@@ -355,7 +357,9 @@ class GenericScheduler:
         self._stack = stack
         job = self.job
         tg_index = {tg.name: i for i, tg in enumerate(job.task_groups)}
-        groups = [stack.compile_group(job, tg) for tg in job.task_groups]
+        with tracing.span("sched.feasible"):
+            groups = [stack.compile_group(job, tg)
+                      for tg in job.task_groups]
         # constraint-only union, NOT g.feasible: readiness and capacity
         # are transient, and a blocked eval keyed on them would mark its
         # class ineligible forever (a down node or full device must not
@@ -463,12 +467,15 @@ class GenericScheduler:
             bulk_results.append((gi, prs, bulk))
             if ticket is not None:
                 self._ext_tickets.append(ticket)
-        for gi, prs, fut in pending_bulk:
-            assign, placed, n_eval, n_exh, scores, ticket = fut.result()
-            bulk_results.append(
-                (gi, prs, (assign, placed, n_eval, n_exh, scores)))
-            if ticket is not None:
-                self._ext_tickets.append(ticket)
+        if pending_bulk:
+            with tracing.span("sched.wait_engine", wait=True):
+                for gi, prs, fut in pending_bulk:
+                    assign, placed, n_eval, n_exh, scores, ticket = \
+                        fut.result()
+                    bulk_results.append(
+                        (gi, prs, (assign, placed, n_eval, n_exh, scores)))
+                    if ticket is not None:
+                        self._ext_tickets.append(ticket)
         # cumulative usage for the scan path + host bookkeeping: apply
         # EVERY bulk group's placements (engine dispatch may reorder
         # parts, so no single returned matrix is complete; the engine
@@ -487,9 +494,10 @@ class GenericScheduler:
         slots = [tg_index[pr.task_group] for pr in slot_requests]
         result = None
         if slots:
-            inputs = stack.build_inputs(
-                job, groups, slots, allocs_by_tg,
-                penalty_nodes=penalty_nodes, used_override=used)
+            with tracing.span("sched.feasible"):
+                inputs = stack.build_inputs(
+                    job, groups, slots, allocs_by_tg,
+                    penalty_nodes=penalty_nodes, used_override=used)
             result = stack.place(inputs, deltas)
 
         ports = PortClaims(cm)
@@ -703,75 +711,79 @@ class GenericScheduler:
 
         from nomad_tpu.core.plan_apply import _alloc_ports as _alloc_ports_fn
 
-        for pr, row in preplaced:
-            extra = []
-            place_on(pr, row, metric_for(None), preempted=extra)
-            account_device_evictions(row, extra)
+        # rows become Allocation records (and slots that found no row
+        # go to the preemption search): one span for the eval
+        with tracing.span("sched.materialise"):
+            for pr, row in preplaced:
+                extra = []
+                place_on(pr, row, metric_for(None), preempted=extra)
+                account_device_evictions(row, extra)
 
-        # bulk-kernel placements: one native expand_pairs call flattens
-        # each group's (row, count, score) triples to per-alloc arrays,
-        # and plain new placements materialize through the batch
-        # constructor instead of K build_allocation round trips
-        for gi, prs, bulk in bulk_results:
-            assign, placed, n_eval, n_exh, bscores = bulk
-            from nomad_tpu import native as _native_mod
-            rows_nz = np.flatnonzero(assign)
-            flat_rows, flat_scores = _native_mod.expand_pairs(
-                rows_nz, assign[rows_nz], np.asarray(bscores)[rows_nz])
-            n_placed = min(len(flat_rows), len(prs))
-            tg = job.task_groups[gi]
-            fast = (n_placed > 0
-                    and not tg.networks
-                    and not any(t.resources.networks for t in tg.tasks)
-                    and all(pr.previous_alloc is None
-                            and not pr.is_canary
-                            and not pr.is_rescheduling
-                            for pr in prs[:n_placed]))
-            if fast:
-                dep_id = ""
-                if deployment is not None \
-                        and tg.name in deployment.task_groups:
-                    dep_id = deployment.id
-                node_names = {}
-                for row in rows_nz:
-                    row = int(row)
-                    node = self.state.node_by_id(cm.node_ids[row])
-                    node_names[row] = node.name if node else ""
-                for alloc in materialize_bulk_allocs(
-                        job, tg, [pr.name for pr in prs[:n_placed]],
-                        flat_rows[:n_placed], flat_scores[:n_placed],
-                        cm.node_ids, node_names, self.eval.id, dep_id,
-                        int(n_eval), int(n_exh), now):
-                    self.plan.append_alloc(alloc, None)
-            else:
-                for pr, row, sc in zip(prs, flat_rows, flat_scores):
-                    row = int(row)
+            # bulk-kernel placements: one native expand_pairs call flattens
+            # each group's (row, count, score) triples to per-alloc arrays,
+            # and plain new placements materialize through the batch
+            # constructor instead of K build_allocation round trips
+            for gi, prs, bulk in bulk_results:
+                assign, placed, n_eval, n_exh, bscores = bulk
+                from nomad_tpu import native as _native_mod
+                rows_nz = np.flatnonzero(assign)
+                flat_rows, flat_scores = _native_mod.expand_pairs(
+                    rows_nz, assign[rows_nz], np.asarray(bscores)[rows_nz])
+                n_placed = min(len(flat_rows), len(prs))
+                tg = job.task_groups[gi]
+                fast = (n_placed > 0
+                        and not tg.networks
+                        and not any(t.resources.networks for t in tg.tasks)
+                        and all(pr.previous_alloc is None
+                                and not pr.is_canary
+                                and not pr.is_rescheduling
+                                for pr in prs[:n_placed]))
+                if fast:
+                    dep_id = ""
+                    if deployment is not None \
+                            and tg.name in deployment.task_groups:
+                        dep_id = deployment.id
+                    node_names = {}
+                    for row in rows_nz:
+                        row = int(row)
+                        node = self.state.node_by_id(cm.node_ids[row])
+                        node_names[row] = node.name if node else ""
+                    for alloc in materialize_bulk_allocs(
+                            job, tg, [pr.name for pr in prs[:n_placed]],
+                            flat_rows[:n_placed], flat_scores[:n_placed],
+                            cm.node_ids, node_names, self.eval.id, dep_id,
+                            int(n_eval), int(n_exh), now):
+                        self.plan.append_alloc(alloc, None)
+                else:
+                    for pr, row, sc in zip(prs, flat_rows, flat_scores):
+                        row = int(row)
+                        m = AllocMetric()
+                        m.nodes_evaluated = n_eval
+                        m.nodes_exhausted = n_exh
+                        if cm.node_ids[row]:
+                            m.populate_score_meta([{
+                                "node_id": cm.node_ids[row],
+                                "norm_score": round(float(sc), 6)}])
+                        place_on(pr, row, m)
+                for pr in prs[n_placed:]:
                     m = AllocMetric()
                     m.nodes_evaluated = n_eval
                     m.nodes_exhausted = n_exh
-                    if cm.node_ids[row]:
-                        m.populate_score_meta([{
-                            "node_id": cm.node_ids[row],
-                            "norm_score": round(float(sc), 6)}])
-                    place_on(pr, row, m)
-            for pr in prs[n_placed:]:
-                m = AllocMetric()
-                m.nodes_evaluated = n_eval
-                m.nodes_exhausted = n_exh
-                if not try_preempt(pr, None):
-                    self._fail_placement(pr, m, "exhausted")
-        if result is not None:
-            for i, pr in enumerate(slot_requests):
-                row = int(result.node[i])
-                if row < 0:
-                    if not try_preempt(pr, i):
-                        self._fail_placement(pr, metric_for(i), "exhausted")
-                else:
-                    extra = []
-                    alts = result.top_nodes[i] if result is not None else []
-                    place_on(pr, row, metric_for(i), preempted=extra,
-                             alt_rows=alts)
-                    account_device_evictions(row, extra)
+                    if not try_preempt(pr, None):
+                        self._fail_placement(pr, m, "exhausted")
+            if result is not None:
+                for i, pr in enumerate(slot_requests):
+                    row = int(result.node[i])
+                    if row < 0:
+                        if not try_preempt(pr, i):
+                            self._fail_placement(pr, metric_for(i),
+                                                 "exhausted")
+                    else:
+                        extra = []
+                        alts = result.top_nodes[i]
+                        place_on(pr, row, metric_for(i), preempted=extra,
+                                 alt_rows=alts)
+                        account_device_evictions(row, extra)
 
     @staticmethod
     def _bulk_node_fields(cm, g, allocs_by_tg, penalty_nodes):

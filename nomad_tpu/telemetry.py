@@ -6,7 +6,9 @@ Canonical names mirror the reference's scheduler metrics:
   nomad.plan.evaluate / nomad.plan.submit      (plan_apply.go:185)
   nomad.worker.invoke_scheduler.<type>         (worker.go:554)
   nomad.broker.total_ready / total_unacked     (eval_broker metrics)
-plus whatever callers emit.  Counters, gauges, and timing samples with
+plus whatever callers emit.  Every timing Sample ``nomad.<name>`` (and
+``nomad.self.<name>``) is written by `tracing.span` / `tracing.record`:
+the span primitive is the one timer, this registry is its counter sink.  Counters, gauges, and timing samples with
 mean/max/p99; JSON snapshot for /v1/metrics and Prometheus text
 exposition for /v1/metrics?format=prometheus.
 """
@@ -14,7 +16,6 @@ from __future__ import annotations
 
 import random
 import threading
-import time
 from collections import defaultdict
 from typing import Dict, List
 
@@ -84,33 +85,12 @@ class MetricsRegistry:
         with self._lock:
             self._samples[name].add(value)
 
-    def measure_since(self, name: str, start: float) -> None:
-        self.add_sample(name, (time.time() - start) * 1000.0)  # ms
-
     def take_sample(self, name: str) -> dict:
         """Summary of one timing series, then reset it — per-window
         measurement (bench scenarios, tests)."""
         with self._lock:
             s = self._samples.pop(name, None)
         return s.summary() if s is not None else _Sample().summary()
-
-    class _Timer:
-        __slots__ = ("reg", "name", "start")
-
-        def __init__(self, reg, name):
-            self.reg = reg
-            self.name = name
-
-        def __enter__(self):
-            self.start = time.time()
-            return self
-
-        def __exit__(self, *exc):
-            self.reg.measure_since(self.name, self.start)
-            return False
-
-    def timer(self, name: str) -> "_Timer":
-        return self._Timer(self, name)
 
     def snapshot(self) -> dict:
         with self._lock:

@@ -1,37 +1,61 @@
-"""Dapper-style sampled distributed tracing for the placement spine.
+"""One span primitive for the placement spine, and its three sinks.
+
+`span(name)` (a context manager) and `record(name, start, end)` (for an
+interval that began on another thread or was stamped outside the FSM
+cone) are the only way the program times a layer boundary.  One call
+feeds, from one pair of `time.perf_counter()` reads:
+
+* **the counter, always**: `count` and `total` of the span go to
+  `telemetry.global_metrics` as the Sample ``nomad.<name>`` (`/v1/metrics`).
+  A span that had child spans on its own thread also records
+  ``nomad.self.<name>``: its duration less what its direct children
+  covered.
+* **the profiler's clock**: while a `jax.profiler` session runs, the span
+  is a `TraceAnnotation` in the `/host:CPU` lines of the trace, beside
+  the device ops.  Each thread shows a FLAT sequence named by its
+  innermost open span (an enclosing span is cut into the pieces of its
+  self time), and a `wait=True` span (blocked on another thread's work:
+  a future, a queue, a commit) is not annotated: what is on the
+  profiler's timeline is work.  With no session the cost is one flag
+  test; jax is never imported from here, so a process that does not
+  schedule does not pay for it.
+* **a Dapper span, when sampled**: with a `Tracer` installed and a
+  sampled context bound to the thread (or passed as `ctx`), the same
+  call adds a `Span` with its parent to the `SpanStore` and binds the
+  child context for the span's duration.
 
 A `Tracer` makes one sampling decision at ingress; sampled requests get a
 trace context — ``{"t": trace_id, "s": parent_span_id, "b": 1}`` — that
 rides RPC args end-to-end under the reserved key `TRACE_KEY`.  Absence of
-the key IS the unsampled state: no per-request flag, no allocation.  The
-tracer is installed process-wide (`install()`) or picked up from the
-environment at import, chaos-layer style:
+the key IS the unsampled state.  The tracer is installed process-wide
+(`install()`) or picked up from the environment at import:
 
     NOMAD_TPU_TRACE=1 NOMAD_TPU_TRACE_SAMPLE=0.01 nomad agent ...
 
-Instrumentation sites pay exactly one module-attribute load + ``is not
-None`` branch when tracing is off (the chaos idiom), and only sampled
-requests allocate spans.  Span timestamps are captured at propose or
-observe time only — never inside the FSM cone, so replicas replay to
-byte-identical state (see nomad_tpu.analysis.fsm_determinism).  The raft
-spine is traced via side tables keyed off the log index on the proposing
-node; trace context never rides in log payloads.
+Nothing is stamped inside the FSM cone, so replicas replay to
+byte-identical state (see nomad_tpu.analysis.fsm_determinism): spans
+open around the FSM call, never under it, and trace context never rides
+in log payloads.  Durations come from `time.perf_counter()`; the wall
+clock is read only for a Dapper span's display start.
 
-Spans land in a bounded ring `SpanStore` per server (`store_for(node)`),
-queried through `/v1/traces` + `/v1/traces/<trace_id>` and exportable as
-Chrome-trace JSON (`chrome_trace()`) for Perfetto.
+Dapper spans land in a bounded ring `SpanStore` per server
+(`store_for(node)`), queried through `/v1/traces` +
+`/v1/traces/<trace_id>` and exportable as Chrome-trace JSON
+(`chrome_trace()`) for Perfetto.
 """
 from __future__ import annotations
 
-import os
 import random
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from time import perf_counter
+from typing import Any, Dict, List, Optional
 
 from nomad_tpu import knobs
 from nomad_tpu.analysis import race
+from nomad_tpu.telemetry import global_metrics
 
 # reserved RPC-args key the context rides under; handlers pop it before
 # dispatch so endpoint code never sees it in its own args
@@ -109,9 +133,9 @@ class Tracer:
         self._rng = random.Random(seed)
         self._lock = threading.Lock()
         self._stores: Dict[str, SpanStore] = {}
-        # eval_id -> (ctx, enqueue_ts): written at propose time (outside
-        # the FSM), read at broker dequeue to emit the queue-wait span
-        self._eval_notes: Dict[str, Tuple[dict, float]] = {}
+        # eval_id -> ctx: written at propose time (outside the FSM),
+        # taken at broker dequeue and again by the dequeuing worker
+        self._eval_notes: Dict[str, dict] = {}
 
     # ------------------------------------------------------------- sampling
 
@@ -135,9 +159,10 @@ class Tracer:
                     parent_id=ctx.get("s", ""), name=name,
                     start=time.time(), node=node)
 
-    def finish(self, span: Span, end: Optional[float] = None) -> None:
-        span.duration = max(0.0, (time.time() if end is None else end)
-                            - span.start)
+    def finish(self, span: Span,
+               duration: Optional[float] = None) -> None:
+        span.duration = max(0.0, time.time() - span.start) \
+            if duration is None else duration
         self.store_for(span.node).add(span)
 
     def emit(self, ctx: dict, name: str, start: float, end: float,
@@ -197,20 +222,16 @@ class Tracer:
 
     # ------------------------------------------------------------- notes
 
-    def note_eval(self, eval_id: str, ctx: dict,
-                  ts: Optional[float] = None) -> None:
-        """Propose-time note: the eval was created under `ctx` at `ts`.
-        The FSM's leader hook enqueues the eval inside the apply cone, so
-        the queue-wait span is stitched here instead: noted at propose
-        time, emitted at dequeue time."""
+    def note_eval(self, eval_id: str, ctx: dict) -> None:
+        """Propose-time note: the eval was created under `ctx`.  The
+        FSM's leader hook enqueues the eval inside the apply cone, so
+        the context crosses the broker here instead of in the payload."""
         with self._lock:
             while len(self._eval_notes) >= self._NOTE_LIMIT:
                 self._eval_notes.pop(next(iter(self._eval_notes)))
-            self._eval_notes[eval_id] = (ctx, time.time() if ts is None
-                                         else ts)
+            self._eval_notes[eval_id] = ctx
 
-    def take_eval_note(self, eval_id: str) \
-            -> Optional[Tuple[dict, float]]:
+    def take_eval_note(self, eval_id: str) -> Optional[dict]:
         with self._lock:
             return self._eval_notes.pop(eval_id, None)
 
@@ -244,9 +265,8 @@ def chrome_trace(spans: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 # ===================================================================== module
 
-# the installed tracer, or None.  Instrumentation sites test this one
-# global before doing anything else: the untraced fast path is a module
-# attribute load + is-check, nothing more (chaos.py idiom).
+# the installed tracer, or None: the Dapper sink is off unless this is
+# set, and then only sampled contexts allocate spans
 active: Optional[Tracer] = None
 
 _tls = threading.local()
@@ -274,6 +294,134 @@ def bind(ctx: Optional[dict]) -> Optional[dict]:
     prev = getattr(_tls, "ctx", None)
     _tls.ctx = ctx
     return prev
+
+
+def note_evals(eval_ids, ctx: Optional[dict] = None) -> None:
+    """Propose-time: these evals are created under `ctx`, or this
+    thread's sampled context (no-op when unsampled)."""
+    tracer = active
+    if tracer is not None:
+        ctx = ctx or current()
+        if ctx is not None:
+            for eval_id in eval_ids:
+                tracer.note_eval(eval_id, ctx)
+
+
+def take_eval_ctx(eval_id: str) -> Optional[dict]:
+    """The sampled context noted for this eval, or None."""
+    tracer = active
+    return tracer.take_eval_note(eval_id) if tracer is not None else None
+
+
+# ====================================================================== spans
+
+_TraceMe = None
+
+
+def _annotate(name: str):
+    """An entered profiler annotation, or None when no profiler session
+    runs (or jax was never imported by this process)."""
+    global _TraceMe
+    tm = _TraceMe
+    if tm is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation as tm
+        _TraceMe = tm
+    if not tm.is_enabled():
+        return None
+    ann = tm(name)
+    ann.__enter__()
+    return ann
+
+
+class span:
+    """``with tracing.span("plan.evaluate"): ...`` — see the module
+    docstring for the three sinks.  `wait=True` marks time blocked on
+    another thread's work.  `ctx` starts the Dapper span under that
+    context instead of the thread's own (ingress, contexts that crossed
+    a queue); `node` and `attrs` go to the Dapper span only, and `attrs`
+    may be filled while the span is open.  After exit `seconds` holds
+    the duration."""
+
+    __slots__ = ("name", "wait", "ctx", "node", "attrs", "seconds",
+                 "_t0", "_children", "_ann", "_dapper", "_prev")
+
+    def __init__(self, name: str, wait: bool = False,
+                 ctx: Optional[dict] = None, node: str = "", **attrs):
+        self.name = name
+        self.wait = wait
+        self.ctx = ctx
+        self.node = node
+        self.attrs = attrs
+        self.seconds = 0.0
+        self._children = None       # seconds its direct children covered
+        self._ann = self._dapper = None
+
+    def __enter__(self) -> "span":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        elif stack and stack[-1]._ann is not None:
+            # flat timeline: the enclosing span's piece ends here
+            stack[-1]._ann.__exit__(None, None, None)
+            stack[-1]._ann = None
+        stack.append(self)
+        tracer = active
+        if tracer is not None:
+            ctx = self.ctx or getattr(_tls, "ctx", None)
+            if ctx is not None:
+                self._dapper = tracer.start(ctx, self.name, self.node)
+                self._prev = bind(tracer.child_ctx(ctx, self._dapper))
+        if not self.wait:
+            self._ann = _annotate(self.name)
+        self._t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = self.seconds = perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        stack = _tls.stack
+        stack.pop()
+        global_metrics.add_sample("nomad." + self.name, dur * 1e3)
+        if self._children is not None:
+            global_metrics.add_sample(
+                "nomad.self." + self.name,
+                max(0.0, dur - self._children) * 1e3)
+        if stack:
+            parent = stack[-1]
+            parent._children = (parent._children or 0.0) + dur
+            if not parent.wait:
+                parent._ann = _annotate(parent.name)
+        sp = self._dapper
+        if sp is not None:
+            bind(self._prev)
+            if self.wait:
+                self.attrs["wait"] = True
+            sp.attrs = self.attrs
+            tracer = active
+            if tracer is not None:
+                tracer.finish(sp, dur)
+        return False
+
+
+def record(name: str, start: float, end: float, wait: bool = False,
+           ctx: Optional[dict] = None, node: str = "", **attrs) -> None:
+    """Observe-time form of `span` for an interval that began on another
+    thread or before this one took the work over (a queue wait, a
+    request's submit-to-resolve): `start` and `end` are
+    `time.perf_counter()` readings.  Counted like a span; never on the
+    profiler's timeline (it is over), never a child of the thread's open
+    span; a Dapper span under `ctx` when one is given."""
+    dur = max(0.0, end - start)
+    global_metrics.add_sample("nomad." + name, dur * 1e3)
+    tracer = active
+    if tracer is not None and ctx is not None:
+        if wait:
+            attrs["wait"] = True
+        wall = time.time() - (perf_counter() - start)
+        tracer.emit(ctx, name, wall, wall + dur, node=node, **attrs)
 
 
 if knobs.get_bool("NOMAD_TPU_TRACE"):

@@ -69,3 +69,51 @@ def _race_guard():
     det.uninstall()
     assert det.races == [], "\n" + det.render_races()
     assert det.cycles() == [], "\n" + det.render_cycles()
+
+
+@pytest.fixture(scope="session")
+def spine_metrics():
+    """One dev agent, no tracer installed: a service job (scan path) and
+    a batch job (bulk path) registered over HTTP and placed, then one
+    blocking query.  Returns what `/v1/metrics` served afterwards, as
+    {"samples": {name: summary}, "prometheus": text, "processed": evals
+    the workers acked}."""
+    import time
+    import urllib.request
+
+    from nomad_tpu import mock, tracing
+    from nomad_tpu.agent import Agent, AgentConfig
+    from nomad_tpu.api import ApiClient
+
+    assert tracing.active is None
+    a = Agent(AgentConfig(http_port=0, num_schedulers=2,
+                          heartbeat_ttl=60.0))
+    a.start()
+    try:
+        for _ in range(3):
+            a.server.register_node(mock.node())
+        api = ApiClient(a.http_addr)
+        before = {s["Name"]: s["count"]
+                  for s in api.system.metrics()["Samples"]}
+        api.jobs.register(mock.job())
+        batch = mock.batch_job()
+        batch.task_groups[0].count = 4
+        api.jobs.register(batch)
+        assert a.server.wait_for_idle(30.0)
+        api.get("/v1/jobs?index=1&wait=10ms")
+        time.sleep(0.2)     # the commit thread's spans close after idle
+        got = {
+            "samples": {s["Name"]: s
+                        for s in api.system.metrics()["Samples"]},
+            "before": before,
+            "prometheus": urllib.request.urlopen(
+                a.http_addr + "/v1/metrics?format=prometheus",
+                timeout=10).read().decode(),
+            "processed": sum(w.stats["processed"]
+                             for w in a.server.workers),
+        }
+    finally:
+        # stopped before the first test reads it: no thread of this
+        # agent outlives the fixture's set-up
+        a.stop()
+    return got

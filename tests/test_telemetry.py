@@ -85,3 +85,39 @@ def test_prometheus_sanitization_collision_detected():
     # the surviving family still has a value line
     assert sum(1 for line in text.splitlines()
                if line.startswith("a_b ")) == 1
+
+
+def test_span_sample_gives_back_total_and_count():
+    """What `benchmark/harness.snapshot` differences: a Sample's `count`
+    and `mean * count` are the number of spans and their summed ms."""
+    import time
+
+    from nomad_tpu import tracing
+    from nomad_tpu.telemetry import global_metrics
+
+    spans = []
+    for _ in range(3):
+        with tracing.span("ttel.total") as sp:
+            time.sleep(0.003)
+        spans.append(sp)
+    (got,) = [s for s in global_metrics.snapshot()["Samples"]
+              if s["Name"] == "nomad.ttel.total"]
+    assert got["count"] == 3
+    assert abs(got["mean"] * got["count"]
+               - sum(sp.seconds for sp in spans) * 1e3) < 1e-6
+    assert got["max"] == max(sp.seconds for sp in spans) * 1e3
+
+
+def test_span_names_export_without_sanitisation_collision(spine_metrics):
+    """Every Sample a job's spans wrote (`nomad.<name>`,
+    `nomad.self.<name>`) is one summary family of the Prometheus page;
+    none is skipped for sanitising to another's name."""
+    lines = spine_metrics["prometheus"].splitlines()
+    names = [n for n in spine_metrics["samples"] if n.startswith("nomad.")]
+    assert len(names) >= 30
+    assert not [ln for ln in lines if ln.startswith("# collision")
+                and any(repr(n) in ln for n in names)]
+    for n in names:
+        fam = n.replace(".", "_").replace("-", "_")
+        assert lines.count(f"# TYPE {fam} summary") == 1, n
+        assert any(ln.startswith(f"{fam}_count ") for ln in lines), n

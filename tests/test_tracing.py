@@ -1,5 +1,6 @@
-"""Distributed tracing tests (r12 tentpole): context propagation end to
-end through the spine, raft span attribution, federation-hop survival,
+"""Tracing tests: the span primitive's three sinks (counter, profiler
+annotation, Dapper span), self time, context propagation end to end
+through the spine, raft span attribution, federation-hop survival,
 span-store bounds, sampling, and Chrome-trace export.  This file is also
 the CI `tracing` leg's payload — it must stay green under
 NOMAD_TPU_RACE=1."""
@@ -11,6 +12,7 @@ import time
 import pytest
 
 from nomad_tpu import mock, tracing
+from nomad_tpu.telemetry import global_metrics
 from nomad_tpu.tracing import TRACE_KEY, Tracer, chrome_trace
 
 
@@ -128,6 +130,251 @@ def test_chrome_trace_export_shape():
     json.dumps(doc)     # must be JSON-serializable as-is
 
 
+# ---------------------------------------------------- the span primitive
+
+
+class _FakeTraceMe:
+    """Stands in for jax.profiler.TraceAnnotation with a session on:
+    records (thread, name, "B"/"E") in order."""
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @staticmethod
+    def is_enabled():
+        return True
+
+    def __enter__(self):
+        _FakeTraceMe.log.append((threading.get_ident(), self.name, "B"))
+
+    def __exit__(self, *exc):
+        _FakeTraceMe.log.append((threading.get_ident(), self.name, "E"))
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _FakeTraceMe.log = []
+    monkeypatch.setattr(tracing, "_TraceMe", _FakeTraceMe)
+    return _FakeTraceMe.log
+
+
+def _sample(name):
+    return {s["Name"]: s
+            for s in global_metrics.snapshot()["Samples"]}.get(name)
+
+
+def _count(name):
+    s = _sample(name)
+    return s["count"] if s else 0
+
+
+def test_one_call_feeds_three_sinks(tracer, annotations):
+    """Counter, profiler annotation and Dapper span from one `span()`;
+    the Dapper span hangs under the bound context and the child context
+    is bound only while the span is open."""
+    n0 = _count("nomad.t3.work")
+    ctx = tracer.new_context()
+    prev = tracing.bind(ctx)
+    try:
+        with tracing.span("t3.work", node="n1", shard=7) as sp:
+            inner = tracing.current()
+            time.sleep(0.01)
+        assert tracing.current() is ctx
+    finally:
+        tracing.bind(prev)
+    assert _count("nomad.t3.work") == n0 + 1
+    assert [(n, k) for _t, n, k in annotations] == \
+        [("t3.work", "B"), ("t3.work", "E")]
+    (got,) = tracer.spans(ctx["t"])
+    assert (got.name, got.node, got.parent_id) == ("t3.work", "n1", "")
+    assert got.attrs == {"shard": 7}
+    assert inner == {"t": ctx["t"], "s": got.span_id, "b": 1}
+    assert 0.009 < sp.seconds == got.duration < 0.5
+
+
+def test_self_time_is_duration_less_direct_children(annotations):
+    """`nomad.self.<name>` = the span less what its direct children
+    covered (a grandchild counts once, through its parent), only for a
+    span that had children, and under a prefix the harness's
+    `nomad.worker.invoke_scheduler.` sum cannot pick up."""
+    name = "tself.outer"
+    with tracing.span(name) as outer:
+        time.sleep(0.01)
+        with tracing.span("tself.child") as c1:
+            with tracing.span("tself.grandchild"):
+                time.sleep(0.01)
+        with tracing.span("tself.waited", wait=True) as c2:
+            time.sleep(0.01)
+    self_s = _sample("nomad.self." + name)
+    assert self_s["count"] == 1
+    want_ms = (outer.seconds - c1.seconds - c2.seconds) * 1e3
+    assert abs(self_s["mean"] - want_ms) < 1e-6
+    assert 9.0 < self_s["mean"] < outer.seconds * 1e3 - 19.0
+    assert _sample("nomad.self.tself.grandchild") is None    # a leaf
+    assert _sample("nomad.self.tself.child")["count"] >= 1
+    assert _sample("nomad." + name + ".self") is None   # a prefix
+    # the profiler's timeline of this thread is flat: the outer span is
+    # cut into the pieces of its self time, the wait shows nothing
+    assert [(n, k) for _t, n, k in annotations] == [
+        (name, "B"), (name, "E"),
+        ("tself.child", "B"), ("tself.child", "E"),
+        ("tself.grandchild", "B"), ("tself.grandchild", "E"),
+        ("tself.child", "B"), ("tself.child", "E"),
+        (name, "B"), (name, "E"),
+        (name, "B"), (name, "E")]
+
+
+def test_wait_span_is_counted_and_not_annotated(tracer, annotations):
+    n0 = _count("nomad.twait.blocked")
+    ctx = tracer.new_context()
+    with tracing.span("twait.blocked", wait=True, ctx=ctx):
+        time.sleep(0.005)
+    assert _count("nomad.twait.blocked") == n0 + 1
+    assert annotations == []
+    (got,) = tracer.spans(ctx["t"])
+    assert got.attrs == {"wait": True}
+
+
+def test_record_counts_and_never_nests(tracer, annotations):
+    """The observe-time form: counted, a Dapper span only under an
+    explicit context, no annotation, and not a child of the open span."""
+    ctx = tracer.new_context()
+    n0 = _count("nomad.trec.queue_wait")
+    with tracing.span("trec.outer"):
+        t1 = time.perf_counter()
+        tracing.record("trec.queue_wait", t1 - 0.25, t1, wait=True)
+        tracing.record("trec.queue_wait", t1 - 0.5, t1, wait=True,
+                       ctx=ctx, node="n2", depth=3)
+    s = _sample("nomad.trec.queue_wait")
+    assert s["count"] == n0 + 2 and abs(s["max"] - 500.0) < 1e-6
+    assert _sample("nomad.self.trec.outer") is None
+    assert [n for _t, n, _k in annotations] == ["trec.outer"] * 2
+    (got,) = tracer.spans(ctx["t"])
+    assert got.node == "n2" and got.attrs == {"wait": True, "depth": 3}
+    assert abs(got.duration - 0.5) < 1e-9
+    assert abs(got.start - (time.time() - 0.5)) < 0.2
+
+
+def test_spans_nest_per_thread():
+    """Stacks are per thread: a span open on one thread is not the
+    parent of a span on another."""
+    name = "tthread.outer"
+    done = threading.Event()
+
+    def other():
+        with tracing.span("tthread.other"):
+            time.sleep(0.005)
+        done.set()
+
+    with tracing.span(name):
+        th = threading.Thread(target=other)
+        th.start()
+        assert done.wait(5.0)
+        th.join(5.0)
+    assert not th.is_alive()
+    assert _sample("nomad.self." + name) is None
+
+
+def test_profiler_session_shows_flat_work_spans(tmp_path):
+    """A real `jax.profiler` session on the CPU, read back with
+    ProfileData: the program's names are in /host:CPU, a wait span is
+    not, and no two of the program's annotations of one thread overlap."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    def work(tag):
+        for _ in range(3):
+            with tracing.span(f"tprof.outer.{tag}"):
+                time.sleep(0.002)
+                with tracing.span("tprof.child"):
+                    time.sleep(0.002)
+                with tracing.span("tprof.blocked", wait=True):
+                    time.sleep(0.002)
+                time.sleep(0.002)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        threads = [threading.Thread(target=work, args=(t,))
+                   for t in ("a", "b")]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(20.0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    names, lines = set(), 0
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            mine = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                          for e in line.events
+                          if e.name.startswith("tprof."))
+            if not mine:
+                continue
+            lines += 1
+            names |= {n for _s, _e, n in mine}
+            for (_s0, e0, n0), (s1, _e1, n1) in zip(mine, mine[1:]):
+                assert e0 <= s1, (n0, n1, e0, s1)
+    assert lines == 2
+    assert names == {"tprof.outer.a", "tprof.outer.b", "tprof.child"}
+
+
+# ------------------------------------------------- one job on a dev agent
+
+# every span of README's table that one registered job on a dev agent
+# opens (raft.append / raft.commit need a raft node: asserted in
+# test_cluster_plan_submit_trace_has_raft_spans; engine.warmup is set-up)
+SPINE_SPANS = (
+    "http.put.jobs", "http.park", "rpc.Job.Register", "broker.wait",
+    "worker.invoke_scheduler.service", "worker.invoke_scheduler.batch",
+    "sched.reconcile", "sched.feasible", "sched.materialise",
+    "sched.wait_engine", "engine.queue_wait", "engine.idle",
+    "engine.stack", "engine.put", "engine.device_get", "engine.resolve",
+    "engine.dispatch", "plan.submit", "plan.queue_wait", "plan.evaluate",
+    "plan.commit", "raft.fsm_apply", "native.validate_plan",
+    "native.scatter_add_rank1", "native.expand_pairs",
+    "native.format_uuids")
+
+
+@pytest.mark.parametrize("name", SPINE_SPANS)
+def test_span_is_in_v1_metrics_after_one_job(spine_metrics, name):
+    moved = spine_metrics["samples"].get("nomad." + name, {"count": 0})[
+        "count"] - spine_metrics["before"].get("nomad." + name, 0)
+    assert moved >= 1, sorted(spine_metrics["samples"])
+
+
+def test_invoke_scheduler_count_is_evals_processed(spine_metrics):
+    """The accepted `invoke_scheduler_ms` sums every Sample under
+    `nomad.worker.invoke_scheduler.`: one per eval, nothing else."""
+    under = {n: s["count"] - spine_metrics["before"].get(n, 0)
+             for n, s in spine_metrics["samples"].items()
+             if n.startswith("nomad.worker.invoke_scheduler.")}
+    assert sum(under.values()) == spine_metrics["processed"] == 2, under
+    assert {n for n, moved in under.items() if moved} == {
+        "nomad.worker.invoke_scheduler.service",
+        "nomad.worker.invoke_scheduler.batch"}
+    # their self time is counted too, under a prefix that sum never sees
+    for kind in ("service", "batch"):
+        n = f"nomad.self.worker.invoke_scheduler.{kind}"
+        assert spine_metrics["samples"][n]["count"] \
+            - spine_metrics["before"].get(n, 0) == 1
+
+
+@pytest.mark.parametrize("name", ["broker.wait", "plan.queue_wait",
+                                  "engine.queue_wait"])
+def test_queue_waits_are_counted_with_no_tracer(spine_metrics, name):
+    """One per eval / plan / engine request, sampled or not."""
+    moved = spine_metrics["samples"]["nomad." + name]["count"] \
+        - spine_metrics["before"].get("nomad." + name, 0)
+    assert moved == 2
+
+
 # ------------------------------------------------------ dev agent (HTTP)
 
 
@@ -150,11 +397,11 @@ def test_dev_agent_http_chain_and_api(tracer):
         api.jobs.register(j)
         a.server.wait_for_idle(10.0)
 
-        reg = _wait_trace(tracer, "http.PUT /v1/jobs",
+        reg = _wait_trace(tracer, "http.put.jobs",
                           {"plan.submit", "raft.fsm_apply"})
         spans = tracer.spans(reg["trace_id"])
         names = {s.name for s in spans}
-        for want in ("http.PUT /v1/jobs", "rpc.Job.Register",
+        for want in ("http.put.jobs", "rpc.Job.Register",
                      "broker.wait", "plan.submit", "plan.queue_wait",
                      "plan.evaluate", "raft.fsm_apply"):
             assert want in names, (want, sorted(names))
@@ -251,6 +498,9 @@ def test_cluster_plan_submit_trace_has_raft_spans(tracer):
         assert {s.trace_id for s in spans} == {ctx["t"]}
         assert any(s.name == "raft.append" and
                    s.attrs and "index" in s.attrs for s in spans)
+        # the counters move for every entry, sampled or not
+        for name in ("raft.append", "raft.commit", "raft.fsm_apply"):
+            assert _count("nomad." + name) >= 1, name
     finally:
         c.stop()
 

@@ -281,31 +281,50 @@ def test_overlap_chained_matches_drained(shard_min):
         np.testing.assert_allclose(sc1, sc2, rtol=1e-5)
 
 
-def test_overlap_windows_recorded():
-    """The engine records (t0, t1) host upload/dispatch windows and
-    device windows; interval_overlap_s over them is the BENCH
-    pipeline_overlap_s metric."""
-    from nomad_tpu.parallel.stage_probe import interval_overlap_s
+def test_chained_upload_opens_before_inflight_device_get_closes():
+    """The overlap the engine's window deques used to time, asked of the
+    spans: with a part in flight, the next part's `engine.put` (stack +
+    dirty-row update + dispatch) opens and closes before the in-flight
+    part's `engine.device_get` does, so the host prep rides under the
+    device's work.  Drained (overlap off), every put is followed by its
+    own device_get."""
+    from nomad_tpu import tracing
 
     cm = _world_cm(64, seed=2)
-    N = cm.n_rows
     bg = _group_fields(cm, 4)
-    eng = PlacementEngine()
-    try:
-        for i in range(2):
-            *_r, t = eng.place_bulk(
-                cm, feasible=bg.feasible, affinity=bg.affinity,
-                has_affinity=bg.has_affinity, desired=4,
-                penalty=np.zeros(N, bool), coll0=np.zeros(N, np.int32),
-                demand=bg.demand, count=4, wave_key=f"ns-{i}")
-            eng.complete(t)
-        assert len(eng.upload_windows) >= 2
-        assert len(eng.device_windows) >= 2
-        assert all(t1 >= t0 for t0, t1 in eng.upload_windows)
-        assert interval_overlap_s(list(eng.upload_windows),
-                                  list(eng.device_windows)) >= 0.0
-    finally:
-        eng.stop()
+
+    def phases(overlap):
+        tracer = tracing.Tracer(sample_rate=1.0, seed=9)
+        prev = tracing.install(tracer)
+        eng = PlacementEngine(shard_min_nodes=1 << 30)
+        eng.overlap = eng.overlap and overlap
+        try:
+            ctx = tracer.new_context()
+            parts = [[_bulk_req(cm, bg, 7, f"ns-{j}-{i}") for j in range(2)]
+                     for i in range(3)]
+            for part in parts:
+                for r in part:
+                    r.ctx = ctx
+                eng._dispatch(part)
+            eng._drain_pending()
+            for *_r, t in _results([r for part in parts for r in part]):
+                eng.complete(t)
+            spans = [s for s in tracer.spans(ctx["t"])
+                     if s.name in ("engine.put", "engine.device_get")]
+            assert all(s.duration >= 0 for s in spans)
+            return [s.name for s in spans], dict(eng.stats)
+        finally:
+            eng.stop()
+            tracing.install(prev)
+
+    chained, stats = phases(overlap=True)
+    assert stats["overlap_chained"] == 2
+    assert chained == ["engine.put", "engine.put", "engine.device_get",
+                       "engine.put", "engine.device_get",
+                       "engine.device_get"]
+    drained, stats = phases(overlap=False)
+    assert stats["overlap_chained"] == 0
+    assert drained == ["engine.put", "engine.device_get"] * 3
 
 
 # ------------------------------------------------------ laned parity
