@@ -10,20 +10,13 @@ program start from the same state and neither takes it from the other.
 Every seed gets the same multiset of node shapes and the same number of
 preloaded allocations; the seed decides which node has which shape and
 where the preload lands.
+
+A configuration whose file names no `cluster` module gets this one; one
+that needs other nodes names its own (PERF.md section 4: the seam).
 """
 from __future__ import annotations
 
-import json
-import os
-
 import numpy as np
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def load_config(name: str) -> dict:
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
-        return json.load(f)
 
 
 def _uuids(rng: np.random.Generator, n: int) -> list:
@@ -154,7 +147,8 @@ class Cluster:
             Allocation, AllocClientStatus, AllocDesiredStatus)
         from nomad_tpu.structs.alloc import (
             AllocatedResources, AllocatedTaskResources)
-        from benchmark import jobs as jobshapes
+        from benchmark.harness import world_module
+        jobshapes = world_module(self.cfg, "jobs")
         server = agent.server
         store = server.store
         for ns in self.cfg["namespaces"]:

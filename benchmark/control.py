@@ -18,20 +18,19 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def readings(workload: str, seed: int, jobs: int, n_nodes=None) -> dict:
-    import importlib
-    from benchmark import cluster, harness, reference, traffic
+    from benchmark import harness, traffic
     cell = next(w for w in harness.load_benchmark()["workloads"]
                 if w["name"] == workload)
-    cfg = cluster.load_config(cell["config"])
-    cl = cluster.Cluster(cfg, seed, n_nodes)
+    cfg = harness.load_config(cell["config"])
+    ref = harness.world_module(cfg, "reference")
+    cl = harness.world_module(cfg, "cluster").Cluster(cfg, seed, n_nodes)
     mix = traffic.load(cell["traffic"])
     order = traffic.shape_order(mix, seed)
     specs = []
     for k in range(jobs):
         name, ns = next(order)
-        specs.append(reference.JobSpec(f"c{k:05d}-{name}", ns,
-                                       mix["shapes"][name]))
-    ref = importlib.import_module(f"benchmark.{cfg['reference']}")
+        specs.append(ref.JobSpec(f"c{k:05d}-{name}", ns,
+                                 mix["shapes"][name]))
     return {name: {"correct": v["correct"],
                    **{k: c["value"] for k, c in v["compared"].items()}}
             for name, v in ref.controls(cl, specs).items()}

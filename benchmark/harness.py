@@ -3,8 +3,10 @@ the comparison with the plain reference, and the result line.
 
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file found by the name `BENCHMARK.json` gives it
-(`configs/`, `traffic/`, `metrics/` + `readers/`); this file knows none
-of them by name.
+(`configs/`, `traffic/`, `metrics/` + `readers/`), and the modules that
+make a configuration's deployment (its cluster, its job shapes, its
+plain reference) by the names its own file gives them (`world_module`);
+this file knows none of them by name.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 TRACE_S = 5.0          # the profiler traces this much of the window
+# what a configuration's file need not name: the modules `c2m-10k` runs
+WORLD_DEFAULTS = {"cluster": "cluster", "jobs": "jobs"}
 
 
 class Refused(RuntimeError):
@@ -35,6 +39,26 @@ def say(*a) -> None:
 def load_benchmark() -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def load_config(name: str) -> dict:
+    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
+def world_module(cfg: dict, key: str):
+    """The module the configuration's file names under `key`
+    (`reference`, `cluster` or `jobs`): a dotted name under this package.
+    PERF.md section 4 has what the harness takes from each."""
+    name = cfg.get(key, WORLD_DEFAULTS.get(key))
+    if not name:
+        raise Refused(f"configuration {cfg.get('name')!r} names no "
+                      f"{key!r} module")
+    try:
+        return importlib.import_module(f"benchmark.{name}")
+    except ModuleNotFoundError as e:
+        raise Refused(f"configuration {cfg.get('name')!r}: {key} module "
+                      f"benchmark.{name}: {e}") from e
 
 
 def device_check(chips: int, require_tpu: bool) -> dict:
@@ -72,18 +96,18 @@ class Compiles:
 
 # ------------------------------------------------------------- set-up
 
-def warm_classes(server, mix: dict) -> None:
+def warm_classes(server, mix: dict, build) -> None:
     """`engine.warmup` for the shape classes this cell's traffic can
     reach and no others (as `bench._warm_engine` builds its samples): per
-    class a scan sample of `scan_slots` slots of the shape's job and, with
-    `bulk`, the bulk variant grid for its first group."""
+    class a scan sample of `scan_slots` slots of the shape's job (made by
+    `build`, the configuration's jobs module) and, with `bulk`, the bulk
+    variant grid for its first group."""
     from nomad_tpu.parallel.engine import get_engine
     from nomad_tpu.scheduler.stack import DenseStack
-    from benchmark import jobs as jobshapes
     eng = get_engine()
     cm = server.store.matrix
     for cls in mix["warm"]["classes"]:
-        job = jobshapes.build(mix["shapes"][cls["shape"]], "warm-sample")
+        job = build(mix["shapes"][cls["shape"]], "warm-sample")
         st = DenseStack(cm)
         groups = [st.compile_group(job, tg) for tg in job.task_groups]
         inputs = st.build_inputs(job, groups, [0] * cls["scan_slots"], {})
@@ -181,10 +205,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     say(f"platform={dev['platform']} device_kind={dev['kind']} "
         f"device_count={dev['count']}")
 
-    from benchmark import cluster, traffic, trace_reduce
-    cfg = cluster.load_config(cell["config"])
-    reference = importlib.import_module(
-        f"benchmark.{cfg['reference']}")
+    from benchmark import traffic, trace_reduce
+    cfg = load_config(cell["config"])
+    reference, cluster, jobs = (world_module(cfg, key) for key in
+                                ("reference", "cluster", "jobs"))
     mix = traffic.load(cell["traffic"])
     compiles = Compiles()
 
@@ -215,7 +239,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             f"{t0 - t_start:.1f}s of imports and device start")
 
         t1 = time.monotonic()
-        warm_classes(agent.server, mix)
+        warm_classes(agent.server, mix, jobs.build)
         say(f"engine.warmup: {time.monotonic() - t1:.1f}s, cache hits "
             f"{compiles.hits} misses {compiles.misses}")
 
@@ -223,7 +247,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         if trace:
             import jax
             span = jax.profiler.TraceAnnotation
-        drv = traffic.Driver(agent.http_addr, mix, seed, span)
+        drv = traffic.Driver(agent.http_addr, mix, seed, reference.JobSpec,
+                             jobs.build, span)
         t1 = time.monotonic()
         warm = drv.warm_pass(mix["warm"]["jobs"],
                              mix.get("clients", 4))
@@ -281,6 +306,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             facts["client.p95_ms"] = percentile(lat_ms, 95)
         if late_ms and mix["arrivals"] == "open":
             facts["client.late_p95_ms"] = percentile(late_ms, 95)
+        say(f"client: p50 {facts.get('client.p50_ms')} ms, p95 "
+            f"{facts.get('client.p95_ms')} ms, generator late p95 "
+            f"{facts.get('client.late_p95_ms')} ms, "
+            f"{sum(lat_ms) / 1e3 / window_s:.2f} jobs in the system on "
+            f"average (the latencies' sum over the window)")
         end_to_end = {
             "job_placed_p50_ms": facts.get("client.p50_ms"),
             "allocs_per_s": facts["client.allocs_completed"] / window_s,
@@ -300,18 +330,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                             if a["job_id"] == r.spec.id)
         say(f"readback: {len(stubs)} stubs, {len(full)} allocations of "
             f"{len(sample)} sampled jobs in {time.monotonic() - t1:.1f}s")
+        # what else the configuration's reference wants to have read:
+        # its own GETs, after the window and the memory reading
+        more = ()
+        if hasattr(reference, "readback"):
+            more = (reference.readback(api.get, list(warm) + recs),)
         t1 = time.monotonic()
         verdict = reference.compare(cl, specs, stubs, full,
-                                    {r.spec.id for r in done})
-        g = verdict["gaps"]
-        say(f"comparison: {time.monotonic() - t1:.1f}s "
-            f"{verdict['allocations_compared']} allocations, "
-            f"{verdict['placements_ranked']} ranked; problems "
-            f"{verdict['problems']}; worst {verdict['worst_score']}; "
-            f"explained gaps p50 {np.median(g) if g.size else None} max "
-            f"{g[g <= 5e-6].max(initial=0.0)}; share over "
-            f"1e-6 {float((g > 1e-6).mean()) if g.size else None}; max regret "
-            f"{verdict['regrets'].max(initial=-1.0)}")
+                                    {r.spec.id for r in done}, *more)
+        say(f"comparison: {time.monotonic() - t1:.1f}s; " + "; ".join(
+            f"{k} {_brief(v)}" for k, v in verdict.items()
+            if k not in ("correct", "compared")))
 
         if trace:
             facts.update(traced.facts(trace_reduce,
@@ -348,6 +377,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         say(f"compared {k}: {v['value']!r} limit {v['limit']!r}")
     say(f"correct: {line['correct']}")
     return line
+
+
+def _brief(v):
+    """A verdict's entry for the log: an array as its size, median and
+    largest value."""
+    if isinstance(v, np.ndarray):
+        return f"n={v.size}" + (f" p50={np.median(v):.3g} max={v.max():.3g}"
+                                if v.size else "")
+    return v
 
 
 def _final_stubs(drv, rec) -> list:

@@ -67,10 +67,10 @@ def test_altered_placements_are_not_correct(monkeypatch):
 
 
 def test_argmax_over_half_the_nodes_is_not_correct(monkeypatch):
-    from benchmark import cluster, reference, traffic
+    from benchmark import cluster, harness, reference, traffic
     from nomad_tpu.scheduler.stack import DenseStack
     seed = 23
-    cl = cluster.Cluster(cluster.load_config("c2m-10k"), seed, N_NODES)
+    cl = cluster.Cluster(harness.load_config("c2m-10k"), seed, N_NODES)
     shapes = traffic.load("backlog")["shapes"]
     hidden_ids = {}               # demand -> ids of the better half
     for shape in shapes.values():

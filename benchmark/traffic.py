@@ -24,9 +24,6 @@ import time
 
 import numpy as np
 
-from benchmark import jobs as jobshapes
-from benchmark.reference import JobSpec
-
 HERE = os.path.dirname(os.path.abspath(__file__))
 _FINAL = {"complete", "failed", "canceled", "blocked"}
 _FALLBACK_S = 2.0      # re-read a job this long after its last wake-up
@@ -74,11 +71,15 @@ class Record:
 
 
 class Driver:
-    """Sends jobs and observes them through ApiClient only."""
+    """Sends jobs and observes them through ApiClient only.  `job_spec`
+    (the reference's record of a job sent) and `build` (shape -> the
+    program's Job) are the configuration's own, handed in by the harness."""
 
-    def __init__(self, address: str, mix: dict, seed: int, span=None):
+    def __init__(self, address: str, mix: dict, seed: int, job_spec, build,
+                 span=None):
         from nomad_tpu.api.client import ApiClient
         self.mix = mix
+        self.job_spec, self.build = job_spec, build
         self.apis = {ns: ApiClient(address, namespace=ns, timeout=120.0)
                      for ns in mix["tenants"]}
         self.order = shape_order(mix, seed)
@@ -132,7 +133,7 @@ class Driver:
             else:
                 ns = self.mix["tenants"][self.count % len(self.mix["tenants"])]
             job_id = f"{phase}{self.count:05d}-{name}"
-            rec = Record(JobSpec(job_id, ns, self.mix["shapes"][name]),
+            rec = Record(self.job_spec(job_id, ns, self.mix["shapes"][name]),
                          phase, due)
             self.records[job_id] = rec
         return rec
@@ -142,7 +143,7 @@ class Driver:
         job's eval failed, or the drain deadline passed."""
         spec = rec.spec
         api = self.apis[spec.namespace]
-        job = jobshapes.build(spec.shape, spec.id, spec.namespace)
+        job = self.build(spec.shape, spec.id, spec.namespace)
         rec.sent = time.monotonic()
         try:
             with self.span("bench.register"):
