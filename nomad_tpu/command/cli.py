@@ -364,7 +364,9 @@ class Cli:
             self.p(f"  Nodes Exhausted = {m.nodes_exhausted}")
             for sm in m.score_meta or []:
                 self.p(f"  {_short(sm.get('node_id', ''))} "
-                       f"norm={sm.get('norm_score', 0):.3f}")
+                       f"norm={sm.get('norm_score', 0):.3f}" + "".join(
+                           f" {k}={v:.3f}"
+                           for k, v in sm.get("scores", {}).items()))
         for name, ts in (a.task_states or {}).items():
             self.p("")
             self.p(f"Task \"{name}\" is \"{ts.state}\"")

@@ -72,6 +72,14 @@ class ClusterMatrix:
         # device-group id -> i32[N] instance capacity / committed usage
         self.device_caps: Dict[str, np.ndarray] = {}
         self.device_used: Dict[str, np.ndarray] = {}
+        # device-group id -> {attribute key -> i32[N] code of the node's
+        # value in `device_attr_values`}; code 0 = the group (or the
+        # attribute) is not on the node.  A device constraint is then one
+        # predicate per distinct value and a gather, never a walk over
+        # node structs (scheduler/feasible.py device_fit).
+        self.device_attr_codes: Dict[str, Dict[str, np.ndarray]] = {}
+        self.device_attr_values: List[object] = [None]
+        self._device_attr_rank: Dict[Tuple[str, object], int] = {}
         # computed-class ordinal per row (-1 = empty row): lets blocked-eval
         # class-eligibility reduce as a vectorized groupby instead of an
         # O(N) Python node walk (reference EvalEligibility keying)
@@ -112,6 +120,9 @@ class ClusterMatrix:
         for k in self.device_used:
             self.device_used[k] = np.concatenate(
                 [self.device_used[k], np.zeros(old, np.int32)])
+        for cols in self.device_attr_codes.values():
+            for k in cols:
+                cols[k] = np.concatenate([cols[k], np.zeros(old, np.int32)])
         self._n_rows = new
 
     # ------------------------------------------------------------- nodes
@@ -155,11 +166,23 @@ class ClusterMatrix:
         # stale groups first — re-registration may drop devices)
         for col in self.device_caps.values():
             col[row] = 0
+        self._clear_device_attrs(row)
         for dev in node.node_resources.devices:
             col = self.device_caps.setdefault(
                 dev.id, np.zeros(self._n_rows, dtype=np.int32))
             # unhealthy instances don't count as schedulable capacity
             col[row] = len(dev.healthy_ids())
+            cols = self.device_attr_codes.setdefault(dev.id, {})
+            for key, value in dev.attributes.items():
+                ident = (type(value).__name__, value)
+                code = self._device_attr_rank.get(ident)
+                if code is None:
+                    code = self._device_attr_rank[ident] = \
+                        len(self.device_attr_values)
+                    self.device_attr_values.append(value)
+                if key not in cols:
+                    cols[key] = np.zeros(self._n_rows, dtype=np.int32)
+                cols[key][row] = code
         self.dyn_port_lo[row] = res.min_dynamic_port
         self.dyn_port_hi[row] = res.max_dynamic_port
         words = np.zeros(_PORT_WORDS, dtype=np.uint32)
@@ -196,9 +219,15 @@ class ClusterMatrix:
             col[row] = 0
         for col in self.device_used.values():
             col[row] = 0
+        self._clear_device_attrs(row)
         self.attrs.clear_row(row)
         self._free_rows.append(row)
         self.generation += 1
+
+    def _clear_device_attrs(self, row: int) -> None:
+        for cols in self.device_attr_codes.values():
+            for col in cols.values():
+                col[row] = 0
 
     # ------------------------------------------------------------- allocs
 

@@ -56,11 +56,15 @@ class NativeBuildError(RuntimeError):
     """The native library could not be built or loaded."""
 
 
-def _build() -> str:
+def _build(build_dir: str = _BUILD_DIR) -> str:
     """Compile the native library, cached by source *content hash* (an
     mtime check could silently prefer a stale or foreign-toolchain binary
     after a checkout).  NOMAD_TPU_NATIVE_LIB overrides with a prebuilt
     .so (the sanitizer CI leg points this at an ASan/UBSan build).
+    Processes that start together on an empty `build_dir` (xdist
+    workers on a fresh checkout) each compile to a temporary name of
+    their own and `os.replace` it into place; whoever finds the library
+    already there returns it.
     Raises NativeBuildError with the compiler's output on failure."""
     override = knobs.get_str("NOMAD_TPU_NATIVE_LIB")
     if override:
@@ -70,29 +74,32 @@ def _build() -> str:
         return override
     if not os.path.exists(_SRC):
         raise NativeBuildError(f"native source missing: {_SRC}")
-    os.makedirs(_BUILD_DIR, exist_ok=True)
+    os.makedirs(build_dir, exist_ok=True)
     with open(_SRC, "rb") as fh:
         digest = hashlib.sha256(fh.read()).hexdigest()[:16]
-    lib_path = os.path.join(_BUILD_DIR, f"libnomad_native-{digest}.so")
+    lib_path = os.path.join(build_dir, f"libnomad_native-{digest}.so")
     if os.path.exists(lib_path):
         return lib_path
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", lib_path + ".tmp", _SRC]
+    tmp = f"{lib_path}.{os.getpid()}.{os.urandom(4).hex()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib_path)
     except subprocess.CalledProcessError as e:
         raise NativeBuildError(
             f"{' '.join(cmd)} exited {e.returncode}:\n"
             f"{e.stderr.decode(errors='replace')}") from e
     except (OSError, subprocess.TimeoutExpired) as e:
         raise NativeBuildError(f"{' '.join(cmd)}: {e}") from e
-    os.replace(lib_path + ".tmp", lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     # prune superseded digests so the build dir doesn't grow unboundedly
-    for name in os.listdir(_BUILD_DIR):
+    for name in os.listdir(build_dir):
         if name.startswith("libnomad_native") and name.endswith(".so") \
                 and name != os.path.basename(lib_path):
             try:
-                os.remove(os.path.join(_BUILD_DIR, name))
+                os.remove(os.path.join(build_dir, name))
             except OSError:
                 pass
     return lib_path

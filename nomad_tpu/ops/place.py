@@ -93,6 +93,12 @@ class PlaceInputs:
     # consumable per-node resources the R-dims don't cover — device
     # instances (reference deviceAllocator free counts) — as a carry.
     place_cap: jax.Array       # i32[G, N]
+    # the `devices` scorer (rank.go: sum of the matched affinity weights
+    # of the group's device asks over the sum of |weight|, appended
+    # whenever that sum is not 0, a zero score included).  A group
+    # without device affinities has `has_dev` false and the scorer off.
+    dev_score: jax.Array       # f32[G, N]
+    has_dev: jax.Array         # bool[G]
     # slots
     demand: jax.Array          # f32[S, R]
     slot_tg: jax.Array         # i32[S]
@@ -190,6 +196,10 @@ def _place_step(inp: PlaceInputs, spread_algorithm: bool, carry, slot):
     sb_on = jnp.any(inp.spread_active[g]) & (sboost != 0.0)
     total = total + jnp.where(sb_on, sboost, 0.0)
     n_scorers = n_scorers + sb_on
+
+    dev_on = inp.has_dev[g]
+    total = total + jnp.where(dev_on, inp.dev_score[g], 0.0)
+    n_scorers = n_scorers + dev_on
 
     final = total / n_scorers
     masked = jnp.where(fits & active, final, -jnp.inf)
@@ -310,7 +320,7 @@ def heavy_dims(inp: PlaceInputs):
 _HEAVY_FIELDS = ("feasible", "affinity", "penalty", "tg_count", "place_cap",
                  "spread_vidx", "spread_desired", "spread_counts",
                  "has_affinity", "desired_count", "spread_targeted",
-                 "spread_wfrac", "spread_active")
+                 "spread_wfrac", "spread_active", "dev_score", "has_dev")
 
 
 def pack_heavy(inp: PlaceInputs) -> np.ndarray:
@@ -352,6 +362,8 @@ def _unpack_heavy(h: jax.Array, G: int, N: int, K: int, Vp1: int):
         spread_targeted=take(G * K, (G, K)) > 0.5,
         spread_wfrac=take(G * K, (G, K)),
         spread_active=take(G * K, (G, K)) > 0.5,
+        dev_score=take(G * N, (G, N)),
+        has_dev=take(G, (G,)) > 0.5,
     )
 
 

@@ -380,7 +380,12 @@ class PlacementEngine:
                       # still in flight on device
                       "donated_carries": 0, "wave_lanes": 0,
                       "lane_evals": 0, "lane_slots": 0,
-                      "overlap_chained": 0}
+                      "overlap_chained": 0,
+                      # device asks (scheduler/generic.py place_on, under
+                      # bulk_gate): placements of groups with a device
+                      # ask, and those whose kernel node had no grantable
+                      # instance and went to a top-K alternative
+                      "device_placements": 0, "device_fallbacks": 0}
         self._cache = _DeviceCache()
         # device-resident worlds: (id(cm), N, mesh identity) ->
         # DeviceWorld (epoch-uploaded capacity/basis, scatter deltas);
@@ -1121,7 +1126,8 @@ class PlacementEngine:
         "feasible", "affinity", "has_affinity", "desired_count",
         "penalty", "tg_count", "spread_vidx", "spread_desired",
         "spread_targeted", "spread_wfrac", "spread_counts",
-        "spread_active", "place_cap", "demand", "slot_tg", "slot_active")
+        "spread_active", "place_cap", "dev_score", "has_dev", "demand",
+        "slot_tg", "slot_active")
 
     def _stack_deltas(self, deltas_per_req, E: int, N: int):
         R = NUM_RESOURCE_DIMS

@@ -130,8 +130,8 @@ def stack_inputs(inputs) -> PlaceInputs:
 _NODE_AXIS = {
     "capacity": 0, "used": 0,
     "feasible": 1, "affinity": 1, "penalty": 1, "tg_count": 1,
-    "spread_vidx": 2, "place_cap": 1,
-    "has_affinity": None, "desired_count": None,
+    "spread_vidx": 2, "place_cap": 1, "dev_score": 1,
+    "has_affinity": None, "desired_count": None, "has_dev": None,
     "spread_desired": None, "spread_targeted": None, "spread_wfrac": None,
     "spread_counts": None, "spread_active": None,
     "demand": None, "slot_tg": None, "slot_active": None,
@@ -143,7 +143,7 @@ def _input_specs(batched: bool) -> PlaceInputs:
     for name, axis in _NODE_AXIS.items():
         ndim = {"capacity": 2, "used": 2, "feasible": 2, "affinity": 2,
                 "penalty": 2, "tg_count": 2, "spread_vidx": 3,
-                "place_cap": 2,
+                "place_cap": 2, "dev_score": 2, "has_dev": 1,
                 "has_affinity": 1, "desired_count": 1, "spread_desired": 3,
                 "spread_targeted": 2, "spread_wfrac": 2, "spread_counts": 3,
                 "spread_active": 2, "demand": 2, "slot_tg": 1,
@@ -198,6 +198,10 @@ def _place_step_sharded(inp: PlaceInputs, spread_algorithm: bool,
     sb_on = jnp.any(inp.spread_active[g]) & (sboost != 0.0)
     total = total + jnp.where(sb_on, sboost, 0.0)
     n_scorers = n_scorers + sb_on
+
+    dev_on = inp.has_dev[g]
+    total = total + jnp.where(dev_on, inp.dev_score[g], 0.0)
+    n_scorers = n_scorers + dev_on
 
     final = total / n_scorers
     masked = jnp.where(fits & active, final, -jnp.inf)
@@ -389,7 +393,8 @@ def _field_specs_batched() -> dict:
         if name in ("capacity", "used"):
             continue
         ndim = {"feasible": 2, "affinity": 2, "penalty": 2, "tg_count": 2,
-                "spread_vidx": 3, "place_cap": 2, "has_affinity": 1,
+                "spread_vidx": 3, "place_cap": 2, "dev_score": 2,
+                "has_dev": 1, "has_affinity": 1,
                 "desired_count": 1, "spread_desired": 3,
                 "spread_targeted": 2, "spread_wfrac": 2,
                 "spread_counts": 3, "spread_active": 2, "demand": 2,

@@ -11,6 +11,7 @@ reference's "escaped" constraint fallback, context.go:252-420).
 from __future__ import annotations
 
 import re
+from dataclasses import dataclass
 from functools import lru_cache
 from typing import List, Optional
 
@@ -19,6 +20,8 @@ import numpy as np
 from nomad_tpu.encode.attrs import AttrTable, hash_code
 from nomad_tpu.encode.matrixizer import ClusterMatrix
 from nomad_tpu.structs.job import Constraint, Operand
+from nomad_tpu.structs.resources import device_id_matches
+from nomad_tpu.scheduler import devices as dv
 from nomad_tpu.scheduler.version import version_matches
 
 
@@ -267,35 +270,174 @@ def csi_volume_mask(cm: ClusterMatrix, snapshot, namespace: str,
     return mask
 
 
-def device_place_cap(cm: ClusterMatrix, requests) -> np.ndarray:
-    """i32[N]: how many instances of this group an eval may place per
-    node = min over requests of floor(free_instances / count), counting
-    committed usage plus the engine's in-flight overlay."""
-    cap = np.full(cm.n_rows, 2**30, np.int64)
+@dataclass
+class DeviceFit:
+    """What a task group's device asks come to on every row."""
+    capable: np.ndarray     # bool[N] DeviceChecker: groups that pass have
+    #                         the healthy instances, whoever holds them
+    place_cap: np.ndarray   # i32[N] placements the free instances allow
+    score: np.ndarray       # f32[N] the `devices` score of the next one
+    has_score: bool         # the asks' affinity weights do not sum to 0
+    multi_level: bool       # on some row the score changes inside the cap
+
+
+def _device_side(cm: ClusterMatrix, gid: str, target: str):
+    """One side of a device constraint on group `gid`: (codes i32[N] into
+    cm.device_attr_values, None) for `${device.attr.<key>}`, else
+    (None, the one parsed value every row has, or None: not found)."""
+    kind, arg = dv.device_target(target)
+    if kind == "lit":
+        return None, arg
+    if kind == "attr":
+        col = cm.device_attr_codes.get(gid, {}).get(arg)
+        return (None, None) if col is None else (col, None)
+    if kind == "unknown":
+        return None, None
+    vendor, dtype, name = gid.split("/")
+    return None, ("str", "", {"vendor": vendor, "type": dtype,
+                              "model": name}[kind])
+
+
+def device_check_mask(cm: ClusterMatrix, gid: str, ltarget: str,
+                      rtarget: str, operand: str) -> np.ndarray:
+    """bool[N]: devices.check_attribute for one constraint (or affinity)
+    of a device ask on group `gid`, evaluated once per distinct pair of
+    values and gathered over the rows."""
+    lcol, lval = _device_side(cm, gid, ltarget)
+    rcol, rval = _device_side(cm, gid, rtarget)
+    n = cm.n_rows
+    if lcol is None and rcol is None:
+        return np.full(n, dv.check_attribute(operand, lval, rval), bool)
+    values = cm.device_attr_values
+    m = len(values)
+    key = ((lcol.astype(np.int64) if lcol is not None else 0) * m
+           + (rcol if rcol is not None else 0))
+    uniq, inverse = np.unique(key, return_inverse=True)
+
+    def side(col, val, code):
+        if col is None:
+            return val
+        return None if code == 0 else dv.parse_attribute(values[code])
+    verdict = np.array([dv.check_attribute(
+        operand, side(lcol, lval, int(k) // m), side(rcol, rval, int(k) % m))
+        for k in uniq], bool)
+    return verdict[inverse]
+
+
+def device_fit(cm: ClusterMatrix, requests, extra_used=None) -> DeviceFit:
+    """DeviceChecker (feasible.go:1192-1295) and AssignDevice
+    (device.go:32-131) for all rows at once.  Per ask, a group is
+    admitted on a row when it answers to the name, passes every one of
+    `req.constraints` there and has a healthy instance; a placement gives
+    each ask the admitted group with `count` free instances whose matched
+    `req.affinities` weigh most, and the row's `devices` score is the
+    matched weights of all asks over the sum of |weight| (rank.go:
+    appended whenever that sum is not 0, a zero included).  Free counts
+    are committed usage plus the engine's in-flight overlay plus
+    `extra_used` ({gid: i32[N]}, what this eval has granted so far).
+
+    Rows where every ask admits at most one group (a fleet with one card
+    model a node) are array work: the score is constant and the cap a
+    division.  A row where an ask admits several groups is walked
+    placement by placement; if its score changes on the way (`multi_level`)
+    the kernel's constant score is right only for the next placement and
+    the scheduler places such an eval one slot at a time."""
     from nomad_tpu.parallel.engine import get_engine
     eng = get_engine()
+    n = cm.n_rows
+    free: dict = {}
+
+    def free_of(gid):
+        if gid not in free:
+            f = cm.device_caps[gid].astype(np.int64) \
+                - cm.device_used.get(gid, 0)
+            inflight = eng.device_overlay(cm, gid) if eng is not None \
+                else None
+            if inflight is not None and inflight.shape[0] == n:
+                f = f - inflight
+            if extra_used and gid in extra_used:
+                f = f - extra_used[gid]
+            free[gid] = f
+        return free[gid]
+
+    asks = []       # (count, total |weight|, [(gid, admitted, matched)])
+    total_w = 0.0
     for req in requests:
-        best = np.zeros(cm.n_rows, np.int64)
-        parts = req.name.split("/")
-        for gid, caps in cm.device_caps.items():
-            vendor, dtype, name = gid.split("/")
-            if len(parts) == 1:
-                match = parts[0] == dtype
-            elif len(parts) == 2:
-                match = parts[0] == dtype and parts[1] == name
-            else:
-                match = ((vendor, dtype, name) == tuple(parts))
-            if not match:
+        tw = sum(abs(float(a.weight)) for a in req.affinities)
+        total_w += tw
+        admitted = []
+        for gid in sorted(cm.device_caps):
+            if not device_id_matches(*gid.split("/"), req.name):
                 continue
-            free = caps.astype(np.int64) - cm.device_used.get(gid, 0)
-            if eng is not None:
-                inflight = eng.device_overlay(cm, gid)
-                if inflight is not None and \
-                        inflight.shape[0] == free.shape[0]:
-                    free = free - inflight
-            best = np.maximum(best, free // max(req.count, 1))
-        cap = np.minimum(cap, best)
-    return np.clip(cap, 0, 2**30).astype(np.int32)
+            ok = cm.device_caps[gid] > 0
+            for c in req.constraints:
+                ok = ok & device_check_mask(cm, gid, c.ltarget, c.rtarget,
+                                            c.operand)
+            w = np.zeros(n)
+            for a in req.affinities:
+                w += float(a.weight) * device_check_mask(
+                    cm, gid, a.ltarget, a.rtarget, a.operand)
+            admitted.append((gid, ok, w))
+        asks.append((max(int(req.count), 1), tw, admitted))
+
+    capable = np.ones(n, bool)
+    several = np.zeros(n, bool)
+    need: dict = {}                      # gid -> instances one placement takes
+    matched = np.zeros(n)
+    for count, _tw, admitted in asks:
+        k = np.zeros(n, np.int64)
+        for gid, ok, w in admitted:
+            k += ok
+            need[gid] = need.get(gid, 0) + count * ok
+            matched += np.where(ok, w, 0.0)
+        capable &= k >= 1
+        several |= k >= 2
+    cap = np.full(n, 2**30, np.int64)
+    for gid, per in need.items():
+        asked = per > 0
+        capable &= ~asked | (cm.device_caps[gid] >= per)
+        cap = np.where(asked, np.minimum(cap, free_of(gid)
+                                         // np.maximum(per, 1)), cap)
+    cap = np.where(capable, np.clip(cap, 0, 2**30), 0)
+    score = matched / total_w if total_w else np.zeros(n)
+
+    multi_level = False
+    for row in np.flatnonzero(several):
+        row = int(row)
+        capable[row] = bool(_device_levels(
+            asks, row, {g: int(cm.device_caps[g][row]) for g in need},
+            total_w, limit=1))
+        levels = _device_levels(
+            asks, row, {g: int(free_of(g)[row]) for g in need}, total_w)
+        cap[row] = len(levels)
+        score[row] = levels[0] if levels else 0.0
+        multi_level |= any(v != levels[0] for v in levels)
+    return DeviceFit(capable=capable, place_cap=cap.astype(np.int32),
+                     score=score.astype(np.float32),
+                     has_score=total_w != 0.0, multi_level=multi_level)
+
+
+def _device_levels(asks, row: int, free: dict, total_w: float,
+                   limit: int = 2**30) -> list:
+    """The `devices` score of each successive placement on one row, until
+    an ask finds no admitted group with room (AssignDevice repeated over
+    its own grants): the walk for rows with several admitted groups."""
+    levels = []
+    while len(levels) < limit:
+        matched = 0.0
+        for count, tw, admitted in asks:
+            best = None
+            for gid, ok, w in admitted:
+                if ok[row] and free[gid] >= count:
+                    choice = w[row] / tw if tw else 0.0
+                    if best is None or choice > best[0]:
+                        best = (choice, gid, w[row])
+            if best is None:
+                return levels
+            free[best[1]] -= count
+            matched += best[2]
+        levels.append(matched / total_w if total_w else 0.0)
+    return levels
 
 
 def host_volume_mask(cm: ClusterMatrix, volumes) -> np.ndarray:
@@ -318,34 +460,8 @@ def host_volume_mask(cm: ClusterMatrix, volumes) -> np.ndarray:
 
 def device_mask(cm: ClusterMatrix, requests,
                 include_usage: bool = True) -> np.ndarray:
-    """DeviceChecker count feasibility (feasible.go:1192): every device
-    request must be satisfiable by some matching device group's capacity.
-    Matching follows NodeDeviceResource.ID semantics (type / type/name /
-    vendor/type/name)."""
-    mask = np.ones(cm.n_rows, dtype=bool)
-    for req in requests:
-        ok = np.zeros(cm.n_rows, dtype=bool)
-        parts = req.name.split("/")
-        for gid, caps in cm.device_caps.items():
-            vendor, dtype, name = gid.split("/")
-            if len(parts) == 1:
-                match = parts[0] == dtype
-            elif len(parts) == 2:
-                match = parts[0] == dtype and parts[1] == name
-            else:
-                match = ((vendor, dtype, name) == tuple(parts))
-            if match:
-                if include_usage:
-                    free = caps - cm.device_used.get(gid, 0)
-                    from nomad_tpu.parallel.engine import get_engine
-                    eng = get_engine()
-                    if eng is not None:
-                        inflight = eng.device_overlay(cm, gid)
-                        if inflight is not None \
-                                and inflight.shape[0] == free.shape[0]:
-                            free = free - inflight
-                else:
-                    free = caps
-                ok |= free >= req.count
-        mask &= ok
-    return mask
+    """DeviceChecker (feasible.go:1192): every device ask finds a group
+    that answers to its name, passes its constraints and has the
+    instances; with `include_usage`, free ones (`device_fit`)."""
+    fit = device_fit(cm, requests)
+    return fit.place_cap > 0 if include_usage else fit.capable

@@ -320,34 +320,39 @@ class GenericScheduler:
         instance picks race-free across workers (basis read, placement,
         id assignment and overlay registration are atomic), mirroring how
         bulk evals serialize.  Everything else runs concurrently."""
-        import contextlib
-
         from nomad_tpu.parallel.engine import get_engine
         eng = get_engine()
         device_eval = any(t.resources.devices
                           for tg in self.job.task_groups
                           for t in tg.tasks)
-        gate = eng.bulk_gate if (eng is not None and device_eval) \
-            else contextlib.nullcontext()
-        with gate:
+        if eng is None or not device_eval:
             self._compute_placements_inner(places, stops, all_allocs)
-            if device_eval and eng is not None:
-                contribs = []
-                for node_id, allocs_ in self.plan.node_allocation.items():
-                    row = self.state.matrix.row_of.get(node_id)
-                    if row is None:
-                        continue
-                    for a_ in allocs_:
-                        for tr_ in a_.allocated_resources.tasks.values():
-                            for d_ in tr_.devices:
-                                gid_ = (f"{d_['vendor']}/{d_['type']}/"
-                                        f"{d_['name']}")
-                                contribs.append(
-                                    (gid_, row,
-                                     len(d_.get("device_ids", []))))
-                if contribs:
-                    self._ext_tickets.append(eng.register_devices(
-                        self.state.matrix, contribs))
+            return
+        t_ask = _time.perf_counter()
+        with eng.bulk_gate:
+            tracing.record("sched.device_gate", t_ask, _time.perf_counter(),
+                           wait=True)
+            self._compute_placements_inner(places, stops, all_allocs)
+            contribs = self._plan_device_grants()
+            if contribs:
+                self._ext_tickets.append(eng.register_devices(
+                    self.state.matrix, contribs))
+
+    def _plan_device_grants(self) -> List[Tuple[str, int, int]]:
+        """[(device group id, row, instances)] the plan's allocations
+        hold: what this eval has granted and no state store has yet."""
+        out = []
+        for node_id, allocs_ in self.plan.node_allocation.items():
+            row = self.state.matrix.row_of.get(node_id)
+            if row is None:
+                continue
+            for a_ in allocs_:
+                for tr_ in a_.allocated_resources.tasks.values():
+                    for d_ in tr_.devices:
+                        out.append((f"{d_['vendor']}/{d_['type']}/"
+                                    f"{d_['name']}", row,
+                                    len(d_.get("device_ids", []))))
+        return out
 
     def _compute_placements_inner(self, places: List[PlacementRequest],
                                   stops, all_allocs: List[Allocation]) -> None:
@@ -491,14 +496,45 @@ class GenericScheduler:
                     groups[gi].demand.astype(np.float32))
         slot_requests = scan_requests
 
-        slots = [tg_index[pr.task_group] for pr in slot_requests]
-        result = None
-        if slots:
+        # one kernel pass places every slot, unless a group's `devices`
+        # score moves inside the pass (a node with several admitted
+        # device groups of different scores, CompiledGroup
+        # .dev_multi_level): the kernel's score is then right for the
+        # next placement only, so such an eval goes one slot a pass and
+        # is re-scored from its own grants in between (exact, and slow:
+        # a fleet with one card model a node never takes this path)
+        one_by_one = any(groups[tg_index[pr.task_group]].dev_multi_level
+                         for pr in slot_requests)
+        rounds = ([[pr] for pr in slot_requests] if one_by_one
+                  else [slot_requests] if slot_requests else [])
+
+        def place_round(prs):
             with tracing.span("sched.feasible"):
                 inputs = stack.build_inputs(
-                    job, groups, slots, allocs_by_tg,
-                    penalty_nodes=penalty_nodes, used_override=used)
-            result = stack.place(inputs, deltas)
+                    job, groups, [tg_index[pr.task_group] for pr in prs],
+                    allocs_by_tg, penalty_nodes=penalty_nodes,
+                    used_override=used)
+            return stack.place(inputs, deltas)
+
+        def rescore(prs):
+            """The next pass of a one-by-one eval: the last pass's ticket
+            stays open, this eval's grants come out of the free counts,
+            and the device groups are compiled again."""
+            if getattr(stack, "last_ticket", None) is not None:
+                self._ext_tickets.append(stack.last_ticket)
+                stack.last_ticket = None
+            grants: Dict[str, np.ndarray] = {}
+            for gid, row, count in self._plan_device_grants():
+                grants.setdefault(
+                    gid, np.zeros(cm.n_rows, np.int64))[row] += count
+            stack.device_grants = grants
+            with tracing.span("sched.feasible"):
+                for gi_, tg_ in enumerate(job.task_groups):
+                    if groups[gi_].place_cap is not None:
+                        groups[gi_] = stack.compile_group(job, tg_)
+            return place_round(prs)
+
+        result = place_round(rounds[0]) if rounds else None
 
         ports = PortClaims(cm)
         now = _time.time()
@@ -516,13 +552,30 @@ class GenericScheduler:
                     if r >= 0 and s > -np.inf and cm.node_ids[r]:
                         entries.append({"node_id": cm.node_ids[r],
                                         "norm_score": round(s, 6)})
+                        if r == int(result.node[i]):
+                            entries[-1]["scores"] = chosen_scores(i, r)
                 m.populate_score_meta(entries)
             m.allocation_time_s = 0.0
             return m
 
+        def chosen_scores(i: int, row: int) -> Dict[str, float]:
+            """The chosen node's scorers by name, as rank.go's ScoreNode
+            names them: `binpack` always, `devices` when the group's
+            device asks carry affinities."""
+            out = {"binpack": round(float(result.fit_score[i]), 6)}
+            g = groups[tg_index[round_prs[i].task_group]]
+            if g.has_dev:
+                out["devices"] = round(float(g.dev_score[row]), 6)
+            return out
+
         def assign_devices(pr, tg, node, row, preempted) -> Optional[Dict]:
+            with tracing.span("sched.assign_devices"):
+                return assign_devices_inner(pr, tg, node, row, preempted)
+
+        def assign_devices_inner(pr, tg, node, row, preempted
+                                 ) -> Optional[Dict]:
             """Assign device instances for every device request of the
-            group (scheduler/device.go AllocateDevice), attempting device
+            group (scheduler/device.go AssignDevice), attempting device
             preemption (PreemptForDevice) when instances are exhausted.
             Returns {task: [assignment dicts]} or None on failure; appends
             extra evictions to `preempted` in place."""
@@ -548,8 +601,8 @@ class GenericScheduler:
             granted: Dict[str, set] = {}   # in-flight grants of THIS alloc
             for t, req in wants:
                 live = [a for a in node_allocs if a.id not in evicted_ids]
-                got = assign_device_instances(node, live, req,
-                                              extra_used=granted)
+                got, _w = assign_device_instances(node, live, req,
+                                                  extra_used=granted)
                 if got is None and preemption_on:
                     nonlocal preemptor
                     if preemptor is None:
@@ -563,8 +616,8 @@ class GenericScheduler:
                         evicted_ids.update(a.id for a in extra)
                         live = [a for a in node_allocs
                                 if a.id not in evicted_ids]
-                        got = assign_device_instances(node, live, req,
-                                                      extra_used=granted)
+                        got, _w = assign_device_instances(
+                            node, live, req, extra_used=granted)
                 if got is None:
                     return None
                 gid = f"{got['vendor']}/{got['type']}/{got['name']}"
@@ -574,7 +627,10 @@ class GenericScheduler:
 
         def place_on(pr: PlacementRequest, row: int, metric: AllocMetric,
                      preempted=None, extra_freed=None,
-                     alt_rows=None) -> bool:
+                     alt_rows=None) -> Optional[Allocation]:
+            """The allocation placed (on `row`, or for a device ask on
+            the first of `alt_rows` that can grant instances), or None
+            with the failure recorded."""
             gi = tg_index[pr.task_group]
             tg = job.task_groups[gi]
             node_id = cm.node_ids[row]
@@ -588,6 +644,9 @@ class GenericScheduler:
             preempted = preempted if preempted is not None else []
             devices = assign_devices(pr, tg, node, row, preempted) \
                 if node is not None else {}
+            if groups[gi].place_cap is not None and _eng is not None:
+                _eng.stats["device_placements"] += 1
+                _eng.stats["device_fallbacks"] += devices is None
             if devices is None:
                 # the dense kernel scores cpu/mem, not per-node device
                 # instances; earlier placements of THIS eval may have
@@ -614,7 +673,7 @@ class GenericScheduler:
                         break
                 else:
                     self._fail_placement(pr, metric, "devices exhausted")
-                    return False
+                    return None
             freed = set(freed_ports.get(row, set()))
             if extra_freed:
                 freed |= extra_freed
@@ -628,7 +687,7 @@ class GenericScheduler:
                 task_devices=devices)
             if alloc is None:
                 self._fail_placement(pr, metric, "ports exhausted")
-                return False
+                return None
             if pr.previous_alloc is not None:
                 pr.previous_alloc.next_allocation = alloc.id
             if preempted:
@@ -641,7 +700,7 @@ class GenericScheduler:
                 state = self.plan.deployment.task_groups.get(tg.name)
                 if state is not None:
                     state.placed_canaries.append(alloc.id)
-            return True
+            return alloc
 
         # preemption for failed slots (BinPackIterator's evict path,
         # rank.go:500-530; gated by SchedulerConfiguration like the
@@ -771,8 +830,10 @@ class GenericScheduler:
                     m.nodes_exhausted = n_exh
                     if not try_preempt(pr, None):
                         self._fail_placement(pr, m, "exhausted")
-            if result is not None:
-                for i, pr in enumerate(slot_requests):
+            for n_round, round_prs in enumerate(rounds):
+                if n_round:
+                    result = rescore(round_prs)
+                for i, pr in enumerate(round_prs):
                     row = int(result.node[i])
                     if row < 0:
                         if not try_preempt(pr, i):
@@ -781,9 +842,16 @@ class GenericScheduler:
                     else:
                         extra = []
                         alts = result.top_nodes[i]
-                        place_on(pr, row, metric_for(i), preempted=extra,
-                                 alt_rows=alts)
+                        alloc = place_on(pr, row, metric_for(i),
+                                         preempted=extra, alt_rows=alts)
                         account_device_evictions(row, extra)
+                        if one_by_one and alloc is not None:
+                            # the next pass scores against this one
+                            if alloc.node_id == cm.node_ids[row]:
+                                used[row] += groups[
+                                    tg_index[pr.task_group]].demand
+                            allocs_by_tg.setdefault(
+                                pr.task_group, []).append(alloc)
 
     @staticmethod
     def _bulk_node_fields(cm, g, allocs_by_tg, penalty_nodes):

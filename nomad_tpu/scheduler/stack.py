@@ -15,6 +15,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from nomad_tpu import tracing
 from nomad_tpu.encode.attrs import AttrTable
 from nomad_tpu.encode.matrixizer import (
     ClusterMatrix,
@@ -86,6 +87,13 @@ class CompiledGroup:
     # per-node placement capacity for this eval (instances the group may
     # still place per node; -1 = unlimited)
     place_cap: Optional[np.ndarray] = None            # i32[N]
+    # the `devices` scorer: the score per node and whether the group's
+    # device asks carry affinities at all (feasible.DeviceFit)
+    dev_score: Optional[np.ndarray] = None            # f32[N]
+    has_dev: bool = False
+    # some node's `devices` score changes inside its place_cap: the
+    # scheduler places such a group one slot at a time
+    dev_multi_level: bool = False
     # constraint-only feasibility (datacenter/constraints/driver/volumes,
     # no readiness or capacity): the class-constant verdict that keys
     # blocked-eval unblocking — a down node or exhausted device must not
@@ -101,6 +109,9 @@ class DenseStack:
         self.cm = cm
         self.config = config or SchedulerConfiguration()
         self.snapshot = snapshot   # state view for CSI volume/claim reads
+        # {device group id: i32[N]} instances this eval has granted and
+        # no plan carries yet, taken out of the free counts on recompile
+        self.device_grants: Optional[Dict[str, np.ndarray]] = None
         self.spread_algorithm = (
             self.config.effective_scheduler_algorithm() == SCHEDULER_ALGORITHM_SPREAD)
 
@@ -148,19 +159,22 @@ class DenseStack:
         # feasible.go:1192); instance AVAILABILITY applies after the
         # preemption-eligibility snapshot so device preemption can still
         # target instance-exhausted nodes
+        fit = None
         if dev_reqs:
-            mask &= fz.device_mask(cm, dev_reqs, include_usage=False)
+            with tracing.span("sched.device_mask"):
+                fit = fz.device_fit(cm, dev_reqs, self.device_grants)
+            mask &= fit.capable
         feasible_pre_ports = mask.copy()
         device_blocked = None
         place_cap = None
-        if dev_reqs:
-            avail = fz.device_mask(cm, dev_reqs)
+        if fit is not None:
+            avail = fit.place_cap > 0
             device_blocked = mask & ~avail
             mask = mask & avail
             # per-node instance budget for this eval: the kernel's
             # place_cap carry stops it over-subscribing a node's free
             # instances within one eval (deviceAllocator free counts)
-            place_cap = fz.device_place_cap(cm, dev_reqs)
+            place_cap = fit.place_cap
         static_ports = group_static_ports(tg)
         if static_ports:
             mask &= cm.static_ports_free(static_ports)
@@ -190,6 +204,9 @@ class DenseStack:
                              static_ports=static_ports,
                              device_blocked=device_blocked,
                              place_cap=place_cap,
+                             dev_score=fit.score if fit else None,
+                             has_dev=bool(fit and fit.has_score),
+                             dev_multi_level=bool(fit and fit.multi_level),
                              class_feasible=class_feasible)
 
     # ------------------------------------------------------------- assemble
@@ -319,9 +336,14 @@ class DenseStack:
                             scounts[gi, ki, rank[col.values[row]]] += 1
 
         place_cap = np.full((G, N), -1, np.int32)
+        dev_score = np.zeros((G, N), np.float32)
+        has_dev = np.zeros(G, bool)
         for gi, g in enumerate(groups):
             if g.place_cap is not None:
                 place_cap[gi] = g.place_cap
+            if g.has_dev:
+                dev_score[gi] = g.dev_score
+                has_dev[gi] = True
 
         demand = np.zeros((S, R), np.float32)
         slot_tg = np.zeros(S, np.int32)
@@ -339,7 +361,7 @@ class DenseStack:
             desired_count=desired, penalty=penalty, tg_count=tg_count,
             spread_vidx=vidx, spread_desired=sdesired, spread_targeted=stargeted,
             spread_wfrac=swfrac, spread_counts=scounts, spread_active=sactive,
-            place_cap=place_cap,
+            place_cap=place_cap, dev_score=dev_score, has_dev=has_dev,
             demand=demand, slot_tg=slot_tg, slot_active=slot_active,
         )
 

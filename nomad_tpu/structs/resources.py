@@ -45,10 +45,19 @@ class NetworkResource:
 @dataclass
 class DeviceRequest:
     """A task's request for devices (reference structs.RequestedDevice)."""
-    name: str = ""            # "vendor/type/model", "type/model" or "type"
+    name: str = ""            # "vendor/type/model", "vendor/type" or "type"
     count: int = 1
     constraints: List = field(default_factory=list)   # List[Constraint]
     affinities: List = field(default_factory=list)    # List[Affinity]
+
+    def __post_init__(self):
+        # the wire codec rebuilds untyped lists as dicts (job.py imports
+        # this module, so the element types cannot be declared here)
+        from nomad_tpu.structs.job import Affinity, Constraint
+        self.constraints = [Constraint(**c) if isinstance(c, dict) else c
+                            for c in self.constraints]
+        self.affinities = [Affinity(**a) if isinstance(a, dict) else a
+                           for a in self.affinities]
 
 
 @dataclass
@@ -74,17 +83,20 @@ class NodeDevice:
         return [i for i in self.instance_ids if i not in bad]
 
     def matches(self, requested: str) -> bool:
-        """Match semantics of structs.NodeDeviceResource.ID matching:
-        request may be 'type', 'type/name' or 'vendor/type/name'."""
-        parts = requested.split("/")
-        if len(parts) == 1:
-            return parts[0] == self.type
-        if len(parts) == 2:
-            return parts[0] == self.type and parts[1] == self.name
-        if len(parts) == 3:
-            return (parts[0] == self.vendor and parts[1] == self.type
-                    and parts[2] == self.name)
-        return False
+        return device_id_matches(self.vendor, self.type, self.name, requested)
+
+
+def device_id_matches(vendor: str, dtype: str, name: str,
+                      requested: str) -> bool:
+    """DeviceIdTuple.Matches on RequestedDevice.ID (structs.go): an ask
+    names `type`, `vendor/type` or `vendor/type/model`, as the job
+    specification's `device` block documents (`device "nvidia/gpu"`)."""
+    parts = requested.split("/", 2)
+    if len(parts) == 1:
+        return parts[0] == dtype
+    if len(parts) == 2:
+        return (parts[0], parts[1]) == (vendor, dtype)
+    return (parts[0], parts[1], parts[2]) == (vendor, dtype, name)
 
 
 @dataclass

@@ -32,10 +32,11 @@ def test_unhealthy_instances_excluded_from_capacity_and_assignment():
     row = cm.upsert_node(n)
     assert int(cm.device_caps["nvidia/gpu/a100"][row]) == 1
 
-    got = assign_device_instances(n, [], DeviceRequest(name="gpu", count=1))
+    got, _weight = assign_device_instances(
+        n, [], DeviceRequest(name="gpu", count=1))
     assert got["device_ids"] == ["g1"]
     assert assign_device_instances(
-        n, [], DeviceRequest(name="gpu", count=2)) is None
+        n, [], DeviceRequest(name="gpu", count=2)) == (None, 0.0)
 
 
 def test_device_death_reschedules_allocs():

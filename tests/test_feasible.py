@@ -7,7 +7,7 @@ from nomad_tpu.scheduler import feasible as fz
 from nomad_tpu.scheduler.stack import DenseStack
 from nomad_tpu.scheduler.version import version_matches
 from nomad_tpu.structs.job import Constraint, Operand, Task, TaskGroup
-from nomad_tpu.structs.resources import NodeDevice
+from nomad_tpu.structs.resources import DeviceRequest, NodeDevice
 
 
 def test_version_matching_semantics():
@@ -74,13 +74,11 @@ def test_device_caps_cleared_on_reregister():
     n = mock.node()
     n.node_resources.devices = [NodeDevice("nvidia", "gpu", "t4", ["i0", "i1"])]
     cm.upsert_node(n)
-    class Req:
-        name = "gpu"
-        count = 1
-    assert fz.device_mask(cm, [Req()])[cm.row_of[n.id]]
+    req = DeviceRequest(name="gpu", count=1)
+    assert fz.device_mask(cm, [req])[cm.row_of[n.id]]
     n.node_resources.devices = []
     cm.upsert_node(n)
-    assert not fz.device_mask(cm, [Req()])[cm.row_of[n.id]]
+    assert not fz.device_mask(cm, [req])[cm.row_of[n.id]]
 
 
 def test_tg_level_distinct_hosts_scoped_to_group():

@@ -1,6 +1,8 @@
 """Native C++ kernel tests — and parity between the native and numpy
 twin paths (reference analogs: structs/funcs_test.go AllocsFit/
 ScoreFit tests, plan_apply_test.go node validation)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -152,3 +154,27 @@ def test_native_numpy_parity():
     score_c = native.score_fit(cap, used, demand)
     assert (fit_np == fit_c).all()
     np.testing.assert_allclose(score_np, score_c, atol=1e-4)
+
+
+def test_six_processes_build_into_an_empty_directory(tmp_path):
+    """A fresh checkout under `pytest -n 6`: every worker finds
+    `native/build` empty and compiles.  Each compiles to a name of its
+    own and `os.replace`s it, so all six end with a library that loads
+    (they used to share one `.tmp` name, and the losers of the race
+    raised FileNotFoundError from `os.replace`)."""
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import ctypes, sys; from nomad_tpu import native; "
+            "p = native._build(sys.argv[1]); ctypes.CDLL(p); print(p)")
+    env = {k: v for k, v in os.environ.items()
+           if k != "NOMAD_TPU_NATIVE_LIB"}
+    build = str(tmp_path / "build")
+    procs = [subprocess.Popen([sys.executable, "-c", code, build], cwd=root,
+                              env=env, stdout=subprocess.PIPE,
+                              stderr=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    outs = [p.communicate(timeout=300) for p in procs]
+    assert [p.returncode for p in procs] == [0] * 6, [e for _o, e in outs]
+    assert len({o.strip() for o, _e in outs}) == 1
+    assert [n for n in os.listdir(build) if not n.endswith(".so")] == []
