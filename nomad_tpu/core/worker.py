@@ -40,6 +40,10 @@ TRANSIENT_ERRORS = (NotLeaderError, LeadershipLostError, RpcError,
                     Unreachable, concurrent.futures.TimeoutError,
                     TimeoutError)
 
+# how long an idle worker parks in one dequeue before it looks at its
+# own state again (stop, deferred settles)
+DEQUEUE_TIMEOUT = 0.1
+
 
 def _submit_by_namespace(plan: Plan, seconds: float) -> None:
     """Per-namespace plan-submit latency: the fairness gate in the
@@ -72,7 +76,8 @@ class Worker:
         # at once; 0 restores strict blocking submits.
         self.pipeline_depth = max(0, knobs.get_int(
             "NOMAD_TPU_PIPELINE_DEPTH"))
-        # (ev, token, [PendingPlan]) awaiting durable commit, oldest first
+        # (ev, token, [PendingPlan], perf_counter at defer) awaiting
+        # durable commit, oldest first
         self._deferred = deque()
         self._eval_pendings: List = []
         self.stats = {"processed": 0, "failed": 0,
@@ -98,9 +103,20 @@ class Worker:
 
     def run(self) -> None:
         while not self._stop.is_set():
-            got = self._dequeue()
+            # a worker that holds deferred evals only asks the broker,
+            # and with nothing to run waits on its oldest commit instead:
+            # parked in the dequeue, a lone eval's COMPLETE would sit out
+            # the whole timeout behind a commit of a few milliseconds.
+            # The wait is one dequeue timeout at most, so a stalled
+            # commit keeps this worker from a new eval no longer than an
+            # empty broker does.
+            got = self._dequeue(0.0 if self._deferred else DEQUEUE_TIMEOUT)
             self._drain_deferred()
             if got is None:
+                if self._deferred:
+                    concurrent.futures.wait(
+                        [p.future for p in self._deferred[0][2]],
+                        timeout=DEQUEUE_TIMEOUT)
                 continue
             ev, token = got
             if self._stop.is_set():
@@ -144,15 +160,14 @@ class Worker:
         settles for free; beyond `pipeline_depth` outstanding, block on
         the oldest so the pipeline stays bounded."""
         while self._deferred:
-            ev, token, pendings = self._deferred[0]
+            pendings = self._deferred[0][2]
             if len(self._deferred) <= self.pipeline_depth and \
                     not all(p.future.done() for p in pendings):
                 return
-            self._deferred.popleft()
-            self._settle_eval(ev, token, pendings)
+            self._settle_eval(*self._deferred.popleft())
 
     def _settle_eval(self, ev: Evaluation, token: str,
-                     pendings: List) -> None:
+                     pendings: List, deferred_at: float) -> None:
         """Deferred tail of process_eval: wait for the durable commits
         backing this eval's plans, then publish COMPLETE and ack.  If a
         commit failed mid-flight, the speculative result is discarded —
@@ -174,6 +189,10 @@ class Worker:
             # worker dies between commit and ack: the lease expires and
             # the redelivered eval no-ops via plan dedup
             return
+        # deferred -> COMPLETE written: what the pipeline adds to an
+        # eval's time once its scheduler has returned
+        tracing.record("worker.settle_wait", deferred_at,
+                       time.perf_counter(), wait=True)
         try:
             self.server.update_eval(ev)
             if self._ack(ev.id, token):
@@ -187,19 +206,19 @@ class Worker:
 
     # -- broker ops, overridable for the RPC path (RemoteWorker)
 
-    def _dequeue(self):
+    def _dequeue(self, timeout: float):
         feeder = getattr(self.server, "eval_feeder", None)
         if feeder is not None:
             # wave-aligned path: one pool member drains a whole ready
             # wave in one broker pass; the rest pick from the buffer
-            got = feeder.get(self.enabled_schedulers, timeout=0.1)
+            got = feeder.get(self.enabled_schedulers, timeout=timeout)
             if got is None:
                 return None
             ev, token = got
             self.stats["wave_dequeues"] += 1
         else:
             ev, token = self.server.broker.dequeue(
-                self.enabled_schedulers, timeout=0.1)
+                self.enabled_schedulers, timeout=timeout)
             if ev is None:
                 return None
         self._wait_index = self.server.store.latest_index
@@ -253,7 +272,8 @@ class Worker:
         if pendings:
             # pipelined submits are still committing: defer the
             # COMPLETE/ack settle and move on to the next eval now
-            self._deferred.append((ev, token, pendings))
+            self._deferred.append(
+                (ev, token, pendings, time.perf_counter()))
             self._drain_deferred()
             return
         server.update_eval(ev)
@@ -343,11 +363,11 @@ class RemoteWorker(Worker):
                 self._stop.wait(sleep * (0.5 + random.random() * 0.5))
                 delay = min(delay * 2.0, 0.5)
 
-    def _dequeue(self):
+    def _dequeue(self, timeout: float):
         try:
             resp = self._rpc("Eval.Dequeue",
                              {"schedulers": self.enabled_schedulers,
-                              "timeout": 0.1})
+                              "timeout": timeout})
         except TRANSIENT_ERRORS:
             self._stop.wait(0.05)
             return None

@@ -337,7 +337,8 @@ SPINE_SPANS = (
     "sched.wait_engine", "engine.queue_wait", "engine.idle",
     "engine.stack", "engine.put", "engine.device_get", "engine.resolve",
     "engine.dispatch", "plan.submit", "plan.queue_wait", "plan.evaluate",
-    "plan.commit", "raft.fsm_apply", "native.validate_plan",
+    "plan.commit", "worker.settle_wait", "raft.fsm_apply",
+    "native.validate_plan",
     "native.scatter_add_rank1", "native.expand_pairs",
     "native.format_uuids")
 
@@ -367,9 +368,12 @@ def test_invoke_scheduler_count_is_evals_processed(spine_metrics):
 
 
 @pytest.mark.parametrize("name", ["broker.wait", "plan.queue_wait",
-                                  "engine.queue_wait"])
+                                  "engine.queue_wait",
+                                  "worker.settle_wait"])
 def test_queue_waits_are_counted_with_no_tracer(spine_metrics, name):
-    """One per eval / plan / engine request, sampled or not."""
+    """One per eval / plan / engine request / deferred eval (each of the
+    two evals submits one plan, so each is deferred once), sampled or
+    not."""
     moved = spine_metrics["samples"]["nomad." + name]["count"] \
         - spine_metrics["before"].get("nomad." + name, 0)
     assert moved == 2

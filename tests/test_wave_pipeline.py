@@ -395,7 +395,7 @@ def test_worker_settle_discards_on_commit_failure():
     pend = PendingPlan.__new__(PendingPlan)
     pend.future = Future()
     pend.future.set_exception(RuntimeError("commit failed"))
-    w._settle_eval(ev, "tok-1", [pend])
+    w._settle_eval(ev, "tok-1", [pend], time.perf_counter())
     assert srv.nacked == [(ev.id, "tok-1")]
     assert not srv.acked and not srv.updated
     assert w.stats["pipeline_discards"] == 1
@@ -404,7 +404,7 @@ def test_worker_settle_discards_on_commit_failure():
     ok.future = Future()
     ok.future.set_result(object())
     ev2 = _eval(job="j2")
-    w._settle_eval(ev2, "tok-2", [ok])
+    w._settle_eval(ev2, "tok-2", [ok], time.perf_counter())
     assert srv.acked == [(ev2.id, "tok-2")]
     assert srv.updated and srv.updated[0] is ev2
     assert w.stats["processed"] == 1
@@ -448,7 +448,8 @@ def test_expired_lease_behind_stalled_commit_settles_exactly_once():
     stalled = PendingPlan.__new__(PendingPlan)
     stalled.future = Future()
     settle = threading.Thread(
-        target=w._settle_eval, args=(got, stale_token, [stalled]),
+        target=w._settle_eval,
+        args=(got, stale_token, [stalled], time.perf_counter()),
         daemon=True)
     settle.start()
 
@@ -480,7 +481,7 @@ def test_expired_lease_behind_stalled_commit_settles_exactly_once():
     landed = PendingPlan.__new__(PendingPlan)
     landed.future = Future()
     landed.future.set_result(object())
-    w._settle_eval(ev2, fresh_token, [landed])
+    w._settle_eval(ev2, fresh_token, [landed], time.perf_counter())
     assert w.stats["processed"] == 1
     assert broker.stats["acked"] == 1
     assert broker.stats["nacked"] == 1       # exactly one redelivery, ever
@@ -489,3 +490,241 @@ def test_expired_lease_behind_stalled_commit_settles_exactly_once():
     again, _ = broker.dequeue(["batch"], timeout=0.1)
     assert again is None
     assert broker.unacked_count() == 0
+
+
+# ------------------------------------------- settle at the commit's landing
+
+class _GatedBroker(EvalBroker):
+    """A real broker whose WAITING dequeue parks until an eval arrives or
+    the test's teardown sets `released`, however long that takes: never
+    the clock.  What was asked, and how, is kept in `asked`."""
+
+    def __init__(self):
+        super().__init__(nack_timeout=60.0, initial_nack_delay=60.0)
+        self.set_enabled(True)
+        self.asked = []
+        self.released = threading.Event()
+
+    def dequeue(self, schedulers, timeout=0.0):
+        self.asked.append(timeout)
+        while True:
+            got = super().dequeue(schedulers, timeout=min(timeout, 0.05))
+            if got[0] is not None or timeout <= 0 or \
+                    self.released.is_set():
+                return got
+
+
+class _HeldCommitServer:
+    """Server surface for `Worker.run` with the applier faked: every
+    submitted plan is evaluated at once and its durable commit is the
+    test's to land (`plans[i].future`)."""
+
+    name = "t"
+    eval_feeder = None
+    latest_index = 0
+
+    def __init__(self):
+        self.broker = _GatedBroker()
+        self.store = self
+        self.plans, self.updated = [], []
+
+    def snapshot_min_index(self, index):
+        return object()
+
+    def enqueue_plan(self, plan):
+        from nomad_tpu.core.plan_queue import PendingPlan
+        pend = PendingPlan(plan)
+        pend.evaluated.set_result(object())
+        self.plans.append(pend)
+        return pend
+
+    def update_eval(self, ev):
+        self.updated.append(ev)
+
+
+class _OnePlanScheduler:
+    def __init__(self, planner):
+        self.planner = planner
+
+    def process(self, ev):
+        self.planner.submit_plan(Plan(eval_id=ev.id, job=mock.job()))
+
+
+def _until(cond, timeout=10.0):
+    deadline = time.time() + timeout
+    while time.time() < deadline and not cond():
+        time.sleep(0.005)
+    return cond()
+
+
+@pytest.fixture
+def lone_worker(monkeypatch):
+    """A started local worker over `_HeldCommitServer`, its scheduler
+    replaced by one that submits one plan an eval."""
+    from nomad_tpu.core import worker as worker_mod
+
+    monkeypatch.setattr(
+        worker_mod.factory, "new_scheduler",
+        lambda _type, _snap, planner: _OnePlanScheduler(planner))
+    srv = _HeldCommitServer()
+    w = worker_mod.Worker(srv, enabled_schedulers=["batch"])
+    assert w.pipeline_depth > 0
+    w.start()
+    yield srv, w
+    for pend in srv.plans:               # let a failed test's worker go
+        if not pend.future.done():
+            pend.future.set_result(object())
+    w.stop()
+    srv.broker.released.set()
+    w.join(10.0)
+    assert not w._thread.is_alive()
+
+
+def _defer_one(srv, ev):
+    """Enqueue `ev` and wait until its worker, the commit held, has asked
+    the broker once more without waiting: it now waits on the commit."""
+    n_plans = len(srv.plans)
+    srv.broker.enqueue(ev)
+    assert _until(lambda: len(srv.plans) > n_plans)
+    asked = len(srv.broker.asked)
+    assert _until(lambda: 0.0 in srv.broker.asked[asked:]), \
+        "the worker parked in a waiting dequeue with an eval deferred"
+    return srv.plans[-1]
+
+
+def test_lone_eval_settles_when_its_commit_lands(lone_worker, monkeypatch):
+    """(fails on the parent of PR 31) With nothing else in the broker a
+    deferred eval is COMPLETE and acked as soon as its commit resolves:
+    the worker is not parked in its next dequeue for the timeout."""
+    from nomad_tpu import tracing
+    from nomad_tpu.structs import EvalStatus
+
+    srv, w = lone_worker
+    recorded = []
+    record = tracing.record
+
+    def recording(name, start, end, **kw):
+        recorded.append((name, start, end, kw))
+        record(name, start, end, **kw)
+
+    monkeypatch.setattr(tracing, "record", recording)
+
+    ev = _eval()
+    pend = _defer_one(srv, ev)
+    assert not srv.updated and srv.broker.stats["acked"] == 0
+    assert srv.broker.outstanding(ev.id)
+
+    pend.future.set_result(object())
+    assert _until(lambda: srv.broker.stats["acked"] == 1)
+    assert [(e.id, e.status) for e in srv.updated] == \
+        [(ev.id, EvalStatus.COMPLETE)]
+    assert srv.broker.outstanding(ev.id) is None
+    assert w.stats["processed"] == w.stats["pipelined_evals"] == 1
+    # no waiting dequeue but the one that delivered the eval returned:
+    # nothing the test did released a second one
+    assert _until(lambda: srv.broker.asked[-1] > 0)
+    assert [t for t in srv.broker.asked[:-1] if t > 0] == \
+        srv.broker.asked[:1]
+    # one settle-wait a deferred eval, a wait, from defer to COMPLETE
+    waits = [r for r in recorded if r[0] == "worker.settle_wait"]
+    assert len(waits) == 1
+    _name, start, end, kw = waits[0]
+    assert kw == {"wait": True} and start <= end
+
+
+def test_lone_eval_is_nacked_when_its_commit_fails(lone_worker):
+    srv, w = lone_worker
+    ev = _eval()
+    pend = _defer_one(srv, ev)
+    pend.future.set_exception(RuntimeError("commit failed"))
+    assert _until(lambda: srv.broker.stats["nacked"] == 1)
+    assert w.stats["pipeline_discards"] == 1
+    assert not srv.updated and srv.broker.stats["acked"] == 0
+    assert w.stats["processed"] == 0
+
+
+def test_stalled_commit_does_not_keep_its_worker_from_a_new_eval(
+        lone_worker):
+    """The wait on a commit is a bounded slice: an eval enqueued while
+    the commit stalls is taken at the worker's next ask, and both settle
+    in order once their commits land."""
+    srv, w = lone_worker
+    first = _eval(job="j1")
+    stalled = _defer_one(srv, first)
+    asked = len(srv.broker.asked)
+    second = _eval(job="j2")
+    srv.broker.enqueue(second)
+    assert _until(lambda: len(srv.plans) == 2)
+    # at most the ask in flight when it arrived, and the next one
+    assert len(srv.broker.asked) <= asked + 2
+    assert srv.broker.asked[asked:] == \
+        [0.0] * (len(srv.broker.asked) - asked)
+    assert not srv.updated, "the stalled commit's eval settled early"
+
+    srv.plans[1].future.set_result(object())
+    stalled.future.set_result(object())
+    assert _until(lambda: srv.broker.stats["acked"] == 2)
+    assert [e.id for e in srv.updated] == [first.id, second.id]
+    assert w.stats["pipelined_evals"] == 2
+
+
+def test_stop_during_the_commit_wait_settles_what_is_deferred(lone_worker):
+    srv, w = lone_worker
+    ev = _eval()
+    pend = _defer_one(srv, ev)
+    w.stop()
+    assert not srv.updated
+    pend.future.set_result(object())
+    w.join(10.0)
+    assert not w._thread.is_alive()
+    assert [e.id for e in srv.updated] == [ev.id]
+    assert srv.broker.stats["acked"] == 1
+    assert srv.broker.unacked_count() == 0
+
+
+def test_remote_worker_never_defers_and_keeps_its_long_poll(monkeypatch):
+    """`RemoteWorker` shares `run()`; its `submit_plan` blocks on the
+    `Plan.Submit` RPC, so nothing is deferred, its evals settle inline
+    and every `Eval.Dequeue` stays the long poll it was."""
+    from nomad_tpu.core import worker as worker_mod
+
+    monkeypatch.setattr(
+        worker_mod.factory, "new_scheduler",
+        lambda _type, _snap, planner: _OnePlanScheduler(planner))
+
+    class _Member(_HeldCommitServer):
+        def __init__(self):
+            super().__init__()
+            self.calls = []
+            self.ev = _eval()
+            self.idle = threading.Event()
+
+        def rpc_leader(self, method, args):
+            self.calls.append((method, args.get("timeout")))
+            if method == "Eval.Dequeue":
+                if self.ev is None:
+                    self.idle.wait(args["timeout"])
+                    return None
+                ev, self.ev = self.ev, None
+                return {"eval": ev, "token": "tok", "wait_index": 0}
+            return {"Plan.Submit": object(), "Eval.Ack": {"ok": True}}[method]
+
+    srv = _Member()
+    ev = srv.ev
+    w = worker_mod.RemoteWorker(srv, enabled_schedulers=["batch"])
+    w.start()
+    try:
+        assert _until(lambda: [m for m, _ in srv.calls].count(
+            "Eval.Dequeue") >= 4)
+    finally:
+        w.stop()
+        srv.idle.set()
+        w.join(10.0)
+    assert not w._thread.is_alive()
+    assert [e.id for e in srv.updated] == [ev.id]
+    assert [m for m, _ in srv.calls[:3]] == \
+        ["Eval.Dequeue", "Plan.Submit", "Eval.Ack"]
+    assert {t for m, t in srv.calls if m == "Eval.Dequeue"} == \
+        {worker_mod.DEQUEUE_TIMEOUT}
+    assert not srv.plans and not w._deferred
+    assert w.stats["processed"] == 1 and w.stats["pipelined_evals"] == 0
