@@ -88,8 +88,8 @@ class _SteadyGate:
         from nomad_tpu.parallel.engine import get_engine
         self._eng = get_engine()
         self.budget = recompile.Budget()
-        self._world0 = self._eng.world_stats() if self._eng else {}
-        self._eng0 = dict(self._eng.stats) if self._eng else {}
+        self._world0 = self._eng.world_stats()
+        self._eng0 = dict(self._eng.stats)
         self._guard = transfer_purity.steady_state_guard()
         self._guard.__enter__()
         return self
@@ -101,7 +101,7 @@ class _SteadyGate:
             return False
         from nomad_tpu.telemetry import global_metrics
         rep = self.budget.report()
-        wstats = self._eng.world_stats() if self._eng else {}
+        wstats = self._eng.world_stats()
         reuploads = wstats.get("steady_reuploads", 0) - \
             self._world0.get("steady_reuploads", 0)
         violations = self.budget.violations()
@@ -109,17 +109,16 @@ class _SteadyGate:
             violations.append(
                 f"{reuploads} full world re-upload(s) during the "
                 f"measured window (steady state must scatter rows only)")
-        estats = dict(self._eng.stats) if self._eng else {}
+        estats = dict(self._eng.stats)
         donated = estats.get("donated_carries", 0) - \
             self._eng0.get("donated_carries", 0)
         bulk_parts = estats.get("bulk_parts", 0) - \
             self._eng0.get("bulk_parts", 0)
         adopts = wstats.get("basis_adopts", 0) - \
             self._world0.get("basis_adopts", 0)
-        if self._eng is not None and getattr(self._eng, "donate", False) \
-                and bulk_parts > 0 and (donated <= 0 or adopts <= 0):
+        if bulk_parts > 0 and (donated <= 0 or adopts <= 0):
             violations.append(
-                f"donation enabled but {bulk_parts} bulk dispatch(es) "
+                f"{bulk_parts} bulk dispatch(es) "
                 f"produced donated_carries={donated} basis_adopts={adopts} "
                 f"(steady state must keep the usage basis resident via "
                 f"donated carries, not re-download + re-upload it)")
@@ -215,9 +214,7 @@ def bench_e2e_spine(n_nodes=1000, n_jobs=50, count=100, workers=48):
     dt = time.time() - t0
 
     from nomad_tpu.parallel.engine import get_engine
-    eng = get_engine()
-    if eng:
-        log(f"engine stats: {eng.stats}")
+    log(f"engine stats: {get_engine().stats}")
     s.stop()
     log(f"e2e spine: placed {placed} allocs in {dt:.2f}s "
         f"({placed/dt:.0f} allocs/s, {n_jobs/dt:.1f} evals/s, "
@@ -302,8 +299,6 @@ def _warm_engine(s, scan_job=None, bulk_job=None):
     from nomad_tpu.parallel.engine import get_engine
     from nomad_tpu.scheduler.stack import DenseStack
     eng = get_engine()
-    if eng is None:
-        return
     cm = s.store.matrix
     inputs = None
     bulk = None
@@ -451,9 +446,8 @@ def bench_c2m_1m(n_nodes=10000, n_jobs=10000, groups_per_job=10,
             log(f"{scenario} applier stats: {s.applier.stats}")
         from nomad_tpu.parallel.engine import get_engine
         eng = get_engine()
-        if eng:
-            log(f"{scenario} engine stats: {eng.stats}")
-            _ENGINE_SNAP[scenario] = dict(eng.stats)
+        log(f"{scenario} engine stats: {eng.stats}")
+        _ENGINE_SNAP[scenario] = dict(eng.stats)
         _log_plan_submit(scenario)
         return placed / dt, placed, want
     finally:
@@ -735,7 +729,7 @@ def bench_scan_spread(n_nodes=10000, n_jobs=60, count=100, workers=48):
         _wait_allocs(s.store, [w], 50, timeout=300)
 
         eng = get_engine()
-        base_batched = eng.stats["batched_evals"] if eng else 0
+        base_batched = eng.stats["batched_evals"]
         jobs = [_service_job(count) for _ in range(n_jobs)]
         want = n_jobs * count
         t0 = time.time()
@@ -743,12 +737,11 @@ def bench_scan_spread(n_nodes=10000, n_jobs=60, count=100, workers=48):
             s.register_job(j)
         placed = _wait_allocs(s.store, jobs, want, timeout=600)
         dt = time.time() - t0
-        batched = (eng.stats["batched_evals"] - base_batched) if eng else 0
+        batched = eng.stats["batched_evals"] - base_batched
         log(f"scan-spread: {placed}/{want} spread-service allocs in "
             f"{dt:.1f}s ({placed/dt:.0f} allocs/s, "
             f"batched_evals={batched})")
-        if eng:
-            log(f"scan-spread engine stats: {eng.stats}")
+        log(f"scan-spread engine stats: {eng.stats}")
         _log_plan_submit("scan_spread")
         _require_complete("scan_spread", placed, want)
         return placed / dt
@@ -1063,43 +1056,34 @@ def main():
                     f"{name}: plan.submit p99 {p99} ms > "
                     f"cap {p99_cap_ms} ms")
         # fused-path leg (r15): the smoke spine must have run every bulk
-        # wave group as ONE device dispatch (NOMAD_TPU_FUSE default),
-        # and the fused kernel must be registered with the recompile
-        # budget and warm before the gate (its cache populated by
-        # warmup, not the measured window).  The sharded twin is only
-        # checkable on a multi-device host.
+        # wave group as ONE device dispatch, and the donating bulk
+        # kernel must be registered with the recompile budget and warm
+        # before the gate (its cache populated by warmup, not the
+        # measured window).  The sharded twin is only checkable on a
+        # multi-device host.
         fused_violations = []
         snap = _ENGINE_SNAP.get("smoke", {})
         groups = snap.get("bulk_groups", 0)
         parts = snap.get("bulk_parts", 0)
-        if os.environ.get("NOMAD_TPU_FUSE", "1") != "0":
-            if groups <= 0:
-                fused_violations.append(
-                    "no bulk wave groups dispatched (fused path unused)")
-            elif parts != groups:
-                fused_violations.append(
-                    f"fused path inactive: {parts} device dispatches for "
-                    f"{groups} wave groups (expected 1 per wave)")
+        if groups <= 0:
+            fused_violations.append(
+                "no bulk wave groups dispatched (fused path unused)")
+        elif parts != groups:
+            fused_violations.append(
+                f"fused path inactive: {parts} device dispatches for "
+                f"{groups} wave groups (expected 1 per wave)")
         from nomad_tpu.analysis import recompile as _recompile
         kernel_sizes = _recompile.cache_sizes()
-        # with donation on (default) the warmed unsharded kernel is the
-        # donate_argnums variant; with it off, the plain one.  Either
-        # satisfies the "bulk kernel warm" requirement — on multi-device
-        # hosts the 2-D sharded kernel carries the waves instead, so the
-        # unsharded check accepts whichever variant warmup compiled.
-        if os.environ.get("NOMAD_TPU_DONATE", "1") != "0":
-            want_kernels = [("place.bulk_batch_donate", "place.bulk_batch")]
-        else:
-            want_kernels = [("place.bulk_batch",)]
+        want_kernels = ["place.bulk_batch_donate"]
         if device_info()["device_count"] > 1:
-            want_kernels.append(("sharded.bulk",))
-        for alts in want_kernels:
-            if all(kernel_sizes.get(k) is None for k in alts):
+            want_kernels.append("sharded.bulk")
+        for k in want_kernels:
+            if kernel_sizes.get(k) is None:
                 fused_violations.append(
-                    f"kernel {alts[0]!r} missing a recompile.register entry")
-            elif all((kernel_sizes.get(k) or 0) < 1 for k in alts):
+                    f"kernel {k!r} missing a recompile.register entry")
+            elif kernel_sizes[k] < 1:
                 fused_violations.append(
-                    f"kernel {alts[0]!r} registered but never warmed "
+                    f"kernel {k!r} registered but never warmed "
                     f"(cache empty after the run)")
         # tracing leg: disabled guards must be free, sampled run must
         # export a well-formed Perfetto file (r12)
@@ -1117,8 +1101,7 @@ def main():
             "serving_plane": serving,
             "fused": {"bulk_groups": groups, "bulk_parts": parts,
                       "kernels": {k: kernel_sizes.get(k)
-                                  for alts in want_kernels
-                                  for k in alts},
+                                  for k in want_kernels},
                       "violations": fused_violations},
             "tracing": trace_checks,
         }), flush=True)

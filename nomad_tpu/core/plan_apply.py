@@ -256,7 +256,7 @@ class PlanApplier:
                 # overlay here so a failed commit never leaks phantom
                 # usage (plans that reached _post_commit released theirs
                 # already; complete_many is idempotent regardless)
-                if eng is not None and pending.plan.engine_tickets:
+                if pending.plan.engine_tickets:
                     eng.complete_many(pending.plan.engine_tickets)
                 pending.future.set_exception(e)
         finally:
@@ -633,9 +633,7 @@ class PlanApplier:
         overlay count it makes concurrent kernels see phantom usage."""
         if plan.engine_tickets:
             from nomad_tpu.parallel.engine import get_engine
-            eng = get_engine()
-            if eng is not None:
-                eng.complete_many(plan.engine_tickets)
+            get_engine().complete_many(plan.engine_tickets)
         if applied is None:
             return
         result.alloc_index = index

@@ -368,10 +368,8 @@ class Server:
         # (the engine overlay's double-count window); once the overlay
         # drains, give blocked evals another chance
         from nomad_tpu.parallel.engine import get_engine
-        _eng = get_engine()
-        if _eng is not None:
-            _eng.on_drain = lambda: self.blocked_evals.unblock_all(
-                self.store.latest_index)
+        get_engine().on_drain = lambda: self.blocked_evals.unblock_all(
+            self.store.latest_index)
         if self.membership is not None:
             self.membership.start()
         if self.wan_pool is not None:

@@ -46,33 +46,16 @@ class Knob:
 # arguments so the static checker can read it without importing us.
 KNOBS: Dict[str, Knob] = {
     # -- parallel engine / serving mesh ------------------------------
-    "NOMAD_TPU_ENGINE": Knob(
-        "1", "bool",
-        "`0` bypasses the batching engine (direct kernel calls)"),
-    "NOMAD_TPU_SHARD": Knob(
-        "1", "bool",
-        "`0` disables the multi-device serving mesh entirely"),
     "NOMAD_TPU_SHARD_MIN": Knob(
         "128", "int",
         "minimum padded node rows before dispatches route over the "
-        "`('node_shard','wave')` mesh (`shard_min_nodes`)"),
+        "`('node_shard','wave')` mesh (`shard_min_nodes`); above the "
+        "cluster's size it keeps a multi-device host on one device"),
     "NOMAD_TPU_WAVE_SHARDS": Knob(
         "", "int",
         "wave extent of the 2-D serving mesh (`wave_mesh_shape`); "
         "empty = auto, a non-divisor of the device count falls back "
         "to 1"),
-    "NOMAD_TPU_FUSE": Knob(
-        "1", "bool",
-        "`0` splits bulk waves into per-group device dispatches "
-        "instead of one fused part per wave"),
-    "NOMAD_TPU_DONATE": Knob(
-        "1", "bool",
-        "`0` disables donated usage-basis carries (kernel falls back "
-        "to functional updates + host re-upload)"),
-    "NOMAD_TPU_OVERLAP": Knob(
-        "1", "bool",
-        "`0` disables upload/compute overlap (each bulk dispatch "
-        "drains before the next uploads; requires donation)"),
     "NOMAD_TPU_BULK_BYTES": Knob(
         "268435456", "int",
         "byte budget for one bulk dispatch's stacked per-eval "

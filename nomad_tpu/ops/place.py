@@ -528,7 +528,7 @@ def _bulk_loop(capacity, used0, feasible, affinity, has_affinity, desired,
                spread_algorithm: bool, max_waves: int,
                fill_grid: int = _FILL_GRID):
     """The wavefront placement loop shared by the single-eval
-    (`place_bulk_jit`) and batched (`place_bulk_batch_jit`) kernels.
+    (`place_bulk_jit`) and batched (`_place_bulk_batch`) kernels.
     Places `count` IDENTICAL slots of one task group (spreads inactive)
     in O(waves) device steps instead of O(count) scan steps — the
     C2M-scale path (SURVEY.md §7 "slot-batching smarter than a 100K-step
@@ -649,6 +649,9 @@ def place_bulk_jit(capacity: jax.Array,    # f32[N, R]
                    max_waves: int = 65536,
                    fill_grid: int = _FILL_GRID):
     """Single-eval wavefront placement (see `_bulk_loop` for semantics).
+    Reference; not on the serving path: the engine dispatches
+    `place_bulk_batch_donate_jit`, and the tests hold that to sequential
+    calls of this.
 
     Returns one packed f32[N, R+3] leaf (one D2H round trip): cols [0,R)
     used, col R assign, col R+1 scores, col R+2 scalars in rows 0-2.
@@ -748,8 +751,7 @@ def _place_bulk_batch(capacity: jax.Array,      # f32[N, R]
                       sparse_out: bool = False,
                       spread_algorithm: bool = False,
                       max_waves: int = 65536,
-                      fill_grid: int = _FILL_GRID,
-                      exact_out: bool = False):
+                      fill_grid: int = _FILL_GRID):
     """Chained batch of E wavefront bulk evals in ONE dispatch: a
     `lax.scan` over the eval axis carries the usage matrix, each step
     runs `_bulk_loop` (the O(waves) wavefront placement), so eval e+1
@@ -763,34 +765,33 @@ def _place_bulk_batch(capacity: jax.Array,      # f32[N, R]
     engine's in-flight overlay).
 
     used0 is a DEVICE-RESIDENT basis (engine ships dirty rows only).
-    Returns (packed, used_final device-resident).  packed per eval:
-    dense [2N+4] (assign[N], scores[N], placed/n_eval/n_exh/waves) or,
-    with sparse_out, [3*SPARSE_CAP+4] (rows, counts, row_scores,
-    scalars) — for count <= SPARSE_CAP only.
+    Returns (packed, used_final, used_exact), the last two
+    device-resident.  packed per eval: dense [2N+4] (assign[N],
+    scores[N], placed/n_eval/n_exh/waves) or, with sparse_out,
+    [3*SPARSE_CAP+4] (rows, counts, row_scores, scalars) — for count <=
+    SPARSE_CAP only.
 
-    Jitted twice below: `place_bulk_batch_jit` (plain) and
-    `place_bulk_batch_donate_jit` (donate_argnums=(1,): the `used0`
-    carry buffer is donated and the caller adopts the carry output as
-    the new resident basis via world.loan_basis/adopt_basis — the carry
-    never re-uploads).
+    Jitted below as `place_bulk_batch_donate_jit` (donate_argnums=(1,):
+    the `used0` carry buffer is donated and the caller adopts
+    `used_exact` as the new resident basis via
+    world.loan_basis/adopt_basis — the carry never re-uploads).
 
-    `exact_out` (the donation path) additionally threads an EXACT
-    rank-1 reconstruction of the basis — `used0 + sum_e assign_e *
-    demand_e`, one fused multiply-add per eval, the same op sequence as
-    world.apply_rank1's host/device scatters — and returns (packed,
-    used_final, used_exact).  The scan's own carry accumulates per-wave
-    partial placements (multiple f32 adds per node), which drifts
-    bitwise from the rank-1 form; scoring must keep the drifted chain
-    carry (placement parity with the non-donated path), while the
-    ADOPTED basis must stay bitwise in lockstep with the host snapshot
-    that apply_rank1_host maintains — hence two carries."""
+    `used_exact` is an EXACT rank-1 reconstruction of the basis —
+    `used0 + sum_e assign_e * demand_e`, one fused multiply-add per
+    eval, the same op sequence as world.apply_rank1_host's scatters.
+    The scan's own carry accumulates per-wave partial placements
+    (multiple f32 adds per node), which drifts bitwise from the rank-1
+    form; scoring must keep the drifted chain carry (placement parity
+    with sequential `place_bulk_jit` calls), while the ADOPTED basis
+    must stay bitwise in lockstep with the host snapshot that
+    apply_rank1_host maintains — hence two carries."""
     N, R = capacity.shape
     E = heavy.shape[0]
     hstack = heavy
     light = dyn.reshape(E, -1)
 
     def eval_step(carry, hl):
-        used, exact = carry if exact_out else (carry, None)
+        used, exact = carry
         h, l = hl
         feasible = h[:N] > 0.5
         affinity = h[N:2 * N]
@@ -837,25 +838,18 @@ def _place_bulk_batch(capacity: jax.Array,      # f32[N, R]
                 scores_o[:SPARSE_CAP], scalars])
         else:
             out = jnp.concatenate([as_f(assign), scores, scalars])
-        new_used = used_f - delta_mat
-        if exact_out:
-            return (new_used, exact + as_f(assign)[:, None] * demand), out
-        return new_used, out
+        return (used_f - delta_mat,
+                exact + as_f(assign)[:, None] * demand), out
 
-    carry0 = (used0, used0) if exact_out else used0
-    carry_f, packed = jax.lax.scan(eval_step, carry0, (hstack, light))
-    if exact_out:
-        used_final, used_exact = carry_f
-        return packed, used_final, used_exact
-    return packed, carry_f
+    (used_final, used_exact), packed = jax.lax.scan(
+        eval_step, (used0, used0), (hstack, light))
+    return packed, used_final, used_exact
 
 
-_BULK_BATCH_STATICS = ("D", "sparse_out", "spread_algorithm",
-                       "max_waves", "fill_grid", "exact_out")
-place_bulk_batch_jit = jax.jit(
-    _place_bulk_batch, static_argnames=_BULK_BATCH_STATICS)
 place_bulk_batch_donate_jit = jax.jit(
-    _place_bulk_batch, static_argnames=_BULK_BATCH_STATICS,
+    _place_bulk_batch,
+    static_argnames=("D", "sparse_out", "spread_algorithm", "max_waves",
+                     "fill_grid"),
     donate_argnums=(1,))
 
 # Loan/adopt protocol for every donate_argnums site in this module
@@ -871,7 +865,7 @@ _DONATE_PROTOCOL = {
 
 def unpack_bulk_batch(packed: np.ndarray, n_rows: int,
                       sparse: bool = False):
-    """Host inverse of place_bulk_batch_jit's per-eval rows (both
+    """Host inverse of _place_bulk_batch's per-eval rows (both
     formats; sparse rows densify host-side — numpy, no transfer):
     returns (assign i32[E, N], scores f32[E, N], placed i32[E],
     n_eval i32[E], n_exh i32[E], waves i32[E]).  Dense scores default
@@ -914,6 +908,9 @@ def unpack_bulk(packed: np.ndarray):
 
 def place_eval(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceResult:
     """Convenience host wrapper returning numpy-backed results.
+    Reference; not on the serving path: a lone eval is an E=1
+    `place_batch_packed_jit` dispatch of the engine, and the tests hold
+    that to this.
 
     All outputs come back in ONE single-leaf D2H transfer (the packed
     output array); the f32[N, R] `used` matrix stays device-resident (no
@@ -934,5 +931,4 @@ recompile.register("place.eval_packed", place_eval_packed_jit)
 recompile.register("place.eval", place_eval_jit)
 recompile.register("place.batch_packed", place_batch_packed_jit)
 recompile.register("place.bulk", place_bulk_jit)
-recompile.register("place.bulk_batch", place_bulk_batch_jit)
 recompile.register("place.bulk_batch_donate", place_bulk_batch_donate_jit)

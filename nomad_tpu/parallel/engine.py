@@ -53,7 +53,6 @@ from nomad_tpu.ops.place import (
     pack_light,
     place_batch_packed_jit,
     place_bulk_batch_donate_jit,
-    place_bulk_batch_jit,
     unpack_bulk_batch,
     unpack_outputs,
 )
@@ -264,7 +263,6 @@ class _PendingBulk:
     world: object                   # DeviceWorld the dispatch scored on
     deltas_per: List
     mapping: object                 # sharded lane mapping or None
-    donated: bool
 
 
 class PlacementEngine:
@@ -311,8 +309,8 @@ class PlacementEngine:
         # axis kept chained for single-device-identical placements.
         # Sharding is the DEFAULT on multi-device meshes: the floor only
         # excludes toy worlds where per-wave collective latency exceeds
-        # the scoring work (>=16 rows/shard on an 8-device mesh).
-        # NOMAD_TPU_SHARD=0 disables; NOMAD_TPU_SHARD_MIN tunes.
+        # the scoring work (>=16 rows/shard on an 8-device mesh);
+        # NOMAD_TPU_SHARD_MIN moves it.
         if shard_min_nodes is None:
             shard_min_nodes = knobs.get_int("NOMAD_TPU_SHARD_MIN")
         self.shard_min_nodes = shard_min_nodes
@@ -320,25 +318,13 @@ class PlacementEngine:
         # so one dispatch's stacked tensors stay under this byte budget
         # (100K-node worlds at the 512-eval bucket would be ~1 GB)
         self.bulk_bytes_budget = knobs.get_int("NOMAD_TPU_BULK_BYTES")
-        # fused wave dispatch (NOMAD_TPU_FUSE=0 restores the 3-way
-        # sparse/delta/dense format split): one device call per bulk
-        # wave — the format split paid ~1.5-2 dispatch+D2H round trips
-        # per wave on mixed serving traffic for transfer savings that
-        # stopped mattering once the heavy blocks went device-resident
-        self.fuse = knobs.get_bool("NOMAD_TPU_FUSE")
-        # donated-carry bulk dispatch (NOMAD_TPU_DONATE=0 restores the
-        # copy-on-dispatch carry): the usage-basis buffer is donated to
-        # the kernel and its carry output adopted as the new resident
-        # basis (world.loan_basis/adopt_basis) — the put_basis re-upload
-        # per wave (BENCH_r05: 0.37 s) drops to zero bytes
-        self.donate = knobs.get_bool("NOMAD_TPU_DONATE")
-        # upload/compute overlap (NOMAD_TPU_OVERLAP=0 disables): hold
-        # ONE bulk dispatch in flight and prep + dispatch the next part
-        # against the adopted carry while the device computes — requires
-        # donation (the carry is what makes the in-flight placements
-        # visible to the chained dispatch without a resolve barrier)
-        self.overlap = self.donate and \
-            knobs.get_bool("NOMAD_TPU_OVERLAP")
+        # a bulk dispatch donates the usage-basis buffer to the kernel
+        # and adopts its carry output as the new resident basis
+        # (world.loan_basis/adopt_basis): no basis re-upload per wave.
+        # ONE bulk dispatch is held in flight while the next part is
+        # prepared and dispatched against the adopted carry, which is
+        # what makes the in-flight placements visible to the chained
+        # dispatch without a resolve barrier
         self._pending: Optional[_PendingBulk] = None
         self._serving_mesh = None
         self._mesh_checked = False
@@ -371,8 +357,8 @@ class PlacementEngine:
                       "bulk_groups": 0, "bulk_parts": 0,
                       # donated-carry / 2-D-mesh health: donated_carries
                       # counts dispatches whose basis was donated (the
-                      # steady state holds this == bulk_parts when
-                      # NOMAD_TPU_DONATE=1), wave_lanes the peak count
+                      # steady state holds this == bulk_parts),
+                      # wave_lanes the peak count
                       # of concurrently-scoring mesh lanes, lane_evals /
                       # lane_slots the laned occupancy (evals shipped vs
                       # W x E slots compiled), overlap_chained the bulk
@@ -463,8 +449,9 @@ class PlacementEngine:
                    spread_algorithm: bool = False, wave_key: str = ""):
         """Wavefront bulk placement of `count` identical slots, batched
         with concurrent bulk evals into one chained device dispatch
-        (ops.place.place_bulk_batch_jit).  Blocks; returns (assign i32[N],
-        placed, nodes_evaluated, nodes_exhausted, scores f32[N], ticket).
+        (ops.place.place_bulk_batch_donate_jit).  Blocks; returns
+        (assign i32[N], placed, nodes_evaluated, nodes_exhausted,
+        scores f32[N], ticket).
         Callers derive usage from `assign` (sparse) — the engine returns
         no usage matrix.  The caller MUST `complete(ticket)` once the
         plan is submitted (ticket may be None if nothing placed)."""
@@ -922,8 +909,7 @@ class PlacementEngine:
             expected_shape = ((N, cm.capacity.shape[1]),
                               (N, cm.used.shape[1]))
             parts = 0
-            for part in self._split_bulk(reqs, sharded=mesh is not None,
-                                         lanes=lanes):
+            for part in self._split_bulk(reqs, lanes=lanes):
                 parts += 1
                 # upload/compute overlap: the previous bulk dispatch may
                 # still be computing.  Chaining behind it is sound ONLY
@@ -934,32 +920,28 @@ class PlacementEngine:
                 # snapshot would erase those placements, and chaos
                 # injection may force exactly that, so both bail to a
                 # drain-first barrier.
-                chained = (self.overlap and self.donate
-                           and chaos.active is None
+                chained = (chaos.active is None
                            and self._pending is not None
                            and self._pending.world is world
-                           and self._pending.donated
                            and world.shape == expected_shape)
                 if self._pending is not None and not chained:
                     self._drain_pending()
                 if mesh is not None:
-                    out, _w, dper, mapping, donated = \
+                    out, _w, dper, mapping = \
                         self._dispatch_bulk_group_sharded(
                             part, mesh, world=world,
                             force_scatter=chained)
                 else:
-                    out, _w, dper, donated = self._dispatch_bulk_group(
+                    out, _w, dper = self._dispatch_bulk_group(
                         part, world=world, force_scatter=chained)
                     mapping = None
                 if chained:
                     self.stats["overlap_chained"] += 1
                 prev, self._pending = self._pending, _PendingBulk(
                     reqs=part, out=out, world=world, deltas_per=dper,
-                    mapping=mapping, donated=donated)
+                    mapping=mapping)
                 if prev is not None:
                     self._drain_record(prev)
-                if not (self.overlap and donated):
-                    self._drain_pending()
             self.stats["bulk_groups"] += 1
             self.stats["bulk_parts"] += parts
             self.stats["bulk_evals"] += len(reqs)
@@ -1029,10 +1011,9 @@ class PlacementEngine:
                               ctx=ctx) as got:
                 fetched = jax.device_get(p.out)
         except Exception as e:                  # noqa: BLE001
-            if p.donated and p.world is not None:
-                # the adopted carry is suspect (failed dispatch): the
-                # next update() re-uploads from the host snapshot
-                p.world.invalidate_basis()
+            # the adopted carry is suspect (failed dispatch): the
+            # next update() re-uploads from the host snapshot
+            p.world.invalidate_basis()
             for r in p.reqs:
                 if not r.future.done():
                     r.future.set_exception(e)
@@ -1041,7 +1022,7 @@ class PlacementEngine:
         try:
             with tracing.span("engine.resolve", ctx=ctx) as sp:
                 self._resolve_bulk(p.reqs, fetched, p.world, p.deltas_per,
-                                   mapping=p.mapping, donated=p.donated)
+                                   mapping=p.mapping)
         except Exception as e:                  # noqa: BLE001
             for r in p.reqs:
                 if not r.future.done():
@@ -1101,8 +1082,6 @@ class PlacementEngine:
     def _mesh_for(self, N: int):
         """The ('node_shard','wave') serving mesh when sharding applies
         to this node axis, else None."""
-        if not knobs.get_bool("NOMAD_TPU_SHARD"):
-            return None
         if not self._mesh_checked:
             import jax
 
@@ -1223,7 +1202,7 @@ class PlacementEngine:
         return bins, mapping
 
     def _dispatch_bulk_group_sharded(self, reqs: List[_BulkRequest],
-                                     mesh, world=None, donate=None,
+                                     mesh, world=None,
                                      force_scatter: bool = False):
         from nomad_tpu.parallel.sharded import (
             NODE_AXIS_NAME,
@@ -1233,7 +1212,6 @@ class PlacementEngine:
 
         cm = reqs[0].cm
         N = reqs[0].feasible.shape[0]
-        donate = self.donate if donate is None else donate
         W = mesh.shape.get(WAVE_AXIS_NAME, 1)
         capacity = cm.capacity[:N]
         basis = self._basis_for(cm)[:N]
@@ -1322,38 +1300,31 @@ class PlacementEngine:
                 key=("bulkstack", N, W, E, digs, meta))
             # device-resident world: one full upload per cluster epoch, then
             # dirty-row scatters; steady state ships zero basis bytes because
-            # _resolve_bulk pre-applied the placements (apply_rank1, or the
-            # donated carry + apply_rank1_host)
+            # the adopted carry holds the placements on device and
+            # _resolve_bulk applied them to the host snapshot
+            # (apply_rank1_host)
             world = world if world is not None else self._world(cm, N, mesh)
-            cap_dev, basis_dev = world.update(capacity, basis,
-                                              force_scatter=force_scatter)
-            if donate:
-                loaned = world.loan_basis()
-                if loaned is not None:
-                    basis_dev = loaned
-                else:
-                    donate = False
+            cap_dev, _ = world.update(capacity, basis,
+                                      force_scatter=force_scatter)
+            basis_dev = world.loan_basis()
             from nomad_tpu.ops.place import fill_grid_for
             out = place_bulk_batch_sharded(
                 mesh, cap_dev, basis_dev,
                 feas, aff, hasa, des, pen, coll, dem, cnt,
                 drows, dvals, spread_algorithm=reqs[0].spread_algorithm,
-                fill_grid=fill_grid_for(max(r.count for r in reqs)),
-                donate=donate)
+                fill_grid=fill_grid_for(max(r.count for r in reqs)))
             assign, scores, placed, n_eval, n_exh, waves, used_tot = out
-            if donate:
-                world.adopt_basis(used_tot)
-                self.stats["donated_carries"] += 1
+            world.adopt_basis(used_tot)
+            self.stats["donated_carries"] += 1
         self.stats["put_s"] += sp.seconds
         self.stats["sharded_evals"] = (
             self.stats.get("sharded_evals", 0) + len(reqs))
         return (assign, scores, placed, n_eval, n_exh, waves), \
-            world, deltas_per, mapping, donate
+            world, deltas_per, mapping
 
     # ---------------------------------------------------------- bulk path
 
-    def _split_bulk(self, reqs: List[_BulkRequest], sharded: bool = False,
-                    lanes: int = 1):
+    def _split_bulk(self, reqs: List[_BulkRequest], lanes: int = 1):
         # oversized-delta requests always go alone so their deltas can
         # fold into the part's private basis copy (fixed delta bucket,
         # no compile variant forked)
@@ -1362,35 +1333,17 @@ class PlacementEngine:
         for r in overflow:
             yield [r]
         chunk = self._bulk_chunk(reqs[0].feasible.shape[0], lanes)
-        if self.fuse or sharded:
-            # FUSED wave dispatch: the whole wave is ONE device call
-            # (modulo the byte-budget chunk).  The dispatch picks the
-            # output format (sparse iff every count fits) and delta
-            # bucket (D=0 iff nothing ships deltas) for the mixed part —
-            # all combinations are warmed compile variants.  The old
-            # 3-way sparse/delta/dense split bought smaller D2H rows at
-            # the price of ~1.5-2 dispatch round trips per wave; with
-            # device-resident heavy blocks the extra round trips
-            # dominate.  The sharded kernel has ONE (dense, fixed-D)
-            # format, so it always dispatched fused.
-            for i in range(0, len(rest), chunk):
-                yield rest[i:i + chunk]
-            return
-        # NOMAD_TPU_FUSE=0: the pre-fusion format split — small-count
-        # (sparse-output) and large-count (dense) requests split so a
-        # part compiles one output format and small evals never pay the
-        # dense [2N] D2H row; delta-free requests (the fresh-placement
-        # common case) split from delta-carrying ones (their D=0 light
-        # block is ~50x smaller, which mattered on slow links)
-        fits_s0, fits_s, fits_d = [], [], []
-        for r in rest:
-            if r.count <= SPARSE_CAP:
-                (fits_s0 if not r.deltas else fits_s).append(r)
-            else:
-                fits_d.append(r)
-        for fits in (fits_s0, fits_s, fits_d):
-            for i in range(0, len(fits), chunk):
-                yield fits[i:i + chunk]
+        # the rest of the wave is ONE device call (modulo the
+        # byte-budget chunk).  The dispatch picks the output format
+        # (sparse iff every count fits) and delta bucket (D=0 iff
+        # nothing ships deltas) for the mixed part — all combinations
+        # are warmed compile variants; the sharded kernel has ONE
+        # (dense, fixed-D) format.  Splitting a wave by format bought
+        # smaller D2H rows at the price of ~1.5-2 dispatch round trips
+        # per wave; with device-resident heavy blocks the round trips
+        # dominate.
+        for i in range(0, len(rest), chunk):
+            yield rest[i:i + chunk]
 
     def _bulk_chunk(self, N: int, lanes: int = 1) -> int:
         """Largest bulk E bucket whose stacked per-eval heavy blocks
@@ -1404,12 +1357,11 @@ class PlacementEngine:
         return min(self.max_batch, allowed[-1] if allowed else 1)
 
     def _dispatch_bulk_group(self, reqs: List[_BulkRequest], world=None,
-                             donate=None, force_scatter: bool = False):
+                             force_scatter: bool = False):
         import jax
 
         cm = reqs[0].cm
         N = reqs[0].feasible.shape[0]
-        donate = self.donate if donate is None else donate
         E = next(b for b in self.BULK_E_BUCKETS if b >= len(reqs))
         # rows are stable across matrix re-bucketing (growth only pads
         # the node axis), so the enqueue-time world is the prefix slice
@@ -1420,8 +1372,7 @@ class PlacementEngine:
             # singleton overflow part (_split_bulk): fold into the
             # private basis copy instead of forking a compile variant
             deltas_per = [_fold_overflow(basis, reqs[0].deltas)]
-        # D=0 when nothing ships deltas (the fresh-placement common
-        # case; _split_bulk separates delta-free parts)
+        # D=0 when nothing ships deltas (the fresh-placement common case)
         D = _DELTA_BUCKET if any(deltas_per) else 0
 
         ctx = self._ctx_of(reqs)
@@ -1437,19 +1388,13 @@ class PlacementEngine:
         self.stats["stack_s"] += sp.seconds
         with tracing.span("engine.put", ctx=ctx) as sp:
             # device-resident world: epoch upload once, dirty-row scatters
-            # after; steady state ships zero basis bytes (apply_rank1 in
-            # _resolve_bulk keeps device and host snapshot in lockstep; on
-            # the donated path the kernel's exact carry IS the new resident
-            # basis and only the host snapshot catches up)
+            # after; steady state ships zero basis bytes (the kernel's
+            # exact carry IS the new resident basis and only the host
+            # snapshot catches up, apply_rank1_host in _resolve_bulk)
             world = world if world is not None else self._world(cm, N)
-            cap_dev, used_dev = world.update(capacity, basis,
-                                             force_scatter=force_scatter)
-            if donate:
-                loaned = world.loan_basis()
-                if loaned is not None:
-                    used_dev = loaned
-                else:
-                    donate = False
+            cap_dev, _ = world.update(capacity, basis,
+                                      force_scatter=force_scatter)
+            used_dev = world.loan_basis()
             digs = tuple(bulk_heavy_digest(r.feasible, r.affinity, r.penalty,
                                            r.coll0) for r in reqs)
             heavy = [self._cache.bulk_heavy(r, dig)
@@ -1467,29 +1412,21 @@ class PlacementEngine:
             sparse = all(r.count <= SPARSE_CAP for r in reqs)
             from nomad_tpu.ops.place import fill_grid_for
             fill_grid = fill_grid_for(max(r.count for r in reqs))
-            if donate:
-                # exact_out: the adopted basis is the rank-1 reconstruction
-                # (bitwise what apply_rank1 would have scattered), while the
-                # scan's own carry keeps chain-scoring parity
-                packed, _used_final, used_exact = place_bulk_batch_donate_jit(
-                    cap_dev, used_dev, hstack, dyn_dev, D,
-                    sparse_out=sparse,
-                    spread_algorithm=reqs[0].spread_algorithm,
-                    fill_grid=fill_grid, exact_out=True)
-                world.adopt_basis(used_exact)
-                self.stats["donated_carries"] += 1
-            else:
-                packed, _used_final = place_bulk_batch_jit(
-                    cap_dev, used_dev, hstack, dyn_dev, D,
-                    sparse_out=sparse,
-                    spread_algorithm=reqs[0].spread_algorithm,
-                    fill_grid=fill_grid)
+            # the adopted basis is the rank-1 reconstruction (bitwise
+            # what the host snapshot's scatters produce), while the
+            # scan's own carry keeps chain-scoring parity
+            packed, _used_final, used_exact = place_bulk_batch_donate_jit(
+                cap_dev, used_dev, hstack, dyn_dev, D,
+                sparse_out=sparse,
+                spread_algorithm=reqs[0].spread_algorithm,
+                fill_grid=fill_grid)
+            world.adopt_basis(used_exact)
+            self.stats["donated_carries"] += 1
         self.stats["put_s"] += sp.seconds
-        return packed, world, deltas_per, donate
+        return packed, world, deltas_per
 
     def _resolve_bulk(self, reqs: List[_BulkRequest], packed: np.ndarray,
-                      world, deltas_per, mapping=None,
-                      donated: bool = False) -> None:
+                      world, deltas_per, mapping=None) -> None:
         """Mirror the kernel's chained usage host-side so every caller
         gets the exact used matrix its placements produced: each eval
         sees basis + prior evals' PLACEMENTS + its own private deltas;
@@ -1499,13 +1436,12 @@ class PlacementEngine:
         empty for an overflow singleton whose deltas were folded into
         the shipped basis (re-applying r.deltas would double-count).
         `world` is the DeviceWorld this dispatch scored against: each
-        eval's placements scatter onto it (host snapshot + device in
-        lockstep) so the NEXT dispatch's update() diff is already clean
+        eval's placements scatter onto its host snapshot
+        (apply_rank1_host: the adopted carry already holds them on
+        device) so the NEXT dispatch's update() diff is already clean
         and ships zero basis rows in steady state.  `mapping` (laned
         sharded dispatches) gives each request's (lane, slot) in the
-        [W, E, ...] outputs; `donated` routes the world hand-off through
-        apply_rank1_host — the adopted carry already holds the
-        placements on device, only the host snapshot catches up."""
+        [W, E, ...] outputs."""
         import jax
 
         N = reqs[0].feasible.shape[0]
@@ -1541,12 +1477,8 @@ class PlacementEngine:
             ticket = self.register_external_sparse(
                 r.cm, rows, assign[i][rows], r.demand) \
                 if rows.size else None
-            if ticket is not None and world is not None:
-                if donated:
-                    world.apply_rank1_host(rows, assign[i][rows],
-                                           r.demand)
-                else:
-                    world.apply_rank1(rows, assign[i][rows], r.demand)
+            if ticket is not None:
+                world.apply_rank1_host(rows, assign[i][rows], r.demand)
             r.future.set_result(
                 (assign[i], int(placed[i]), int(n_eval[i]),
                  int(n_exh[i]), scores[i], ticket))
@@ -1630,11 +1562,9 @@ _engine: Optional[PlacementEngine] = None
 _engine_lock = threading.Lock()
 
 
-def get_engine() -> Optional[PlacementEngine]:
-    """Process-wide engine; disable with NOMAD_TPU_ENGINE=0."""
+def get_engine() -> PlacementEngine:
+    """The process-wide engine, made on first use."""
     global _engine
-    if not knobs.get_bool("NOMAD_TPU_ENGINE"):
-        return None
     with _engine_lock:
         if _engine is None:
             _engine = PlacementEngine()

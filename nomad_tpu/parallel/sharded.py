@@ -479,8 +479,7 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
                              delta_rows, delta_vals,
                              spread_algorithm: bool = False,
                              max_waves: int = 65536,
-                             fill_grid: int = 64,
-                             donate: bool = False):
+                             fill_grid: int = 64):
     """Laned chained bulk wavefront batch (engine place_bulk) over the
     2-D ('node_shard','wave') mesh — the C2M-scale multi-chip path.
 
@@ -497,11 +496,11 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
     `used_final = u0 + psum_over_wave(lane_delta)`.
 
     Returns (assign i32[W, E, N], scores f32[W, E, N], placed/n_eval/
-    n_exh/waves i32[W, E] each, used_final node-sharded).  With
-    `donate=True` the `used0` buffer is donated to the kernel — the
-    caller hands over its resident basis and adopts `used_final` in its
-    place (world.loan_basis / adopt_basis), so the steady state ships
-    zero basis bytes."""
+    n_exh/waves i32[W, E] each, used_final node-sharded).  The `used0`
+    buffer is donated to the kernel — the caller hands over its
+    resident basis and adopts `used_final` in its place
+    (world.loan_basis / adopt_basis), so the steady state ships zero
+    basis bytes."""
     from nomad_tpu.ops.place import (
         _bulk_scores,
         bulk_run_lengths as _bulk_run_lengths,
@@ -524,8 +523,8 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
             feasible, affinity, has_aff, desired, penalty, coll0, \
                 demand, count, dr, dv = ev
             # deltas are scoped to THIS eval (backed out of the carry
-            # below), matching place_bulk_batch_jit: uncommitted stops of
-            # one eval never leak into another's scoring
+            # below), matching ops.place._place_bulk_batch: uncommitted
+            # stops of one eval never leak into another's scoring
             used = _apply_deltas_local(used_in, dr, dv, shard_offset)
             delta_local = used - used_in
             desired_f = desired.astype(jnp.float32)
@@ -639,7 +638,7 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
                 P(W, None, NS), P(W, None, NS), P(W, None, None),
                 P(W, None), P(W, None, None), P(W, None, None, None))
     key = ("bulk", mesh_key(mesh), spread_algorithm, max_waves,
-           fill_grid, donate)
+           fill_grid)
     fn = _SERVING_FN_CACHE.get(key)
     if fn is None:
         out_specs = (P(W, None, NS), P(W, None, NS), P(W, None),
@@ -649,8 +648,7 @@ def place_bulk_batch_sharded(mesh: Mesh, capacity, used0,
         # donate_argnums=(1,): used0 and used_final share shape [N, R]
         # and sharding P('node_shard', None), so XLA aliases the carry
         # in place of a fresh allocation + a host re-upload next wave
-        fn = jax.jit(mapped, donate_argnums=(1,)) if donate \
-            else jax.jit(mapped)
+        fn = jax.jit(mapped, donate_argnums=(1,))
         recompile.register("sharded.bulk", fn)
         _SERVING_FN_CACHE[key] = fn
     args = [capacity, used0, feasible, affinity, has_affinity, desired,

@@ -25,7 +25,10 @@ dirty-row scatters:
   update lands in the host snapshot (native scatter) and in the device
   basis (jitted scatter) in one call, so a resolved bulk eval's
   placements are already device-resident before the next dispatch
-  diffs — the steady-state diff is empty and ships zero rows.
+  diffs — the steady-state diff is empty and ships zero rows.  The
+  engine's bulk path does not call it: the donated carry it adopts
+  already holds the placements, and `apply_rank1_host` (below) brings
+  only the host snapshot along.
 
 On a multi-device mesh the buffers live sharded over the serving
 mesh's 'node_shard' axis (`NamedSharding(mesh, P('node_shard', None))`,
@@ -294,12 +297,17 @@ class DeviceWorld:
         scatter or update can alias a donated-away array); the caller
         MUST follow the dispatch with `adopt_basis(used_final)` — or, on
         a failed dispatch, leave the world invalidated so the next
-        update() re-uploads from the host snapshot.  Returns None if no
-        basis is resident."""
+        update() re-uploads from the host snapshot.  update() always
+        leaves a basis resident and the donating kernels are the only
+        ones warmed, so a loan with none resident is a broken
+        invariant, not a case to fall back from."""
         with self.lock:
             dev, self._basis_dev = self._basis_dev, None
-            if dev is not None:
-                self.stats["basis_loans"] += 1
+            if dev is None:
+                raise RuntimeError(
+                    "DeviceWorld.loan_basis: no resident basis "
+                    "(loan must follow update())")
+            self.stats["basis_loans"] += 1
             return dev
 
     def adopt_basis(self, dev) -> None:

@@ -351,8 +351,7 @@ def device_fit(cm: ClusterMatrix, requests, extra_used=None) -> DeviceFit:
         if gid not in free:
             f = cm.device_caps[gid].astype(np.int64) \
                 - cm.device_used.get(gid, 0)
-            inflight = eng.device_overlay(cm, gid) if eng is not None \
-                else None
+            inflight = eng.device_overlay(cm, gid)
             if inflight is not None and inflight.shape[0] == n:
                 f = f - inflight
             if extra_used and gid in extra_used:

@@ -18,8 +18,10 @@ import numpy as np
 
 from nomad_tpu import tracing
 from nomad_tpu.encode.matrixizer import comparable_vec
+from nomad_tpu.parallel.engine import get_engine
 
 from nomad_tpu.scheduler import factory
+from nomad_tpu.scheduler.preemption import Preemptor
 from nomad_tpu.scheduler.placement import (
     PortClaims,
     build_allocation,
@@ -224,11 +226,9 @@ class GenericScheduler:
                     self._stack.release()
                     self._stack = None
                 if self._ext_tickets:
-                    from nomad_tpu.parallel.engine import get_engine
                     eng = get_engine()
-                    if eng is not None:
-                        for t in self._ext_tickets:
-                            eng.complete(t)
+                    for t in self._ext_tickets:
+                        eng.complete(t)
                     self._ext_tickets = []
         adjust_queued_allocations(self.plan_result, self.queued_allocs)
 
@@ -320,12 +320,11 @@ class GenericScheduler:
         instance picks race-free across workers (basis read, placement,
         id assignment and overlay registration are atomic), mirroring how
         bulk evals serialize.  Everything else runs concurrently."""
-        from nomad_tpu.parallel.engine import get_engine
         eng = get_engine()
         device_eval = any(t.resources.devices
                           for tg in self.job.task_groups
                           for t in tg.tasks)
-        if eng is None or not device_eval:
+        if not device_eval:
             self._compute_placements_inner(places, stops, all_allocs)
             return
         t_ask = _time.perf_counter()
@@ -376,10 +375,9 @@ class GenericScheduler:
         # overlay (placements of concurrently scheduled, not-yet-committed
         # plans) minus what this plan stops; `deltas` mirrors every
         # adjustment sparsely for the batching engine
-        from nomad_tpu.parallel.engine import get_engine
-        _eng = get_engine()
-        used = _eng.basis_for(cm) if _eng is not None \
-            and cm.used.shape[0] == cm.capacity.shape[0] else cm.used.copy()
+        eng = get_engine()
+        used = eng.basis_for(cm) \
+            if cm.used.shape[0] == cm.capacity.shape[0] else cm.used.copy()
         deltas: List[Tuple[int, np.ndarray]] = []
         freed_ports: Dict[int, Set[int]] = {}
         stopped_ids: Set[str] = set()
@@ -431,7 +429,7 @@ class GenericScheduler:
         # --- bulk path: groups of identical slots with no
         # placement-coupled constraints (spreads / distinct_*) place via
         # the wavefront kernel in O(waves) steps instead of an
-        # O(slots) scan — the C2M-scale path (ops.place.place_bulk_jit).
+        # O(slots) scan — the C2M-scale path (ops.place._place_bulk_batch).
         # The eval submits EVERY eligible group before waiting
         # (place_bulk_begin), so a many-small-group job (the C2M-1M
         # shape: 10 groups x count 10) is ONE chained device dispatch
@@ -444,8 +442,6 @@ class GenericScheduler:
             by_group.setdefault(tg_index[pr.task_group], []).append(pr)
         bulk_results: List[Tuple[int, List[PlacementRequest], object]] = []
         scan_requests: List[PlacementRequest] = []
-        from nomad_tpu.parallel.engine import get_engine
-        eng = get_engine()
         pending_bulk: List[Tuple[int, List[PlacementRequest], object]] = []
         for gi, prs in by_group.items():
             g = groups[gi]
@@ -461,17 +457,9 @@ class GenericScheduler:
             if not eligible:
                 scan_requests.extend(prs)
                 continue
-            if eng is not None:
-                fut = self._place_bulk_begin(eng, cm, g, prs,
-                                             allocs_by_tg, penalty_nodes,
-                                             deltas, stack)
-                pending_bulk.append((gi, prs, fut))
-                continue
-            bulk, ticket = self._place_bulk(cm, job, g, prs, allocs_by_tg,
-                                            penalty_nodes, deltas, stack)
-            bulk_results.append((gi, prs, bulk))
-            if ticket is not None:
-                self._ext_tickets.append(ticket)
+            fut = self._place_bulk_begin(eng, cm, g, prs, allocs_by_tg,
+                                         penalty_nodes, deltas, stack)
+            pending_bulk.append((gi, prs, fut))
         if pending_bulk:
             with tracing.span("sched.wait_engine", wait=True):
                 for gi, prs, fut in pending_bulk:
@@ -606,7 +594,6 @@ class GenericScheduler:
                 if got is None and preemption_on:
                     nonlocal preemptor
                     if preemptor is None:
-                        from nomad_tpu.scheduler.preemption import Preemptor
                         preemptor = Preemptor(self.state, job.priority,
                                               seed=self.eval.id)
                     extra = preemptor.preempt_for_device(
@@ -644,9 +631,9 @@ class GenericScheduler:
             preempted = preempted if preempted is not None else []
             devices = assign_devices(pr, tg, node, row, preempted) \
                 if node is not None else {}
-            if groups[gi].place_cap is not None and _eng is not None:
-                _eng.stats["device_placements"] += 1
-                _eng.stats["device_fallbacks"] += devices is None
+            if groups[gi].place_cap is not None:
+                eng.stats["device_placements"] += 1
+                eng.stats["device_fallbacks"] += devices is None
             if devices is None:
                 # the dense kernel scores cpu/mem, not per-node device
                 # instances; earlier placements of THIS eval may have
@@ -717,7 +704,6 @@ class GenericScheduler:
             if not preemption_on:
                 return False
             if preemptor is None:
-                from nomad_tpu.scheduler.preemption import Preemptor
                 preemptor = Preemptor(self.state, job.priority,
                                       seed=self.eval.id)
             gi = tg_index[pr.task_group]
@@ -887,54 +873,6 @@ class GenericScheduler:
             # are independent waves and may score concurrently on the
             # 2-D mesh's wave columns
             wave_key=self.job.namespace)
-
-    def _place_bulk(self, cm, job, g, prs, allocs_by_tg, penalty_nodes,
-                    deltas, stack):
-        """Wavefront placement of len(prs) identical slots of group `g`.
-        With the engine present this coalesces with concurrent bulk evals
-        into ONE chained device dispatch (engine.place_bulk ->
-        ops.place.place_bulk_batch_jit) — conflict-free by chaining, no
-        serializing gate needed.  Returns ((assign i32[N], placed,
-        nodes_evaluated, nodes_exhausted, scores f32[N],
-        used_after f32[N, R]), overlay ticket or None)."""
-        import jax
-
-        from nomad_tpu.ops.place import place_bulk_jit, unpack_bulk
-        from nomad_tpu.parallel.engine import get_engine
-
-        eng = get_engine()
-        N = cm.n_rows
-        penalty, coll0 = self._bulk_node_fields(cm, g, allocs_by_tg,
-                                                penalty_nodes)
-
-        if eng is not None:
-            assign, placed, n_eval, n_exh, scores, ticket = \
-                eng.place_bulk(
-                    cm, feasible=g.feasible,
-                    affinity=g.affinity.astype(np.float32),
-                    has_affinity=bool(g.has_affinity),
-                    desired=max(g.tg.count, 1), penalty=penalty,
-                    coll0=coll0, demand=g.demand.astype(np.float32),
-                    count=len(prs), deltas=deltas,
-                    spread_algorithm=stack.spread_algorithm,
-                    wave_key=job.namespace)
-            return ((assign, placed, n_eval, n_exh, scores), ticket)
-
-        base = cm.used.copy()
-        for row, vec in deltas:       # this eval's stops/preplacements
-            if row < N:
-                base[row] += vec
-        packed = place_bulk_jit(
-            np.ascontiguousarray(cm.capacity),
-            np.ascontiguousarray(base.astype(np.float32)),
-            g.feasible, g.affinity.astype(np.float32),
-            bool(g.has_affinity), np.int32(max(g.tg.count, 1)), penalty,
-            coll0, g.demand.astype(np.float32), np.int32(len(prs)),
-            spread_algorithm=stack.spread_algorithm)
-        assign, placed, n_eval, n_exh, scores, _waves, _used_f = \
-            unpack_bulk(jax.device_get(packed))
-        return ((assign, int(placed), int(n_eval), int(n_exh),
-                 np.asarray(scores)), None)
 
     def _fail_placement(self, pr: PlacementRequest, metric: AllocMetric,
                         reason: str) -> None:

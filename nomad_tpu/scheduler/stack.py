@@ -3,7 +3,7 @@
 Dense analog of scheduler/stack.go (GenericStack/SystemStack): where the
 reference wires an iterator chain per eval and pulls nodes through it, we
 compile the job's constraints/affinities/spreads once into padded tensors
-and hand them to ops.place.place_eval.  Job-level and task-group-level
+and hand them to the placement engine.  Job-level and task-group-level
 checkers are merged exactly like the reference's FeasibilityWrapper
 (feasible.go:1010-1174): job constraints apply to every group, task
 constraints/drivers fold into their group.
@@ -26,7 +26,8 @@ from nomad_tpu.encode.matrixizer import (
     RES_NET,
     pad_to_bucket,
 )
-from nomad_tpu.ops.place import PlaceInputs, PlaceResult, place_eval
+from nomad_tpu.ops.place import PlaceInputs, PlaceResult
+from nomad_tpu.parallel.engine import get_engine
 from nomad_tpu.scheduler import feasible as fz
 from nomad_tpu.structs.job import Constraint, Job, Operand, Spread, TaskGroup
 from nomad_tpu.structs.config import (
@@ -375,22 +376,13 @@ class DenseStack:
         Sets `self.last_ticket`: the caller must hand it back to
         `engine.complete()` once the resulting plan is submitted (the
         generic scheduler does), releasing the in-flight usage overlay."""
-        from nomad_tpu.parallel.engine import get_engine
-        eng = get_engine()
-        if eng is not None:
-            result, self.last_ticket = eng.place(
-                self.cm, inputs, deltas,
-                spread_algorithm=self.spread_algorithm)
-            return result
-        self.last_ticket = None
-        return place_eval(inputs, spread_algorithm=self.spread_algorithm)
+        result, self.last_ticket = get_engine().place(
+            self.cm, inputs, deltas, spread_algorithm=self.spread_algorithm)
+        return result
 
     def release(self) -> None:
         """Release the in-flight usage contribution of the last place()."""
         ticket = getattr(self, "last_ticket", None)
         if ticket is not None:
-            from nomad_tpu.parallel.engine import get_engine
-            eng = get_engine()
-            if eng is not None:
-                eng.complete(ticket)
+            get_engine().complete(ticket)
             self.last_ticket = None

@@ -127,7 +127,7 @@ def test_generic_scheduler_uses_bulk_path():
 
 def test_engine_bulk_batch_matches_serial():
     """Concurrent engine.place_bulk calls coalesce into one chained
-    dispatch (place_bulk_batch_jit) and must equal sequential bulk
+    dispatch (place_bulk_batch_donate_jit) and must equal sequential bulk
     processing: each eval's placements land on usage that includes the
     previous eval's, and no node ends over capacity."""
     import threading

@@ -267,3 +267,101 @@ def test_engine_single_device_world_resident_across_evals():
         fresh.stop()
     np.testing.assert_array_equal(a2, a2_fresh)
     np.testing.assert_array_equal(cm.used, committed)
+
+
+def test_get_engine_has_no_off_switch(monkeypatch):
+    """NOMAD_TPU_ENGINE=0 once made get_engine() return None and every
+    caller carried a second scheduler behind `is None`; the variable now
+    means nothing and the engine is always there."""
+    from nomad_tpu.parallel.engine import get_engine
+
+    monkeypatch.setenv("NOMAD_TPU_ENGINE", "0")
+    assert isinstance(get_engine(), PlacementEngine)
+
+
+# ------------------------------------------- the families serving reaches
+
+_SERVED = {"place.batch_packed", "place.bulk_batch_donate"}
+
+
+@pytest.fixture(scope="module")
+def warmed():
+    """One engine on one device, warmed for one scan class and one bulk
+    class; yields (engine, matrix, bulk fields, the kernel families whose
+    jit caches grew in the warm-up)."""
+    from nomad_tpu import knobs
+    from nomad_tpu.analysis import recompile
+
+    cm = _world(64)
+    N = cm.n_rows
+    j = mock.batch_job()
+    g = DenseStack(cm).compile_group(j, j.task_groups[0])
+    bulk = dict(feasible=g.feasible, affinity=g.affinity.astype(np.float32),
+                has_affinity=bool(g.has_affinity), desired=8,
+                penalty=np.zeros(N, bool), coll0=np.zeros(N, np.int32),
+                demand=g.demand.astype(np.float32), count=8)
+    # a byte budget of eight evals' heavy blocks: the warm grid is the
+    # bulk buckets 1 and 8, not all six
+    with knobs.override("NOMAD_TPU_BULK_BYTES", 8 * 4 * N * 4):
+        eng = PlacementEngine(shard_min_nodes=1 << 30)
+    try:
+        assert eng._mesh_for(N) is None and eng._bulk_chunk(N) == 8
+        budget = recompile.Budget()
+        eng.warmup(cm, inputs=_request(cm, count=5).inputs, bulk=bulk)
+        grew = {k for k in budget.report()["recompiled"]
+                if k.startswith(("place.", "sharded."))}
+        yield eng, cm, bulk, grew
+    finally:
+        eng.stop()
+
+
+def _bulk_reqs(cm, bulk, counts, deltas=()):
+    from nomad_tpu.parallel.engine import _BulkRequest
+    return [_BulkRequest(cm=cm, deltas=list(deltas), spread_algorithm=False,
+                         future=Future(), wave_key=f"ns-{i}",
+                         **dict(bulk, count=c, desired=c))
+            for i, c in enumerate(counts)]
+
+
+def _some_deltas(n):
+    vec = np.array([1.0, 1.0, 0.0, 0.0], np.float32)
+    return [(i % 64, vec) for i in range(n)]
+
+
+@pytest.mark.parametrize("form", [
+    "lone_scan", "scan_group", "bulk_sparse", "bulk_dense", "bulk_deltas",
+    "bulk_delta_overflow"])
+def test_serving_reaches_two_kernel_families(warmed, form):
+    """Every form of traffic one device serves goes through
+    `place.batch_packed` or `place.bulk_batch_donate`, at a shape the
+    warm-up compiled: no other placement family grows in the warm-up, and
+    no registered kernel compiles once it is over."""
+    from nomad_tpu.analysis import recompile
+    from nomad_tpu.ops.place import SPARSE_CAP
+    from nomad_tpu.parallel.engine import _DELTA_BUCKET
+
+    eng, cm, bulk, grew_warming = warmed
+    assert grew_warming == _SERVED
+    before = dict(eng.stats)
+    budget = recompile.Budget()
+    reqs = {
+        "lone_scan": lambda: [_request(cm, count=3)],
+        "scan_group": lambda: [_request(cm, count=3) for _ in range(3)],
+        "bulk_sparse": lambda: _bulk_reqs(cm, bulk, [7, 5, 3]),
+        "bulk_dense": lambda: _bulk_reqs(cm, bulk, [SPARSE_CAP + 2, 4]),
+        "bulk_deltas": lambda: _bulk_reqs(cm, bulk, [7, 5],
+                                          _some_deltas(2)),
+        "bulk_delta_overflow": lambda: _bulk_reqs(
+            cm, bulk, [6], _some_deltas(_DELTA_BUCKET + 1)),
+    }[form]()
+    eng._dispatch(reqs)
+    eng._drain_pending()
+    tickets = [r.future.result(timeout=60)[-1] for r in reqs]
+    want = {"lone_scan": {"single_evals": 1},
+            "scan_group": {"batched_evals": 3}}.get(
+        form, {"bulk_groups": 1, "bulk_parts": 1, "donated_carries": 1,
+               "bulk_evals": len(reqs)})
+    for t in tickets:
+        eng.complete(t)
+    assert {k: eng.stats[k] - before[k] for k in want} == want
+    assert budget.violations() == []

@@ -53,8 +53,8 @@ def test_call_site_default_beats_registry_default():
 def test_empty_string_counts_as_unset():
     env = {"NOMAD_TPU_WAVE_SHARDS": ""}
     assert knobs.get_int("NOMAD_TPU_WAVE_SHARDS", env=env) is None
-    assert knobs.get_bool("NOMAD_TPU_FUSE",
-                          env={"NOMAD_TPU_FUSE": ""}) is True
+    assert knobs.get_bool("NOMAD_TPU_JAX_CACHE",
+                          env={"NOMAD_TPU_JAX_CACHE": ""}) is True
 
 
 @pytest.mark.parametrize("raw,want", [
@@ -72,6 +72,18 @@ def test_unregistered_knob_is_a_hard_error():
     with pytest.raises(KeyError):
         with knobs.override("NOMAD_TPU_NO_SUCH_KNOB", "1"):
             pass
+
+
+@pytest.mark.parametrize("name", [
+    "NOMAD_TPU_ENGINE", "NOMAD_TPU_SHARD", "NOMAD_TPU_FUSE",
+    "NOMAD_TPU_DONATE", "NOMAD_TPU_OVERLAP"])
+def test_removed_path_switch_is_refused(name):
+    """The five on/off switches whose off side was deleted (PR 32) must
+    not come back as silent no-ops: reading one is the hard error of any
+    unregistered knob."""
+    assert name not in knobs.KNOBS
+    with pytest.raises(KeyError):
+        knobs.get_bool(name)
 
 
 def test_override_scopes_and_restores():

@@ -205,13 +205,27 @@ def test_e2e_spine_sharded_matches_single_device():
     with placements identical (same node rows) to the single-device
     engine.  One scheduler worker keeps eval processing order
     deterministic so the runs are comparable."""
-    import os
+    import contextlib
 
+    from nomad_tpu import knobs
     from nomad_tpu.core.server import Server, ServerConfig
+    from nomad_tpu.parallel import engine as engine_mod
+
+    @contextlib.contextmanager
+    def one_device_engine():
+        # the process-wide engine reads its mesh floor when it is made:
+        # the single-device side gets an engine of its own, made under a
+        # floor no cluster reaches
+        with knobs.override("NOMAD_TPU_SHARD_MIN", 1 << 30):
+            prev, engine_mod._engine = engine_mod._engine, None
+            try:
+                yield
+            finally:
+                engine_mod.get_engine().stop()
+                engine_mod._engine = prev
 
     def run_spine(shard: bool):
-        os.environ["NOMAD_TPU_SHARD"] = "1" if shard else "0"
-        try:
+        with contextlib.nullcontext() if shard else one_device_engine():
             s = Server(ServerConfig(num_schedulers=1,
                                     heartbeat_ttl=3600.0,
                                     gc_interval=3600.0))
@@ -246,11 +260,11 @@ def test_e2e_spine_sharded_matches_single_device():
                         counts[row] = counts.get(row, 0) + 1
                     rows[j.id] = counts
                 assert placed == want, placed
+                assert (engine_mod.get_engine()._mesh_for(1024)
+                        is not None) == shard
                 return rows
             finally:
                 s.stop()
-        finally:
-            os.environ.pop("NOMAD_TPU_SHARD", None)
 
     sharded = run_spine(shard=True)
     single = run_spine(shard=False)

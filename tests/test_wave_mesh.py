@@ -166,7 +166,6 @@ def test_donated_carry_invalidates_loaned_buffer():
     eng = PlacementEngine()            # N=64 < shard_min -> mesh off
     try:
         assert eng._mesh_for(N) is None
-        assert eng.donate                     # NOMAD_TPU_DONATE default
         world = eng._world(cm, N, None)
         loaned = []
         orig = world.loan_basis
@@ -207,38 +206,6 @@ def test_donated_carry_invalidates_loaned_buffer():
         eng.stop()
 
 
-def test_donation_disabled_fallback():
-    """NOMAD_TPU_DONATE=0 path: the plain (non-donating) kernel places
-    identically and never loans the basis."""
-    cm = _world_cm(64, seed=5)
-    N = cm.n_rows
-    bg = _group_fields(cm, 5)
-
-    def run(donate):
-        eng = PlacementEngine()
-        eng.donate = donate
-        eng.overlap = eng.overlap and donate
-        try:
-            _a, p, *_rest, t = eng.place_bulk(
-                cm, feasible=bg.feasible, affinity=bg.affinity,
-                has_affinity=bg.has_affinity, desired=5,
-                penalty=np.zeros(N, bool), coll0=np.zeros(N, np.int32),
-                demand=bg.demand, count=5)
-            stats = dict(eng.stats)
-            wstats = eng.world_stats()
-            eng.complete(t)
-            return np.asarray(_a).copy(), p, stats, wstats
-        finally:
-            eng.stop()
-
-    a1, p1, s1, w1 = run(donate=True)
-    a2, p2, s2, w2 = run(donate=False)
-    assert p1 == p2 == 5
-    np.testing.assert_array_equal(a1, a2)
-    assert s1["donated_carries"] == 1 and s2["donated_carries"] == 0
-    assert w2["basis_loans"] == 0 and w2["basis_adopts"] == 0
-
-
 # ------------------------------------------------ upload/compute overlap
 
 @pytest.mark.parametrize("shard_min", [8, 1 << 30],
@@ -254,14 +221,16 @@ def test_overlap_chained_matches_drained(shard_min):
 
     def run(overlap):
         eng = PlacementEngine(shard_min_nodes=shard_min)
-        eng.overlap = eng.overlap and overlap
         try:
             parts = [[_bulk_req(cm, bg, 7, f"ns-{j}-{i}") for j in range(2)]
                      for i in range(3)]
             # direct dispatch: each part goes out while the previous is
-            # still pending, deterministically exercising the chain
+            # still pending, deterministically exercising the chain;
+            # the reference drains after every part
             for part in parts:
                 eng._dispatch(part)
+                if not overlap:
+                    eng._drain_pending()
             eng._drain_pending()
             res = _results([r for part in parts for r in part])
             stats = dict(eng.stats)
@@ -286,8 +255,8 @@ def test_chained_upload_opens_before_inflight_device_get_closes():
     spans: with a part in flight, the next part's `engine.put` (stack +
     dirty-row update + dispatch) opens and closes before the in-flight
     part's `engine.device_get` does, so the host prep rides under the
-    device's work.  Drained (overlap off), every put is followed by its
-    own device_get."""
+    device's work.  Drained after every part, every put is followed by
+    its own device_get."""
     from nomad_tpu import tracing
 
     cm = _world_cm(64, seed=2)
@@ -297,7 +266,6 @@ def test_chained_upload_opens_before_inflight_device_get_closes():
         tracer = tracing.Tracer(sample_rate=1.0, seed=9)
         prev = tracing.install(tracer)
         eng = PlacementEngine(shard_min_nodes=1 << 30)
-        eng.overlap = eng.overlap and overlap
         try:
             ctx = tracer.new_context()
             parts = [[_bulk_req(cm, bg, 7, f"ns-{j}-{i}") for j in range(2)]
@@ -306,6 +274,8 @@ def test_chained_upload_opens_before_inflight_device_get_closes():
                 for r in part:
                     r.ctx = ctx
                 eng._dispatch(part)
+                if not overlap:
+                    eng._drain_pending()
             eng._drain_pending()
             for *_r, t in _results([r for part in parts for r in part]):
                 eng.complete(t)
