@@ -33,8 +33,9 @@ and reports:
   - restore-only        restored but never persisted (and not derived)
   - record-key drift    snapshot record keys vs the keys restore reads
   - inline rebuilds     restore mutating a derived index outside its
-                        builder (resetting to an empty container is the
-                        one legal inline form)
+                        builder (resetting to an empty container, by
+                        assignment or by `.clear()`, is the one legal
+                        inline form)
   - builder drift       a declared builder missing, unreachable from
                         restore, or incremental yet unreachable from
                         apply (rows rebuilt through a constructor the
@@ -272,6 +273,8 @@ def run(corpus: Corpus) -> List[Finding]:
                     continue
                 if m.kind == "assign" and is_empty_ctor(m.node.value):
                     continue
+                if m.kind == "method" and m.node.func.attr == "clear":
+                    continue        # the same reset, of a table kept in place
                 report(fi.sf, m.line,
                        f"derived index `{m.attr}` rebuilt inline in the "
                        f"restore path; route rows through "

@@ -239,8 +239,10 @@ class Worker:
         # for this eval (crash-after-commit nack, lease expiry, failover)
         # at an index past the eval's own, and scheduling from an older
         # snapshot would double-place the job
-        snap = server.store.snapshot_min_index(
-            max(ev.modify_index, ev.snapshot_index, self._wait_index))
+        with tracing.span("worker.snapshot_wait", wait=True,
+                          node=server.name):
+            snap = server.store.snapshot_min_index(
+                max(ev.modify_index, ev.snapshot_index, self._wait_index))
         if snap is None:
             self._nack(ev.id, token)
             return
@@ -318,7 +320,9 @@ class Worker:
         self.server.blocked_evals.block(ev)
 
     def refresh_snapshot(self, min_index: int = 0):
-        snap = self.server.store.snapshot_min_index(min_index)
+        with tracing.span("worker.snapshot_wait", wait=True,
+                          node=self.server.name):
+            snap = self.server.store.snapshot_min_index(min_index)
         self._snapshot = snap
         return snap
 

@@ -449,22 +449,32 @@ class NomadFSM:
         data = pickle.loads(blob)
         s = self.store
         with s._lock:
-            s._nodes = {n.id: n for n in data["nodes"]}
-            s._jobs = dict(data["jobs"])
+            # the seven versioned tables are emptied and refilled in
+            # place: snapshots taken before the restore keep what they hold
+            s._nodes.clear()
+            for n in data["nodes"]:
+                s._nodes[n.id] = n
+            s._jobs.clear()
+            for k, j in data["jobs"].items():
+                s._jobs[k] = j
             s._job_versions = defaultdict(list)
             for k, v in data["job_versions"].items():
                 s._job_versions[k] = list(v)
-            s._evals = {e.id: e for e in data["evals"]}
-            s._allocs = {}
-            s._allocs_by_job = defaultdict(set)
-            s._allocs_by_node = defaultdict(set)
+            s._evals.clear()
+            for e in data["evals"]:
+                s._evals[e.id] = e
+            s._allocs.clear()
+            s._allocs_by_job.clear()
+            s._allocs_by_node.clear()
             s._allocs_by_eval = defaultdict(set)
             s._evals_by_job = defaultdict(set)
             # derived indexes go through the store's builders — the same
             # row constructors the apply path uses (_SNAPSHOT_DERIVED)
             for e in data["evals"]:
                 s._index_eval_locked(e)
-            s._deployments = {d.id: d for d in data["deployments"]}
+            s._deployments.clear()
+            for d in data["deployments"]:
+                s._deployments[d.id] = d
             s._job_summaries = dict(data["job_summaries"])
             s.scheduler_config = data["scheduler_config"]
             from nomad_tpu.structs.namespace import Namespace

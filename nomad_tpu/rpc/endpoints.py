@@ -291,7 +291,7 @@ class Endpoints:
         # workers never see the dry-run state
         snap = _copy.copy(server.store.snapshot())
         planner = _DryRunPlanner(server.store)
-        snap.jobs = dict(snap.jobs)
+        snap.jobs = dict(snap.jobs.items())
         snap.jobs[(job.namespace, job.id)] = job
         ev = Evaluation(
             namespace=job.namespace, priority=job.priority, type=job.type,

@@ -5,9 +5,11 @@ SnapshotMinIndex:217, UpsertPlanResults:337) and the table schemata in
 nomad/state/schema.go:116-1107.  Differences by design:
 
 - go-memdb's immutable radix trees give O(1) snapshots; here objects are
-  treated as immutable-once-inserted (writers always insert copies) and a
-  snapshot shallow-copies the table dicts, memoized per index so concurrent
-  scheduler workers share one snapshot until the next write.
+  treated as immutable-once-inserted (writers always insert copies) and
+  the seven tables a snapshot hands out are versioned (state/table.py):
+  each is a dict with a shadow whose pieces a snapshot shares and a later
+  write copies.  Memoized per index, so concurrent scheduler workers
+  share one snapshot until the next write.
 - The dense ClusterMatrix mirror is maintained inline on every node/alloc
   write — the TPU analog of memdb watchsets feeding blocking queries.
 """
@@ -18,8 +20,10 @@ import time as _time
 from collections import defaultdict
 from typing import Callable, Dict, Iterable, List, Optional, Set, Tuple
 
+from nomad_tpu import tracing
 from nomad_tpu.analysis import race
 from nomad_tpu.encode.matrixizer import ClusterMatrix
+from nomad_tpu.state.table import FANOUT, IndexTable, Table, TableView
 from nomad_tpu.structs import (
     Allocation,
     AllocClientStatus,
@@ -67,19 +71,21 @@ class JobSummary:
 
 
 class StateSnapshot:
-    """A consistent read-only view at one index."""
+    """A consistent read-only view at one index: a read point on the
+    store's versioned tables, not a copy of them."""
 
     @requires_lock("_lock")
     def __init__(self, store: "StateStore"):
-        # caller (StateStore.snapshot) holds store._lock while we copy
+        # caller (StateStore.snapshot) holds store._lock: a view is cut
+        # between writes, never inside one
         self.index = store.latest_index
-        self.nodes: Dict[str, Node] = dict(store._nodes)
-        self.jobs: Dict[Tuple[str, str], Job] = dict(store._jobs)
-        self.evals: Dict[str, Evaluation] = dict(store._evals)
-        self.allocs: Dict[str, Allocation] = dict(store._allocs)
-        self.deployments: Dict[str, Deployment] = dict(store._deployments)
-        self._allocs_by_job = {k: set(v) for k, v in store._allocs_by_job.items()}
-        self._allocs_by_node = {k: set(v) for k, v in store._allocs_by_node.items()}
+        self.nodes: TableView = store._nodes.view()
+        self.jobs: TableView = store._jobs.view()
+        self.evals: TableView = store._evals.view()
+        self.allocs: TableView = store._allocs.view()
+        self.deployments: TableView = store._deployments.view()
+        self._allocs_by_job = store._allocs_by_job.view()
+        self._allocs_by_node = store._allocs_by_node.view()
         self.scheduler_config = store.scheduler_config
         # the matrix is shared (incremental); schedulers use it read-only
         # together with per-eval used_override deltas
@@ -102,12 +108,11 @@ class StateSnapshot:
 
     def allocs_by_job(self, namespace: str, job_id: str,
                       all_allocs: bool = True) -> List[Allocation]:
-        ids = self._allocs_by_job.get((namespace, job_id), ())
-        return [self.allocs[i] for i in ids]
+        return self.allocs.rows(
+            self._allocs_by_job.get((namespace, job_id), ()))
 
     def allocs_by_node(self, node_id: str) -> List[Allocation]:
-        ids = self._allocs_by_node.get(node_id, ())
-        return [self.allocs[i] for i in ids]
+        return self.allocs.rows(self._allocs_by_node.get(node_id, ()))
 
     def allocs_by_node_terminal(self, node_id: str, terminal: bool) -> List[Allocation]:
         return [a for a in self.allocs_by_node(node_id)
@@ -185,15 +190,22 @@ class StateStore:
         self._lock = threading.RLock()
         self._index_cv = threading.Condition(self._lock)
         self.latest_index = 0
-        self._nodes: Dict[str, Node] = {}
-        self._jobs: Dict[Tuple[str, str], Job] = {}
+        # how often the versioned tables engage: snapshots taken, and the
+        # pieces (bucket lists, buckets, id sets) a write had to copy
+        # because a snapshot shared them
+        self.stats: Dict[str, int] = {
+            "snapshots": 0, "roots_copied": 0, "buckets_copied": 0,
+            "sets_copied": 0}
+        # the seven tables a snapshot hands out
+        self._nodes = Table(FANOUT, self.stats)             # id -> Node
+        self._jobs = Table(FANOUT, self.stats)              # (ns, id) -> Job
         self._job_versions: Dict[Tuple[str, str], List[Job]] = defaultdict(list)
-        self._evals: Dict[str, Evaluation] = {}
-        self._allocs: Dict[str, Allocation] = {}
-        self._deployments: Dict[str, Deployment] = {}
+        self._evals = Table(FANOUT, self.stats)             # id -> Evaluation
+        self._allocs = Table(FANOUT, self.stats)            # id -> Allocation
+        self._deployments = Table(FANOUT, self.stats)       # id -> Deployment
         self._job_summaries: Dict[Tuple[str, str], JobSummary] = {}
-        self._allocs_by_job: Dict[Tuple[str, str], Set[str]] = defaultdict(set)
-        self._allocs_by_node: Dict[str, Set[str]] = defaultdict(set)
+        self._allocs_by_job = IndexTable(FANOUT, self.stats)  # (ns, id) -> alloc ids
+        self._allocs_by_node = IndexTable(FANOUT, self.stats)  # node id -> alloc ids
         self._allocs_by_eval: Dict[str, Set[str]] = defaultdict(set)
         # derived, never serialized: (namespace, job_id, name) -> ids of
         # non-terminal allocs holding that name (the plan-apply
@@ -262,7 +274,11 @@ class StateStore:
         """Memoized per index (reference Snapshot, state_store.go:190)."""
         with self._lock:
             if self._snapshot_cache is None:
-                self._snapshot_cache = StateSnapshot(self)
+                # the work of a snapshot alone, lock in hand: what the
+                # caller waited for (the lock, an index) is the caller's
+                with tracing.span("store.snapshot"):
+                    self._snapshot_cache = StateSnapshot(self)
+                self.stats["snapshots"] += 1
             return self._snapshot_cache
 
     def snapshot_min_index(self, index: int, timeout: float = 5.0) -> Optional[StateSnapshot]:
@@ -732,8 +748,8 @@ class StateStore:
 
     @requires_lock("_lock")
     def _index_alloc_locked(self, a: Allocation) -> None:
-        self._allocs_by_job[(a.namespace, a.job_id)].add(a.id)
-        self._allocs_by_node[a.node_id].add(a.id)
+        self._allocs_by_job.add((a.namespace, a.job_id), a.id)
+        self._allocs_by_node.add(a.node_id, a.id)
         self._allocs_by_eval[a.eval_id].add(a.id)
         if a.terminal_status():
             self._live_name_unset(a)
@@ -753,8 +769,8 @@ class StateStore:
         a = self._allocs.pop(alloc_id, None)
         if a is None:
             return
-        self._allocs_by_job[(a.namespace, a.job_id)].discard(alloc_id)
-        self._allocs_by_node[a.node_id].discard(alloc_id)
+        self._allocs_by_job.discard((a.namespace, a.job_id), alloc_id)
+        self._allocs_by_node.discard(a.node_id, alloc_id)
         self._allocs_by_eval[a.eval_id].discard(alloc_id)
         self._live_name_unset(a)
         if not a.terminal_status():

@@ -350,7 +350,7 @@ def test_store_applies_update_of_existing_alloc_despite_dup_name():
     a2 = mock.alloc_for(j, node_id=node.id, index=0)
     # force the duplicate in (simulates pre-guard history)
     store._allocs[a2.id] = a2
-    store._allocs_by_job[(a2.namespace, a2.job_id)].add(a2.id)
+    store._allocs_by_job.add((a2.namespace, a2.job_id), a2.id)
     upd = a1.copy()
     upd.deployment_id = "d-join"
     store.upsert_plan_results(3, AppliedPlanResults(
