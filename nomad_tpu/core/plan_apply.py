@@ -79,7 +79,9 @@ class PlanApplier:
         self._overlay: Dict[int, tuple] = {}
         self._overlay_seq = 0
         self.stats = {"applied": 0, "rejected_nodes": 0, "partial": 0,
-                      "pipelined": 0}
+                      "pipelined": 0,
+                      # allocations committed: placed, and evicted for them
+                      "placed": 0, "preempted": 0}
 
     # ------------------------------------------------------------- public
 
@@ -425,6 +427,10 @@ class PlanApplier:
                     list(plan.node_allocation[node_id])
             else:
                 rejected.append(node_id)
+                # an eviction goes with the placement it makes room for
+                # (evaluatePlanPlacements skips a node that does not
+                # fit, its preemptions with it, plan_apply.go:452-476)
+                result.node_preemptions.pop(node_id, None)
 
         # namespace quota admission at propose time, in the same
         # placement order the FSM will apply (node_allocation insertion
@@ -638,6 +644,8 @@ class PlanApplier:
             return
         result.alloc_index = index
         self.stats["applied"] += 1
+        self.stats["placed"] += len(applied.allocs_to_place)
+        self.stats["preempted"] += len(applied.allocs_preempted)
         if applied.allocs_preempted and self.on_preempted is not None:
             try:
                 self.on_preempted(applied.allocs_preempted)

@@ -594,8 +594,7 @@ class GenericScheduler:
                 if got is None and preemption_on:
                     nonlocal preemptor
                     if preemptor is None:
-                        preemptor = Preemptor(self.state, job.priority,
-                                              seed=self.eval.id)
+                        preemptor = Preemptor(self.state, job.priority)
                     extra = preemptor.preempt_for_device(
                         node, live, req, exclude=evicted_ids)
                     if extra:
@@ -704,21 +703,22 @@ class GenericScheduler:
             if not preemption_on:
                 return False
             if preemptor is None:
-                preemptor = Preemptor(self.state, job.priority,
-                                      seed=self.eval.id)
+                preemptor = Preemptor(self.state, job.priority)
             gi = tg_index[pr.task_group]
             cache = preempt_cache.setdefault(gi, [])
             if not cache:
                 # one find round serves a batch of failed slots (each
                 # find rebuilds the per-node candidate tensors)
-                cache.extend(preemptor.find_many(
-                    groups[gi].feasible, groups[gi].demand, used, 64,
-                    static_ports=groups[gi].static_ports,
-                    feasible_pre_ports=groups[gi].feasible_pre_ports,
-                    device_blocked=groups[gi].device_blocked))
+                with tracing.span("sched.preempt_find"):
+                    cache.extend(preemptor.find_many(
+                        groups[gi].feasible, groups[gi].demand, used, 64,
+                        static_ports=groups[gi].static_ports,
+                        feasible_pre_ports=groups[gi].feasible_pre_ports,
+                        device_blocked=groups[gi].device_blocked))
             if not cache:
                 return False
-            row, evicted = cache.pop(0)
+            found = cache.pop(0)
+            row, evicted = found.row, found.evicted
             # ports held by the evicted allocs become claimable — but only
             # commit that (and the usage adjustments) if the placement
             # actually lands, else later placements would claim ports of
@@ -728,6 +728,13 @@ class GenericScheduler:
             for a in evicted:
                 evicted_ports.update(_alloc_ports(a))
             metric = metric_for(i)
+            # the kernel found no row for this slot: what the chosen node
+            # scores is what the search ranked it by, with this evicted set
+            metric.populate_score_meta([{
+                "node_id": cm.node_ids[row],
+                "norm_score": round(found.score, 6),
+                "scores": {"binpack": round(found.binpack, 6),
+                           "preemption": round(found.preemption, 6)}}])
             if not place_on(pr, row, metric, preempted=evicted,
                             extra_freed=evicted_ports):
                 return True   # failure already recorded by place_on

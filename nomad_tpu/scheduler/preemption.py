@@ -22,10 +22,11 @@ Not yet modeled: per-job migrate max_parallel scoring penalty.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
+from nomad_tpu import tracing
 from nomad_tpu.encode.matrixizer import NUM_RESOURCE_DIMS, comparable_vec, pad_to_bucket
 from nomad_tpu.ops.preempt import (
     net_priority,
@@ -51,15 +52,23 @@ def _score_fit_np(capacity, util):
     return np.clip(20.0 - total, 0.0, 18.0).astype(np.float32)
 
 
+class Eviction(NamedTuple):
+    """One preemption assignment: the node row, the allocations that go,
+    and the score the search ranked the node by with that set gone (the
+    mean of `binpack`, the fit after the eviction, and `preemption`, the
+    logistic score of the set's net priority: rank.go's ScoreNode names)."""
+    row: int
+    evicted: List
+    score: float
+    binpack: float
+    preemption: float
+
+
 class Preemptor:
-    def __init__(self, snapshot, job_priority: int, seed: str = ""):
+    def __init__(self, snapshot, job_priority: int):
         self.snapshot = snapshot
         self.cm = snapshot.matrix
         self.job_priority = job_priority
-        # per-eval decorrelation seed (the reference's seeded node shuffle,
-        # util.go:464-486): concurrent evals must not all rank the same
-        # victims first or only one plan per round survives the applier
-        self._seed = seed
         self._built = False
         self.already_preempted: Set[str] = set()
 
@@ -142,8 +151,8 @@ class Preemptor:
              static_ports: Optional[List[int]] = None,
              feasible_pre_ports: Optional[np.ndarray] = None,
              device_blocked: Optional[np.ndarray] = None,
-             ) -> Optional[Tuple[int, List]]:
-        """-> (node row, allocs to preempt) or None.
+             ) -> Optional[Eviction]:
+        """-> the best node's Eviction, or None.
 
         `used` is the eval's current proposed usage matrix; remaining =
         capacity - used per node.  When `static_ports` is given,
@@ -151,7 +160,8 @@ class Preemptor:
         filter: port-conflicted nodes become eligible by force-evicting
         the preemptible holders of the asked ports."""
         if not self._built:
-            self._build()
+            with tracing.span("sched.preempt_build"):
+                self._build()
         cm = self.cm
         remaining = cm.capacity - used
 
@@ -197,46 +207,43 @@ class Preemptor:
         if not met.any():
             return None
 
-        # rank eligible nodes: mean of (binpack fit after preemption) and
-        # the logistic preemption score of the evicted set.  Fit for ALL
-        # nodes in one vectorized call — a per-row eager device op would
-        # cost one host<->device round trip per node
+        # what goes is what the superset filter leaves of the greedy set,
+        # and a node is ranked, and reported, by that set (the upstream
+        # scores PreemptForTaskGroup's result, which is filtered)
         rows = np.flatnonzero(met)
+        picked = self._superset_filter(picked, rows, remaining, demand, forced)
+        # mean of (binpack fit after preemption) and the logistic
+        # preemption score of the evicted set.  Fit for ALL nodes in one
+        # vectorized call — a per-row eager device op would cost one
+        # host<->device round trip per node
         freed_all = (self.cand_res * picked[:, :, None]).sum(axis=1)
         util_after = used - freed_all + demand[None, :]
         fit_all = _score_fit_np(cm.capacity, util_after) / 18.0
-        best_row, best_score = -1, -np.inf
-        row_scores = []
+        ranked = []
         for row in rows:
             evicted = [self.cand_allocs[row][i]
                        for i in np.flatnonzero(picked[row])]
             p_score = preemption_score(net_priority(
                 [a.job.priority if a.job else 50 for a in evicted]))
-            score = (float(fit_all[row]) + p_score) / 2.0
-            row_scores.append((score, int(row)))
-            if score > best_score:
-                best_score, best_row = score, int(row)
-        # every met row, best-first, for find_many: eviction sets on
-        # distinct rows are disjoint, so one kernel round can serve a
-        # whole batch of failed slots instead of one
-        row_scores.sort(reverse=True)
-        self._last_ranked = [(row, picked, forced, remaining)
-                             for _, row in row_scores]
-
-        protected = {self.cand_allocs[best_row][i].id
-                     for i in forced.get(best_row, ())}
-        evicted = [self.cand_allocs[best_row][i]
-                   for i in np.flatnonzero(picked[best_row])]
-        evicted = self._superset_filter(
-            evicted, remaining[best_row], demand, protected)
-        return best_row, evicted
+            fit = float(fit_all[row])
+            ranked.append(Eviction(int(row), evicted, (fit + p_score) / 2.0,
+                                   fit, p_score))
+        # the first of the best in row order, then every other met row
+        # best-first, for find_many: eviction sets on distinct rows are
+        # disjoint, so one kernel round can serve a whole batch of failed
+        # slots instead of one
+        best = max(ranked, key=lambda e: e.score)
+        ranked.sort(key=lambda e: (e.score, e.row), reverse=True)
+        self._last_ranked = [e for e in ranked
+                             if e is not best and e.evicted]
+        return best
 
     def find_many(self, feasible: np.ndarray, demand: np.ndarray,
                   used: np.ndarray, count: int,
                   static_ports: Optional[List[int]] = None,
                   feasible_pre_ports: Optional[np.ndarray] = None,
                   device_blocked: Optional[np.ndarray] = None,
-                  ) -> List[Tuple[int, List]]:
+                  ) -> List[Eviction]:
         """Up to `count` preemption assignments from ONE kernel round.
         Eviction sets on distinct rows are disjoint (an alloc lives on one
         node), so the round's ranked rows can serve `count` slots without
@@ -249,25 +256,7 @@ class Preemptor:
                           device_blocked=device_blocked)
         if first is None:
             return []
-        out: List[Tuple[int, List]] = [first]
-        row0 = first[0]
-        for row, picked, forced, remaining in getattr(
-                self, "_last_ranked", []):
-            if len(out) >= count:
-                break
-            if row == row0:
-                continue
-            evicted = [self.cand_allocs[row][i]
-                       for i in np.flatnonzero(picked[row])
-                       if self.cand_valid[row, i]]
-            if not evicted:
-                continue
-            protected = {self.cand_allocs[row][i].id
-                         for i in forced.get(row, ())}
-            evicted = self._superset_filter(
-                evicted, remaining[row], demand, protected)
-            out.append((row, evicted))
-        return out
+        return [first] + self._last_ranked[:count - 1]
 
     # ------------------------------------------------------------- devices
 
@@ -338,27 +327,33 @@ class Preemptor:
 
     # ------------------------------------------------------------- filter
 
-    def _superset_filter(self, picks: List, remaining: np.ndarray,
-                         ask: np.ndarray,
-                         protected: Optional[Set[str]] = None) -> List:
+    def _superset_filter(self, picked: np.ndarray, rows: np.ndarray,
+                         remaining: np.ndarray, ask: np.ndarray,
+                         forced: Dict[int, Set[int]]) -> np.ndarray:
         """Drop picks whose resources are already covered by the rest
         (reference filterSuperset: iterate largest-first, keep only while
-        the remainder no longer satisfies the ask).  Allocs in `protected`
-        (port holders) are never dropped."""
-        protected = protected or set()
-
-        def vec(a):
-            cr = a.comparable_resources()
-            return comparable_vec(cr)
-
-        picks = sorted(picks, key=lambda a: -vec(a).sum())
-        kept = list(picks)
-        for a in picks:
-            if a.id in protected:
-                continue
-            trial = [x for x in kept if x.id != a.id]
-            avail = remaining + sum((vec(x) for x in trial),
-                                    np.zeros(NUM_RESOURCE_DIMS, np.float32))
-            if np.all(avail >= ask) and trial:
-                kept = trial
-        return kept
+        the remainder no longer satisfies the ask), on every row of `rows`
+        at once.  Forced picks (port holders) are never dropped.  Filters
+        the `picked` mask in place and returns it."""
+        rows = rows[picked[rows].sum(axis=1) > 1]
+        if not len(rows):
+            return picked
+        res = self.cand_res[rows]
+        on = picked[rows]
+        keep = np.zeros_like(on)
+        for row, holders in forced.items():
+            i = int(np.searchsorted(rows, row))
+            if i < len(rows) and rows[i] == row:
+                keep[i, list(holders)] = True
+        size = np.where(on, res.sum(axis=2), -np.inf)
+        order = np.argsort(-size, axis=1, kind="stable")
+        at = np.arange(len(rows))
+        for k in order[:, :int(on.sum(axis=1).max())].T:
+            rest = on.copy()
+            rest[at, k] = False
+            room = remaining[rows] + (res * rest[:, :, None]).sum(axis=1)
+            drop = (on[at, k] & ~keep[at, k] & rest.any(axis=1)
+                    & np.all(room >= ask, axis=1))
+            on[at[drop], k[drop]] = False
+        picked[rows] = on
+        return picked

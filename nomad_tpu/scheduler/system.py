@@ -174,7 +174,7 @@ class SystemScheduler:
         found = self._preemptor.find(feas, d, used)
         if found is None:
             return None
-        _, evicted = found
+        evicted = found.evicted
         self._preemptor.invalidate({a.id for a in evicted})
         return evicted
 
