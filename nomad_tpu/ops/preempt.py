@@ -17,6 +17,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from nomad_tpu import tracing
 
 BIG = jnp.float32(3.4e38)
 
@@ -78,7 +81,8 @@ def preempt_for_task_group(
     ask: jax.Array,            # f32[R]
     max_steps: int = 16,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """-> (met bool[N], picked bool[N, A], avail_after f32[N, R])."""
+    """-> (met bool[N], picked bool[N, A], avail_after f32[N, R]).  Serves
+    nothing: the tests' oracle for `preempt_for_task_group_np`."""
     return jax.vmap(
         lambda r, p, v, rem: _node_preempt(r, p, v, rem, ask, max_steps)
     )(cand_res, cand_prio, cand_valid, remaining)
@@ -105,15 +109,16 @@ def preemption_score(net_prio: float) -> float:
 
 def preempt_for_task_group_np(cand_res, cand_prio, cand_valid, remaining,
                               ask, max_steps: int = 16):
-    """Numpy twin of preempt_for_task_group, used on the scheduler-worker
-    host path: worker threads must not issue device work concurrently
-    with the PlacementEngine's dispatcher (single-dispatch-thread
-    discipline: one thread owns every launch and fetch, so the
-    steady-state transfer guard and the donated-carry protocol have one
-    place to hold), and at N x A x steps this selection is trivial host
-    math anyway."""
-    import numpy as np
-
+    """Numpy twin of preempt_for_task_group, and the one that serves
+    (`Preemptor.find`, on a scheduler worker's thread): worker threads
+    must not issue device work concurrently with the PlacementEngine's
+    dispatcher (single-dispatch-thread discipline: one thread owns every
+    launch and fetch, so the steady-state transfer guard and the
+    donated-carry protocol have one place to hold).  Where the scan runs
+    `max_steps` passes over every row, the host runs a pass only over the
+    rows that can still pick, and none once no row can: a row that is met
+    or out of open candidates is left as it is by every later pass, so
+    the arrays returned are the scan's."""
     N, A, R = cand_res.shape
     picked = np.zeros((N, A), bool)
     needed = np.broadcast_to(ask, (N, R)).copy()
@@ -121,22 +126,25 @@ def preempt_for_task_group_np(cand_res, cand_prio, cand_valid, remaining,
     met = np.all(avail >= ask, axis=-1)
     INT_MAX = np.int32(2**31 - 1)
     BIGF = np.float32(3.4e38)
+    live = np.arange(N)
     for _ in range(max_steps):
-        open_ = cand_valid & ~picked
-        prio_masked = np.where(open_, cand_prio, INT_MAX)
-        min_prio = prio_masked.min(axis=1)                    # [N]
-        tier = open_ & (cand_prio == min_prio[:, None])
-        askp = needed[:, None, :]                             # [N,1,R]
-        coord = np.where(askp > 0.0,
-                         (askp - cand_res) / np.maximum(askp, 1e-9), 0.0)
-        dist = np.sqrt((coord * coord).sum(axis=-1))          # [N, A]
-        dist = np.where(tier, dist, BIGF)
-        pick = dist.argmin(axis=1)                            # [N]
-        can_pick = tier.any(axis=1) & ~met
-        onehot = (np.arange(A)[None, :] == pick[:, None]) & can_pick[:, None]
-        picked |= onehot
-        freed = (cand_res * onehot[:, :, None]).sum(axis=1)
-        avail += freed
-        needed -= freed
-        met |= np.all(avail >= ask, axis=-1)
+        open_ = cand_valid[live] & ~picked[live]
+        can_pick = open_.any(axis=1) & ~met[live]
+        live, open_ = live[can_pick], open_[can_pick]
+        if not len(live):
+            break
+        with tracing.span("sched.preempt_pass"):
+            res, prio = cand_res[live], cand_prio[live]
+            min_prio = np.where(open_, prio, INT_MAX).min(axis=1)
+            tier = open_ & (prio == min_prio[:, None])
+            askp = needed[live][:, None, :]                   # [n, 1, R]
+            coord = np.where(askp > 0.0,
+                             (askp - res) / np.maximum(askp, 1e-9), 0.0)
+            dist = np.sqrt((coord * coord).sum(axis=-1))      # [n, A]
+            pick = np.where(tier, dist, BIGF).argmin(axis=1)
+            picked[live, pick] = True
+            freed = res[np.arange(len(live)), pick]
+            avail[live] += freed
+            needed[live] -= freed
+            met[live] = np.all(avail[live] >= ask, axis=-1)
     return met, picked, avail

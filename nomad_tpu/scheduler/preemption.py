@@ -151,8 +151,9 @@ class Preemptor:
              static_ports: Optional[List[int]] = None,
              feasible_pre_ports: Optional[np.ndarray] = None,
              device_blocked: Optional[np.ndarray] = None,
-             ) -> Optional[Eviction]:
-        """-> the best node's Eviction, or None.
+             count: int = 1) -> Optional[Eviction]:
+        """-> the best node's Eviction, or None; the `count - 1` next
+        best are kept for find_many.
 
         `used` is the eval's current proposed usage matrix; remaining =
         capacity - used per node.  When `static_ports` is given,
@@ -162,16 +163,23 @@ class Preemptor:
         if not self._built:
             with tracing.span("sched.preempt_build"):
                 self._build()
+        with tracing.span("sched.preempt_search"):
+            return self._search(np.asarray(feasible).copy(), demand, used,
+                                static_ports, feasible_pre_ports,
+                                device_blocked, count)
+
+    def _search(self, feasible, demand, used, static_ports,
+                feasible_pre_ports, device_blocked, count):
         cm = self.cm
         remaining = cm.capacity - used
 
         forced: Dict[int, Set[int]] = {}
-        feasible = np.asarray(feasible).copy()
         if static_ports and feasible_pre_ports is not None:
             port_rows = np.flatnonzero(feasible_pre_ports & ~feasible)
             forced = self._port_forced_evictions(static_ports, port_rows)
-            for row in forced:
-                feasible[row] = True   # eligible again via eviction
+        in_forced = np.zeros(len(feasible), bool)
+        in_forced[list(forced)] = True
+        feasible |= in_forced          # eligible again via eviction
         # instance-exhausted device nodes: eligible targets — the actual
         # device evictions are chosen later by preempt_for_device inside
         # the placement (PreemptForDevice, preemption.go:472)
@@ -180,22 +188,16 @@ class Preemptor:
             dev_rows = np.asarray(device_blocked) & ~feasible
             feasible |= dev_rows
 
-        met, picked, avail_after = preempt_for_task_group_np(
-            self.cand_res, self.cand_prio, self.cand_valid,
-            remaining.astype(np.float32), demand.astype(np.float32),
-            max_steps=self.max_steps)
-        met = np.asarray(met) & feasible
+        met, picked = self._greedy(feasible, remaining.astype(np.float32),
+                                   demand.astype(np.float32))
+        met &= feasible
         # nodes that fit without eviction are not preemption targets --
         # unless a port eviction is what makes them usable
         fits_plain = np.all(remaining >= demand, axis=-1)
-        no_ports_needed = np.array(
-            [r not in forced for r in range(len(fits_plain))])
-        met &= ~(fits_plain & no_ports_needed & ~dev_rows)
+        met &= ~(fits_plain & ~in_forced & ~dev_rows)
         # port/device rows that fit resource-wise still need their evictions
-        met |= (np.array([r in forced for r in range(len(fits_plain))])
-                & fits_plain & feasible)
+        met |= in_forced & fits_plain & feasible
         met |= dev_rows & fits_plain
-        picked = np.asarray(picked).copy()
         # fold the forced port evictions into each row's pick set, and
         # re-check resource sufficiency with the combined freed set (the
         # kernel ran without knowing about the forced frees)
@@ -211,32 +213,58 @@ class Preemptor:
         # and a node is ranked, and reported, by that set (the upstream
         # scores PreemptForTaskGroup's result, which is filtered)
         rows = np.flatnonzero(met)
-        picked = self._superset_filter(picked, rows, remaining, demand, forced)
+        on = self._superset_filter(picked, rows, remaining, demand,
+                                   forced)[rows]
         # mean of (binpack fit after preemption) and the logistic
-        # preemption score of the evicted set.  Fit for ALL nodes in one
-        # vectorized call — a per-row eager device op would cost one
-        # host<->device round trip per node
-        freed_all = (self.cand_res * picked[:, :, None]).sum(axis=1)
-        util_after = used - freed_all + demand[None, :]
-        fit_all = _score_fit_np(cm.capacity, util_after) / 18.0
-        ranked = []
-        for row in rows:
-            evicted = [self.cand_allocs[row][i]
-                       for i in np.flatnonzero(picked[row])]
-            p_score = preemption_score(net_priority(
-                [a.job.priority if a.job else 50 for a in evicted]))
-            fit = float(fit_all[row])
-            ranked.append(Eviction(int(row), evicted, (fit + p_score) / 2.0,
-                                   fit, p_score))
+        # preemption score of the evicted set, for every met row at once
+        freed = (self.cand_res[rows] * on[:, :, None]).sum(axis=1)
+        fit = (_score_fit_np(cm.capacity[rows],
+                             used[rows] - freed + demand[None, :])
+               / 18.0).astype(np.float64)
+        # the logistic through the scalar arithmetic that reports it, once
+        # for each distinct (max, sum) of a set's priorities: np.exp may
+        # differ in the last place and reorder ties
+        prio = np.where(on, self.cand_prio[rows], 0).astype(np.int64)
+        _, first, which = np.unique(
+            (prio.max(axis=1) << 32) + prio.sum(axis=1),
+            return_index=True, return_inverse=True)
+        p_score = np.array([
+            preemption_score(net_priority(prio[i][on[i]].tolist()))
+            for i in first])[which]
+        score = (fit + p_score) / 2.0
+
+        def record(i):
+            row = int(rows[i])
+            return Eviction(
+                row, [self.cand_allocs[row][k] for k in np.flatnonzero(on[i])],
+                float(score[i]), float(fit[i]), float(p_score[i]))
         # the first of the best in row order, then every other met row
-        # best-first, for find_many: eviction sets on distinct rows are
-        # disjoint, so one kernel round can serve a whole batch of failed
-        # slots instead of one
-        best = max(ranked, key=lambda e: e.score)
-        ranked.sort(key=lambda e: (e.score, e.row), reverse=True)
-        self._last_ranked = [e for e in ranked
-                             if e is not best and e.evicted]
-        return best
+        # that evicts best-first, for find_many: eviction sets on distinct
+        # rows are disjoint, so one search can serve a whole batch of
+        # failed slots instead of one.  Records only for what is returned
+        best = int(np.argmax(score))
+        order = np.lexsort((rows, score))[::-1]
+        order = order[(order != best) & on.any(axis=1)[order]]
+        self._last_ranked = [record(i) for i in order[:count - 1]]
+        return record(best)
+
+    def _greedy(self, feasible, remaining, ask):
+        """The greedy passes over the rows that can answer: feasible, not
+        fitting as they are, holding a valid candidate; the candidate axis
+        cut to the widest of them (candidates fill a row from index 0, and
+        argmin's first-index tie-break survives a prefix).  Every other row
+        is left as a pass leaves it: met if it fits, nothing picked.
+        -> (met bool[N], picked bool[N, A])"""
+        met = np.all(remaining >= ask, axis=-1)
+        picked = np.zeros(self.cand_valid.shape, bool)
+        go = np.flatnonzero(feasible & ~met & self.cand_valid.any(axis=1))
+        if len(go):
+            width = int(np.flatnonzero(self.cand_valid[go].any(axis=0))[-1]) + 1
+            met[go], picked[go, :width], _ = preempt_for_task_group_np(
+                self.cand_res[go, :width], self.cand_prio[go, :width],
+                self.cand_valid[go, :width], remaining[go], ask,
+                max_steps=self.max_steps)
+        return met, picked
 
     def find_many(self, feasible: np.ndarray, demand: np.ndarray,
                   used: np.ndarray, count: int,
@@ -244,19 +272,19 @@ class Preemptor:
                   feasible_pre_ports: Optional[np.ndarray] = None,
                   device_blocked: Optional[np.ndarray] = None,
                   ) -> List[Eviction]:
-        """Up to `count` preemption assignments from ONE kernel round.
+        """Up to `count` preemption assignments from ONE search.
         Eviction sets on distinct rows are disjoint (an alloc lives on one
-        node), so the round's ranked rows can serve `count` slots without
-        paying one device round trip per slot; later rounds (triggered by
-        the caller when this batch is exhausted) see updated usage and
-        invalidated candidates."""
+        node), so the search's ranked rows can serve `count` slots without
+        paying one search per slot; later rounds (triggered by the caller
+        when this batch is exhausted) see updated usage and invalidated
+        candidates."""
         first = self.find(feasible, demand, used,
                           static_ports=static_ports,
                           feasible_pre_ports=feasible_pre_ports,
-                          device_blocked=device_blocked)
+                          device_blocked=device_blocked, count=count)
         if first is None:
             return []
-        return [first] + self._last_ranked[:count - 1]
+        return [first] + self._last_ranked
 
     # ------------------------------------------------------------- devices
 
