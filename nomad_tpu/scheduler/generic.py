@@ -730,11 +730,7 @@ class GenericScheduler:
             metric = metric_for(i)
             # the kernel found no row for this slot: what the chosen node
             # scores is what the search ranked it by, with this evicted set
-            metric.populate_score_meta([{
-                "node_id": cm.node_ids[row],
-                "norm_score": round(found.score, 6),
-                "scores": {"binpack": round(found.binpack, 6),
-                           "preemption": round(found.preemption, 6)}}])
+            metric.populate_score_meta([found.score_meta(cm.node_ids[row])])
             if not place_on(pr, row, metric, preempted=evicted,
                             extra_freed=evicted_ports):
                 return True   # failure already recorded by place_on

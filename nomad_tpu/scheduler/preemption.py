@@ -63,6 +63,23 @@ class Eviction(NamedTuple):
     binpack: float
     preemption: float
 
+    def score_meta(self, node_id: str) -> dict:
+        """What the placement that evicts this set reports for its node
+        (`AllocMetric.score_meta`): the value the search ranked by."""
+        return {"node_id": node_id, "norm_score": round(self.score, 6),
+                "scores": {"binpack": round(self.binpack, 6),
+                           "preemption": round(self.preemption, 6)}}
+
+
+def fit_score_meta(node_id: str, capacity, util) -> dict:
+    """A placement's `score_meta` entry where binpack alone speaks (the
+    system stack's one node, nothing evicted): the normalised fit of the
+    node at `util`, through the search's own arithmetic."""
+    fit = round(float(_score_fit_np(capacity[None, :], util[None, :])[0])
+                / 18.0, 6)
+    return {"node_id": node_id, "norm_score": fit,
+            "scores": {"binpack": fit}}
+
 
 class Preemptor:
     def __init__(self, snapshot, job_priority: int):

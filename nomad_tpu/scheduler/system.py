@@ -2,9 +2,16 @@
 and util.go diffSystemAllocsForNode:70).
 
 One allocation per eligible node per task group.  Feasibility is one dense
-mask over all nodes; the per-node resource check is a single vectorized
-fits_after call — no placement coupling across nodes (each node hosts its
-own instance), so no scan is needed.
+mask over all nodes (`DenseStack.compile_group`); everything after it is a
+host loop over the node axis: a node that fits takes its allocation, one
+that does not asks the preemption search for that one row (one
+`Preemptor.find` a node, on the `Preemptor` the eval builds once).  No
+placement is coupled to another across nodes (each node hosts its own
+instance), so no scan is needed and the device is not asked.  A placement
+reports what the upstream's system stack scores the one node by: its
+binpack fit, and for a preempting one the mean of the fit after the
+eviction and the evicted set's preemption score, the value the search
+ranked the row by.
 """
 from __future__ import annotations
 
@@ -13,9 +20,11 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from nomad_tpu import tracing
 from nomad_tpu.encode.matrixizer import comparable_vec
 
 from nomad_tpu.scheduler.placement import PortClaims, build_allocation
+from nomad_tpu.scheduler.preemption import Preemptor, fit_score_meta
 from nomad_tpu.scheduler.reconcile import tasks_updated
 from nomad_tpu.scheduler.stack import DenseStack
 from nomad_tpu.scheduler.util import tainted_nodes
@@ -38,6 +47,22 @@ class SystemScheduler:
 
     def process(self, ev: Evaluation) -> None:
         self.eval = ev
+        with tracing.span("sched.system_diff"):
+            todo = self._diff(ev)
+        if todo is None:
+            return
+        plan, job, groups, live, terminal_newest, used = todo
+        with tracing.span("sched.system_place"):
+            self._place_nodes(plan, job, groups, live, terminal_newest, used)
+        ev.queued_allocations = dict(self.queued_allocs)
+        if not plan.is_no_op():
+            self.planner.submit_plan(plan)
+
+    def _diff(self, ev: Evaluation):
+        """Everything before the node loop: the job, its allocations split
+        into live and newest-terminal by (node, name), the stops for
+        tainted nodes, the groups' feasibility masks.  None when the job
+        is stopped (its plan is submitted here)."""
         job = self.state.job_by_id(ev.namespace, ev.job_id)
         allocs = self.state.allocs_by_job(ev.namespace, ev.job_id)
         plan = ev.make_plan(job)
@@ -61,7 +86,7 @@ class SystemScheduler:
             if not plan.is_no_op():
                 self.planner.submit_plan(plan)
             ev.queued_allocations = {}
-            return
+            return None
 
         tainted = tainted_nodes(self.state, allocs)
 
@@ -69,8 +94,6 @@ class SystemScheduler:
                            snapshot=self.state)
         groups = [stack.compile_group(job, tg) for tg in job.task_groups]
         used = cm.used.copy()
-        ports = PortClaims(cm)
-        now = _time.time()
         self.queued_allocs = {tg.name: 0 for tg in job.task_groups}
 
         # stops: down nodes -> lost; draining -> migrate-stop
@@ -89,7 +112,14 @@ class SystemScheduler:
                 if row is not None:
                     cr = a.comparable_resources()
                     used[row] -= comparable_vec(cr)
+        return plan, job, groups, live, terminal_newest, used
 
+    def _place_nodes(self, plan, job, groups, live, terminal_newest, used):
+        """The node loop: one allocation of each group on every feasible
+        node that has none."""
+        cm = self.state.matrix
+        ports = PortClaims(cm)
+        now = _time.time()
         for gi, tg in enumerate(job.task_groups):
             g = groups[gi]
             name = alloc_name(job.id, tg.name, 0)
@@ -126,23 +156,26 @@ class SystemScheduler:
                 self._try_place(plan, job, tg, name, node_id, row, used, d,
                                 ports, now)
 
-        ev.queued_allocations = dict(self.queued_allocs)
-        if not plan.is_no_op():
-            self.planner.submit_plan(plan)
-
     def _try_place(self, plan, job, tg, name, node_id, row, used, d, ports, now):
         cm = self.state.matrix
-        preempted = []
+        found = None
         if not np.all(used[row] + d <= cm.capacity[row]):
-            preempted = self._try_preempt(plan, job, row, d, used)
-            if preempted is None:
+            found = self._try_preempt(plan, job, row, d, used)
+            if found is None:
                 m = self.failed_tg_allocs.setdefault(tg.name, AllocMetric())
                 m.exhausted_node(node_id, "resources")
                 self.queued_allocs[tg.name] = self.queued_allocs.get(tg.name, 0) + 1
                 return
+        preempted = found.evicted if found is not None else []
         node = self.state.node_by_id(node_id)
         metric = AllocMetric()
         metric.nodes_evaluated = 1
+        # the one node the system stack looks at, scored as the upstream
+        # scores it: what the search ranked the row by where it evicts,
+        # the binpack fit where it does not
+        metric.populate_score_meta([
+            found.score_meta(node_id) if found is not None
+            else fit_score_meta(node_id, cm.capacity[row], used[row] + d)])
         alloc = build_allocation(
             job=job, tg=tg, name=name, node_id=node_id,
             node_name=node.name if node else "", eval_id=self.eval.id,
@@ -162,21 +195,21 @@ class SystemScheduler:
 
     def _try_preempt(self, plan, job, row, d, used):
         """System jobs preempt lower-priority work by default (reference
-        SystemScheduler + PreemptionConfig.SystemSchedulerEnabled)."""
+        SystemScheduler + PreemptionConfig.SystemSchedulerEnabled).
+        -> the one row's Eviction, or None."""
         if not self.state.scheduler_config.preemption_enabled(
                 "sysbatch" if self.sysbatch else "system"):
             return None
         if self._preemptor is None:
-            from nomad_tpu.scheduler.preemption import Preemptor
             self._preemptor = Preemptor(self.state, job.priority)
         feas = np.zeros(self.state.matrix.n_rows, bool)
         feas[row] = True
-        found = self._preemptor.find(feas, d, used)
+        with tracing.span("sched.preempt_find"):
+            found = self._preemptor.find(feas, d, used)
         if found is None:
             return None
-        evicted = found.evicted
-        self._preemptor.invalidate({a.id for a in evicted})
-        return evicted
+        self._preemptor.invalidate({a.id for a in found.evicted})
+        return found
 
 
 class SysBatchScheduler(SystemScheduler):
