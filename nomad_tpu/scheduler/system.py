@@ -3,11 +3,13 @@ and util.go diffSystemAllocsForNode:70).
 
 One allocation per eligible node per task group.  Feasibility is one dense
 mask over all nodes (`DenseStack.compile_group`); everything after it is a
-host loop over the node axis: a node that fits takes its allocation, one
-that does not asks the preemption search for that one row (one
-`Preemptor.find` a node, on the `Preemptor` the eval builds once).  No
-placement is coupled to another across nodes (each node hosts its own
-instance), so no scan is needed and the device is not asked.  A placement
+host work over the node axis, a task group at a time: one mask says which
+nodes fit, one preemption search answers every node that does not (one
+`Preemptor.find_many` a group, on the `Preemptor` the eval builds once),
+and the node loop only builds allocations.  No placement is coupled to
+another across nodes (each node hosts its own instance, and the eviction
+sets of distinct nodes are disjoint), so no scan is needed and the device
+is not asked.  A placement
 reports what the upstream's system stack scores the one node by: its
 binpack fit, and for a preempting one the mean of the fit after the
 eviction and the evicted set's preemption score, the value the search
@@ -24,7 +26,7 @@ from nomad_tpu import tracing
 from nomad_tpu.encode.matrixizer import comparable_vec
 
 from nomad_tpu.scheduler.placement import PortClaims, build_allocation
-from nomad_tpu.scheduler.preemption import Preemptor, fit_score_meta
+from nomad_tpu.scheduler.preemption import Eviction, Preemptor, fit_score_meta
 from nomad_tpu.scheduler.reconcile import tasks_updated
 from nomad_tpu.scheduler.stack import DenseStack
 from nomad_tpu.scheduler.util import tainted_nodes
@@ -115,58 +117,75 @@ class SystemScheduler:
         return plan, job, groups, live, terminal_newest, used
 
     def _place_nodes(self, plan, job, groups, live, terminal_newest, used):
-        """The node loop: one allocation of each group on every feasible
-        node that has none."""
+        """One allocation of each group on every feasible node that has
+        none.  For a group: what a job update stops leaves `used` first,
+        one mask over the node axis says which rows fit, one search
+        answers every row that does not, and the node loop builds the
+        allocations."""
         cm = self.state.matrix
         ports = PortClaims(cm)
         now = _time.time()
         for gi, tg in enumerate(job.task_groups):
-            g = groups[gi]
             name = alloc_name(job.id, tg.name, 0)
-            feas = g.feasible
-            d = g.demand
-            for node_id, row in cm.row_of.items():
-                if not feas[row]:
-                    continue
-                key = (node_id, name)
-                cur = live.get(key)
-                if cur is not None:
-                    # update in place or destructively on job change
-                    if cur.job is not None and cur.job.version != job.version:
-                        old_tg = cur.job.lookup_task_group(tg.name)
-                        if old_tg is not None and not tasks_updated(old_tg, tg):
-                            u = cur.copy()
-                            u.job = job
-                            plan.append_alloc(u, job)
-                        else:
-                            plan.append_stopped_alloc(
-                                cur, "alloc not needed due to job update")
-                            cr = cur.comparable_resources()
-                            used[row] -= comparable_vec(cr)
-                            self._try_place(plan, job, tg, name, node_id, row,
-                                            used, d, ports, now)
-                    continue
-                if self.sysbatch:
-                    t = terminal_newest.get(key)
-                    if t is not None and t.ran_successfully():
-                        continue   # sysbatch doesn't rerun completed nodes
-                elif terminal_newest.get(key) is not None and \
-                        terminal_newest[key].client_status == AllocClientStatus.COMPLETE:
-                    continue       # system alloc completed on purpose
-                self._try_place(plan, job, tg, name, node_id, row, used, d,
-                                ports, now)
+            d = groups[gi].demand
+            todo = self._settle(plan, job, tg, name, groups[gi].feasible,
+                                live, terminal_newest, used)
+            fits = np.all(used + d <= cm.capacity, axis=1)
+            asked = np.zeros(cm.n_rows, bool)
+            asked[[row for _, row, kept in todo if kept is None]] = True
+            asked &= ~fits
+            found = self._try_preempt(job, asked, d, used)
+            for node_id, row, kept in todo:
+                if kept is not None:
+                    plan.append_alloc(kept, job)
+                else:
+                    self._try_place(plan, job, tg, name, node_id, row, used,
+                                    d, ports, now, fits, found)
 
-    def _try_place(self, plan, job, tg, name, node_id, row, used, d, ports, now):
+    def _settle(self, plan, job, tg, name, feas, live, terminal_newest, used):
+        """Everything of a group that changes `used` ahead of a placement
+        (an allocation the job's update stops gives its room back), and
+        the rows the node loop walks, in `row_of`'s order.
+        -> [(node_id, row, kept)]: `kept` the live allocation's copy where
+        the update is in place, None where the row takes a new one."""
         cm = self.state.matrix
-        found = None
-        if not np.all(used[row] + d <= cm.capacity[row]):
-            found = self._try_preempt(plan, job, row, d, used)
-            if found is None:
-                m = self.failed_tg_allocs.setdefault(tg.name, AllocMetric())
-                m.exhausted_node(node_id, "resources")
-                self.queued_allocs[tg.name] = self.queued_allocs.get(tg.name, 0) + 1
-                return
-        preempted = found.evicted if found is not None else []
+        todo = []
+        for node_id, row in cm.row_of.items():
+            if not feas[row]:
+                continue
+            key = (node_id, name)
+            cur = live.get(key)
+            if cur is not None:
+                # update in place or destructively on job change
+                if cur.job is None or cur.job.version == job.version:
+                    continue
+                old_tg = cur.job.lookup_task_group(tg.name)
+                if old_tg is not None and not tasks_updated(old_tg, tg):
+                    todo.append((node_id, row, cur.copy()))
+                    continue
+                plan.append_stopped_alloc(
+                    cur, "alloc not needed due to job update")
+                cr = cur.comparable_resources()
+                used[row] -= comparable_vec(cr)
+            elif self.sysbatch:
+                t = terminal_newest.get(key)
+                if t is not None and t.ran_successfully():
+                    continue   # sysbatch doesn't rerun completed nodes
+            elif terminal_newest.get(key) is not None and \
+                    terminal_newest[key].client_status == AllocClientStatus.COMPLETE:
+                continue       # system alloc completed on purpose
+            todo.append((node_id, row, None))
+        return todo
+
+    def _try_place(self, plan, job, tg, name, node_id, row, used, d, ports,
+                   now, fits, found):
+        cm = self.state.matrix
+        evict = found.get(row)
+        if evict is None and not fits[row]:
+            m = self.failed_tg_allocs.setdefault(tg.name, AllocMetric())
+            m.exhausted_node(node_id, "resources")
+            self.queued_allocs[tg.name] = self.queued_allocs.get(tg.name, 0) + 1
+            return
         node = self.state.node_by_id(node_id)
         metric = AllocMetric()
         metric.nodes_evaluated = 1
@@ -174,7 +193,7 @@ class SystemScheduler:
         # scores it: what the search ranked the row by where it evicts,
         # the binpack fit where it does not
         metric.populate_score_meta([
-            found.score_meta(node_id) if found is not None
+            evict.score_meta(node_id) if evict is not None
             else fit_score_meta(node_id, cm.capacity[row], used[row] + d)])
         alloc = build_allocation(
             job=job, tg=tg, name=name, node_id=node_id,
@@ -184,32 +203,33 @@ class SystemScheduler:
             m = self.failed_tg_allocs.setdefault(tg.name, AllocMetric())
             m.exhausted_node(node_id, "ports")
             return
-        if preempted:
-            alloc.preempted_allocations = [a.id for a in preempted]
-            for a in preempted:
+        if evict is not None:
+            alloc.preempted_allocations = [a.id for a in evict.evicted]
+            for a in evict.evicted:
                 plan.append_preempted_alloc(a, alloc.id)
                 cr = a.comparable_resources()
                 used[row] -= comparable_vec(cr)
         used[row] += d
         plan.append_alloc(alloc, None)
 
-    def _try_preempt(self, plan, job, row, d, used):
+    def _try_preempt(self, job, asked, d, used) -> Dict[int, Eviction]:
         """System jobs preempt lower-priority work by default (reference
-        SystemScheduler + PreemptionConfig.SystemSchedulerEnabled).
-        -> the one row's Eviction, or None."""
-        if not self.state.scheduler_config.preemption_enabled(
-                "sysbatch" if self.sysbatch else "system"):
-            return None
+        SystemScheduler + PreemptionConfig.SystemSchedulerEnabled).  One
+        search for all the rows of `asked`: eviction sets on distinct rows
+        are disjoint and a group places once a node, so no row's answer
+        depends on another's.  -> {row: Eviction} of the rows answered."""
+        if not asked.any() or \
+                not self.state.scheduler_config.preemption_enabled(
+                    "sysbatch" if self.sysbatch else "system"):
+            return {}
         if self._preemptor is None:
             self._preemptor = Preemptor(self.state, job.priority)
-        feas = np.zeros(self.state.matrix.n_rows, bool)
-        feas[row] = True
         with tracing.span("sched.preempt_find"):
-            found = self._preemptor.find(feas, d, used)
-        if found is None:
-            return None
-        self._preemptor.invalidate({a.id for a in found.evicted})
-        return found
+            found = self._preemptor.find_many(asked, d, used,
+                                              count=int(asked.sum()))
+        self._preemptor.invalidate(
+            {a.id for e in found for a in e.evicted})
+        return {e.row: e for e in found}
 
 
 class SysBatchScheduler(SystemScheduler):

@@ -351,9 +351,10 @@ def test_find_many_with_rows_the_rules_add_back(rule):
 
 
 def test_a_single_feasible_row_is_searched_alone(monkeypatch):
-    """The system scheduler's shape: one `find` a node.  Its passes see
-    that row and no other, and give what the whole cluster's search gives
-    for it."""
+    """A one-row ask (a rack of one, a retry of one slot; the system
+    scheduler's shape until it asked once a group).  Its passes see that
+    row and no other, and give what the whole cluster's search gives for
+    it."""
     h, rows = _random_world(5)
     search, feasible = _search_of(h, rows, 5)
     demand, used = _ask(h, rows, 2), h.store.matrix.used.copy()
