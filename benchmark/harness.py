@@ -41,6 +41,65 @@ def load_benchmark() -> dict:
         return json.load(f)
 
 
+PER_LAYER_MAX = 128     # the driver refuses a longer `per_layer`
+
+
+def check_benchmark(bench: dict) -> None:
+    """`per_layer` as PERF.md section 3 rules it: a quantity is one entry,
+    named by its metric file's base name, and lists its cells.  `Refused`
+    with the entry's name, before anything starts, so that a list the
+    driver would refuse (or read nothing from) is heard of here."""
+    entries = bench["per_layer"]
+    if len(entries) > PER_LAYER_MAX:
+        raise Refused(f"per_layer has {len(entries)} entries, "
+                      f"{PER_LAYER_MAX} at most: the first one over is "
+                      f"{entries[PER_LAYER_MAX]['name']!r}")
+    cells = {w["name"] for w in bench["workloads"]}
+    moved = {m["name"]: set(m.get("workloads", cells))
+             for m in bench["end_to_end"]}
+    seen, split = set(), {}
+    for m in entries:
+        name = m["name"]
+        if name in seen:
+            raise Refused(f"per_layer names {name!r} twice")
+        seen.add(name)
+        listed = m.get("workloads")
+        if not isinstance(listed, list) or not listed:
+            raise Refused(f"per_layer {name!r} lists no workloads")
+        if m["moves"] not in moved:
+            raise Refused(f"per_layer {name!r} moves {m['moves']!r}, "
+                          "which is no end-to-end metric")
+        for cell in listed:
+            if cell not in cells:
+                raise Refused(f"per_layer {name!r} lists {cell!r}, "
+                              "which is no workload")
+            if cell not in moved[m["moves"]]:
+                raise Refused(f"per_layer {name!r} lists {cell!r}, which "
+                              f"does not report {m['moves']!r}")
+        path = _metric_file(name)
+        if path is None:
+            raise Refused(f"per_layer {name!r} has no metric file under "
+                          "benchmark/metrics")
+        with open(path) as f:
+            reader = json.load(f).get("reader")
+        try:
+            importlib.import_module(f"benchmark.readers.{reader}")
+        except ImportError as e:
+            raise Refused(f"per_layer {name!r}: reader {reader!r} of "
+                          f"{os.path.basename(path)}: {e}") from e
+        # a name without a file of its own is a copy of its base name's
+        base = os.path.basename(path)[:-len(".json")]
+        split.setdefault((base, m["moves"]), []).append(name)
+    for (base, moves), names in split.items():
+        if len(names) > 1:
+            extra = next(n for n in names if n != base)
+            other = next(n for n in names if n != extra)
+            raise Refused(
+                f"per_layer {extra!r} reads {base!r}'s metric file and moves "
+                f"{moves!r}, as {other!r} does: one entry lists the cells "
+                "of both, or the copy brings a metric file of its own")
+
+
 def load_config(name: str) -> dict:
     with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
         return json.load(f)
@@ -198,6 +257,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     and `require_tpu=False` are for the tests under benchmark/tests,
     which drive the same code at a small size on the CPU."""
     bench = load_benchmark()
+    check_benchmark(bench)
     cell = next((w for w in bench["workloads"] if w["name"] == workload), None)
     if cell is None:
         raise Refused(f"no workload {workload!r} in BENCHMARK.json")
@@ -410,17 +470,23 @@ def _sample(done: list, k: int, seed: int) -> list:
     return [big] + [rest[i] for i in sorted(pick)]
 
 
+def _metric_file(name: str):
+    """The metric file of a per-layer entry: `metrics/<name>.json`, or the
+    file of its base name, which a quantity split by what it moves
+    (`x.backlog`, `x`) shares.  None where there is none."""
+    for stem in (name, name.rpartition(".")[0]):
+        path = os.path.join(HERE, "metrics", f"{stem}.json")
+        if stem and os.path.exists(path):
+            return path
+    return None
+
+
 def _metric_specs(bench: dict, workload: str):
     """(entry of BENCHMARK.json, its metric file) for the cell's
-    per-layer metrics.  A quantity split by what it moves
-    (`x.backlog`, `x.steady`) shares the file of its base name."""
+    per-layer metrics."""
     for m in bench["per_layer"]:
         if workload in m.get("workloads", [workload]):
-            path = os.path.join(HERE, "metrics", f"{m['name']}.json")
-            if not os.path.exists(path):
-                path = os.path.join(
-                    HERE, "metrics", f"{m['name'].rpartition('.')[0]}.json")
-            with open(path) as f:
+            with open(_metric_file(m["name"])) as f:
                 yield m, json.load(f)
 
 

@@ -9,9 +9,10 @@ from the benchmark's client, the runtime's own `TraceMe`s).
 * busy: the union of the op intervals of one chip, averaged over chips;
 * kernel time: the module events whose name holds a registered kernel's
   name, averaged over chips;
-* idle gaps: the spaces between busy intervals on the first chip, each
-  given to the host span that overlaps it most (the client's own waiting
-  spans only when nothing else does).
+* idle gaps: the spaces between busy intervals on the first chip, cut
+  where a host event starts or ends; each slice is shared by the host
+  threads that have an event open in it (`blame`), so the list adds up
+  to the gaps' own length.
 """
 from __future__ import annotations
 
@@ -21,7 +22,11 @@ import os
 DEVICE_PREFIXES = ("/device:TPU:",)
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# whose claim on an idle slice counts, in this order: a program or
+# runtime event; the benchmark client's own spans; the client waiting
+_CLIENT = "bench."
 _WAITING = ("bench.wait", "bench.idle")
+NO_SPAN = "no host span"
 
 
 def find_trace(log_dir: str) -> str:
@@ -51,13 +56,82 @@ def _events(line):
             for e in line.events if e.duration_ns > 0]
 
 
+def _rank(name: str) -> int:
+    if not name.startswith(_CLIENT):
+        return 0
+    return 2 if name.startswith(_WAITING) else 1
+
+
+def blame(gaps: list, events_by_line: dict) -> dict:
+    """{name: time} of the idle `gaps` ((start, end), disjoint) by what the
+    host was doing.  `events_by_line`: {line (a thread): [(name, start,
+    end)]}.  Every gap is cut at the starts and ends of the events inside
+    it.  In a slice a thread claims by its innermost open event, and the
+    slice's length is split evenly among the threads whose claim is no
+    `bench.*` name; where there is none, among the `bench.*` claims, the
+    waiting ones (`bench.wait`, `bench.idle`) last; where no thread has
+    an event open, it goes to `no host span`.  The values add up to the
+    gaps' length.  One sweep over the sorted event edges."""
+    edges = []
+    for line, events in events_by_line.items():
+        for i, (name, s, e) in enumerate(events):
+            if e > s:
+                edges.append((s, 1, line, i, name))
+                edges.append((e, 0, line, i, name))
+    edges.sort(key=lambda x: x[:2])       # at one instant: ends, then starts
+    open_on = {line: {} for line in events_by_line}   # line: {i: name}
+    claims = [{}, {}, {}]                 # by rank: {name: threads claiming}
+    out: dict = {}
+
+    def move(line, sign):
+        held = open_on[line]
+        if held:
+            # events of a thread nest, so the last opened is the innermost
+            name = held[next(reversed(held))]
+            by_name = claims[_rank(name)]
+            n = by_name.get(name, 0) + sign
+            if n:
+                by_name[name] = n
+            else:
+                del by_name[name]
+
+    def give(t0, t1):
+        if t1 <= t0:
+            return
+        share = next((c for c in claims if c), None)
+        if share is None:
+            out[NO_SPAN] = out.get(NO_SPAN, 0.0) + (t1 - t0)
+            return
+        each = (t1 - t0) / sum(share.values())
+        for name, threads in share.items():
+            out[name] = out.get(name, 0.0) + each * threads
+
+    k = 0
+    for gs, ge in sorted(gaps):
+        at = gs
+        while k < len(edges) and edges[k][0] < ge:
+            t, start, line, i, name = edges[k]
+            k += 1
+            if t > at:
+                give(at, t)
+                at = t
+            move(line, -1)
+            if start:
+                open_on[line][i] = name
+            else:
+                open_on[line].pop(i, None)
+            move(line, +1)
+        give(at, ge)
+    return out
+
+
 def reduce_trace(path: str, kernels: dict) -> dict:
     """`kernels`: {key: substring of the compiled program's name}.
     Returns busy_s, chips, kernel_s per key, device_ops and idle_gaps
     (each at most 10 [name, seconds] pairs)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    chips, host = [], []
+    chips, host = [], {}
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIXES):
             lines = {ln.name: ln for ln in plane.lines}
@@ -67,7 +141,7 @@ def reduce_trace(path: str, kernels: dict) -> dict:
                           if MODULES_LINE in lines else []))
         elif plane.name.startswith("/host:CPU"):
             for ln in plane.lines:
-                host.extend(_events(ln))
+                host[f"{ln.name}#{len(host)}"] = _events(ln)
     if not chips:
         return {"chips": 0, "busy_s": 0.0, "kernel_s": {},
                 "device_ops": [], "idle_gaps": []}
@@ -83,22 +157,7 @@ def reduce_trace(path: str, kernels: dict) -> dict:
                     kernel[key] += e - s
     ops0 = _union([(s, e) for _, s, e in chips[0][0]])
     gaps = [(a[1], b[0]) for a, b in zip(ops0, ops0[1:]) if b[0] > a[1]]
-    gaps.sort(key=lambda g: g[0] - g[1])
-    blame: dict = {}
-    host.sort(key=lambda ev: ev[1])
-    host = [ev for ev in host if ev[2] - ev[1] >= 20_000.0]
-    for gs, ge in gaps[:200]:
-        best, second = {}, {}
-        for name, s, e in host:
-            if s >= ge:
-                break
-            ov = min(e, ge) - max(s, gs)
-            if ov > 0:
-                d = second if name.startswith(_WAITING) else best
-                d[name] = d.get(name, 0.0) + ov
-        pick = best or second
-        who = max(pick, key=pick.get) if pick else "no host span"
-        blame[who] = blame.get(who, 0.0) + (ge - gs)
+
     def top(d: dict) -> list:
         return [[k, v / 1e9] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:10]]
@@ -106,4 +165,4 @@ def reduce_trace(path: str, kernels: dict) -> dict:
     return {"chips": n, "busy_s": busy / n / 1e9,
             "kernel_s": {k: v / n / 1e9 for k, v in kernel.items()},
             "device_ops": top({k: v / n for k, v in per_op.items()}),
-            "idle_gaps": top(blame)}
+            "idle_gaps": top(blame(gaps, host))}
