@@ -8,6 +8,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import List, Optional
 
+from nomad_tpu import tracing
 from nomad_tpu.core.server import Server, ServerConfig
 
 
@@ -45,6 +46,7 @@ class Agent:
         self.client = None
         self.http: Optional["HTTPServer"] = None
         self._lock = threading.Lock()
+        self._watching_gc = False
         # in-process log ring feeding /v1/agent/monitor (reference
         # command/agent/monitor/monitor.go: a log broker the HTTP monitor
         # endpoint streams from)
@@ -102,6 +104,10 @@ class Agent:
                 rpc=self.server.endpoints.handle)
 
     def start(self) -> None:
+        if not self._watching_gc:
+            # the collector's pauses as `gc.collect.gen<n>` spans
+            self._watching_gc = True
+            tracing.watch_gc(True)
         if self.server is not None:
             self.server.start()
         if self.client is not None:
@@ -144,6 +150,9 @@ class Agent:
             self.client.stop()
         if self.server is not None:
             self.server.stop()
+        if self._watching_gc:
+            self._watching_gc = False
+            tracing.watch_gc(False)
 
     @property
     def http_addr(self) -> str:

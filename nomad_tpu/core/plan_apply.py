@@ -146,7 +146,7 @@ class PlanApplier:
                     commit_in_flight = (commit_t is not None
                                         and commit_t.is_alive())
                     with tracing.span("plan.evaluate", ctx=pending.ctx,
-                                      node=node):
+                                      node=node, cpu=True):
                         result = self._evaluate(pending.plan)
                     if commit_in_flight and \
                             self._result_rejected_something(pending.plan,
@@ -206,9 +206,10 @@ class PlanApplier:
         happens here, off the evaluation lock; overlay entries pop only
         after the write lands (never a double-free window)."""
         try:
-            entries = [(pending, result,
-                        self._applied_for(pending.plan, result))
-                       for pending, result, _token in staged]
+            with tracing.span("plan.flatten", cpu=True):
+                entries = [(pending, result,
+                            self._applied_for(pending.plan, result))
+                           for pending, result, _token in staged]
             applied_list = [ap for _, _, ap in entries if ap is not None]
             index = None
             if applied_list:

@@ -1020,7 +1020,7 @@ class PlacementEngine:
             return
         self.stats["device_s"] += got.seconds
         try:
-            with tracing.span("engine.resolve", ctx=ctx) as sp:
+            with tracing.span("engine.resolve", ctx=ctx, cpu=True) as sp:
                 self._resolve_bulk(p.reqs, fetched, p.world, p.deltas_per,
                                    mapping=p.mapping)
         except Exception as e:                  # noqa: BLE001
@@ -1042,7 +1042,7 @@ class PlacementEngine:
         with tracing.span("engine.device_get", wait=True, ctx=ctx) as got:
             fetched = jax.device_get(packed)
         self.stats["device_s"] += got.seconds
-        with tracing.span("engine.resolve", ctx=ctx) as sp:
+        with tracing.span("engine.resolve", ctx=ctx, cpu=True) as sp:
             node, score, fit_s, n_eval, n_exh, top_n, top_s = \
                 unpack_outputs(np.asarray(fetched))
             for i, r in enumerate(reqs):
@@ -1132,7 +1132,7 @@ class PlacementEngine:
         E = next(b for b in self.E_BUCKETS if b >= len(reqs))
         S = _s_bucket(reqs[0].inputs.demand.shape[0])
         ctx = self._ctx_of(reqs)
-        with tracing.span("engine.stack", ctx=ctx) as sp:
+        with tracing.span("engine.stack", ctx=ctx, cpu=True) as sp:
             fields = {}
             for f in self._SHARD_FIELDS:
                 arrs = [np.asarray(getattr(r.inputs, f)) for r in reqs]
@@ -1155,7 +1155,7 @@ class PlacementEngine:
             drows, dvals = self._stack_deltas(
                 deltas_per + [[]] * (E - len(reqs)), E, N)
         self.stats["stack_s"] += sp.seconds
-        with tracing.span("engine.put", ctx=ctx) as sp:
+        with tracing.span("engine.put", ctx=ctx, cpu=True) as sp:
             # content-addressed sharded placement: identical job-state
             # batches (the common case) ship zero bytes; basis/deltas always
             # ship (they change every dispatch and are small)
@@ -1235,7 +1235,7 @@ class PlacementEngine:
         self.stats["lane_slots"] += W * E
 
         ctx = self._ctx_of(reqs)
-        with tracing.span("engine.stack", ctx=ctx) as sp:
+        with tracing.span("engine.stack", ctx=ctx, cpu=True) as sp:
             # content key from per-request digests (packbits + zero-marker
             # fast paths) — cheaper than hashing the stacked [W, E, N]
             # tensors, and a hit skips even BUILDING the host stacks.  The
@@ -1287,7 +1287,7 @@ class PlacementEngine:
             drows = np.stack(lane_drows)
             dvals = np.stack(lane_dvals)
         self.stats["stack_s"] += sp.seconds
-        with tracing.span("engine.put", ctx=ctx) as sp:
+        with tracing.span("engine.put", ctx=ctx, cpu=True) as sp:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as _P
             lane3 = NamedSharding(
@@ -1376,7 +1376,7 @@ class PlacementEngine:
         D = _DELTA_BUCKET if any(deltas_per) else 0
 
         ctx = self._ctx_of(reqs)
-        with tracing.span("engine.stack", ctx=ctx) as sp:
+        with tracing.span("engine.stack", ctx=ctx, cpu=True) as sp:
             lights = [pack_bulk_light(r.has_affinity, r.desired, r.count,
                                       r.demand, ds, N, D)
                       for r, ds in zip(reqs, deltas_per)]
@@ -1386,7 +1386,7 @@ class PlacementEngine:
                 lights += [np.zeros(Ll, np.float32)] * (E - len(reqs))
             dyn = np.concatenate(lights)
         self.stats["stack_s"] += sp.seconds
-        with tracing.span("engine.put", ctx=ctx) as sp:
+        with tracing.span("engine.put", ctx=ctx, cpu=True) as sp:
             # device-resident world: epoch upload once, dirty-row scatters
             # after; steady state ships zero basis bytes (the kernel's
             # exact carry IS the new resident basis and only the host
@@ -1532,7 +1532,7 @@ class PlacementEngine:
         D = _DELTA_BUCKET
 
         ctx = self._ctx_of(reqs)
-        with tracing.span("engine.stack", ctx=ctx) as sp:
+        with tracing.span("engine.stack", ctx=ctx, cpu=True) as sp:
             lights = [pack_light(r.inputs, d, D, S)
                       for r, d in zip(reqs, deltas_per_req)]
             Ll = lights[0].shape[0]
@@ -1543,7 +1543,7 @@ class PlacementEngine:
         self.stats["stack_s"] += sp.seconds
         # cache resolution inside the put window: misses device_put the
         # heavy bytes, and that transfer cost belongs in put_s
-        with tracing.span("engine.put", ctx=ctx) as sp:
+        with tracing.span("engine.put", ctx=ctx, cpu=True) as sp:
             cap_dev, used_dev = self._world(
                 reqs[0].cm, basis.shape[0]).update(capacity, basis)
             heavy = [self._cache.heavy(r.inputs) for r in reqs]

@@ -143,7 +143,7 @@ class GenericScheduler:
             batch=self.batch,
             eval_priority=ev.priority,
         )
-        with tracing.span("sched.reconcile"):
+        with tracing.span("sched.reconcile", cpu=True):
             results = reconciler.compute()
 
         # follow-up (delayed) evals must exist before allocs reference them
@@ -361,7 +361,7 @@ class GenericScheduler:
         self._stack = stack
         job = self.job
         tg_index = {tg.name: i for i, tg in enumerate(job.task_groups)}
-        with tracing.span("sched.feasible"):
+        with tracing.span("sched.feasible", cpu=True):
             groups = [stack.compile_group(job, tg)
                       for tg in job.task_groups]
         # constraint-only union, NOT g.feasible: readiness and capacity
@@ -497,7 +497,7 @@ class GenericScheduler:
                   else [slot_requests] if slot_requests else [])
 
         def place_round(prs):
-            with tracing.span("sched.feasible"):
+            with tracing.span("sched.feasible", cpu=True):
                 inputs = stack.build_inputs(
                     job, groups, [tg_index[pr.task_group] for pr in prs],
                     allocs_by_tg, penalty_nodes=penalty_nodes,
@@ -516,7 +516,7 @@ class GenericScheduler:
                 grants.setdefault(
                     gid, np.zeros(cm.n_rows, np.int64))[row] += count
             stack.device_grants = grants
-            with tracing.span("sched.feasible"):
+            with tracing.span("sched.feasible", cpu=True):
                 for gi_, tg_ in enumerate(job.task_groups):
                     if groups[gi_].place_cap is not None:
                         groups[gi_] = stack.compile_group(job, tg_)
@@ -761,7 +761,7 @@ class GenericScheduler:
 
         # rows become Allocation records (and slots that found no row
         # go to the preemption search): one span for the eval
-        with tracing.span("sched.materialise"):
+        with tracing.span("sched.materialise", cpu=True):
             for pr, row in preplaced:
                 extra = []
                 place_on(pr, row, metric_for(None), preempted=extra)

@@ -1336,12 +1336,7 @@ class StateStore:
         """Apply a committed plan (reference UpsertPlanResults,
         state_store.go:337): denormalize stopped/preempted allocs, insert
         placements, attach deployment updates."""
-        touched: list = []
-        with self._lock:
-            self._upsert_plan_result_locked(index, result, touched)
-            self._bump(index)
-        for a in touched:
-            self._notify("allocs", a)
+        self.upsert_plan_results_many(index, (result,))
 
     def upsert_plan_results_many(self, index: int,
                                  results) -> None:
@@ -1349,14 +1344,20 @@ class StateStore:
         acquisition and ONE index bump — the applier's batch commit.
         Plans in a batch touch disjoint alloc ids (each scheduler eval
         owns its placements), so sharing an index is safe: upserts are
-        keyed by alloc id and create_index is preserved on update."""
+        keyed by alloc id and create_index is preserved on update.
+
+        `store.plan_write` is the write alone, lock in hand (the wait
+        for the lock is the caller's), and closes before the first
+        watcher hears of it; `store.plan_notify` is the watchers."""
         touched: list = []
         with self._lock:
-            for result in results:
-                self._upsert_plan_result_locked(index, result, touched)
-            self._bump(index)
-        for a in touched:
-            self._notify("allocs", a)
+            with tracing.span("store.plan_write", cpu=True):  # analysis: allow(fsm-determinism) — a duration for the metrics registry and the profiler; nothing a replica stores reads it
+                for result in results:
+                    self._upsert_plan_result_locked(index, result, touched)
+                self._bump(index)
+        with tracing.span("store.plan_notify"):  # analysis: allow(fsm-determinism) — as store.plan_write: the watchers' time, kept out of the store
+            for a in touched:
+                self._notify("allocs", a)
 
 
 class AppliedPlanResults:

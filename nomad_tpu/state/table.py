@@ -31,8 +31,11 @@ Writers are serialized by the store's lock; `view()` is called under it.
 from __future__ import annotations
 
 from collections.abc import Mapping
+from time import perf_counter
 from typing import Dict
 from zlib import crc32
+
+from nomad_tpu import tracing
 
 _EMPTY: dict = {}       # every bucket of a new table; never owned, never written
 
@@ -140,10 +143,14 @@ class Table(dict):
                 self._root_gen = gen
                 self._stats["roots_copied"] += 1
             old = self._root[i]
-            self._root[i] = dict(old)
             self._bgen[i] = gen
             if old:
+                t0 = perf_counter()
+                self._root[i] = dict(old)
+                tracing.record("store.bucket_copy", t0, perf_counter())  # analysis: allow(fsm-determinism, allow-audit) — under the FSM's apply at run time (`t[k] = v` is no call the static cone follows): a duration for the metrics registry, nothing a replica stores
                 self._stats["buckets_copied"] += 1
+            else:
+                self._root[i] = {}
         return self._root[i]
 
     def __setitem__(self, key, value) -> None:

@@ -1,15 +1,20 @@
 """One span primitive for the placement spine, and its three sinks.
 
 `span(name)` (a context manager) and `record(name, start, end)` (for an
-interval that began on another thread or was stamped outside the FSM
-cone) are the only way the program times a layer boundary.  One call
-feeds, from one pair of `time.perf_counter()` reads:
+interval that began on another thread, or whose two clock readings the
+caller took itself) are the only way the program times a layer
+boundary.  One call feeds, from one pair of `time.perf_counter()` reads:
 
 * **the counter, always**: `count` and `total` of the span go to
   `telemetry.global_metrics` as the Sample ``nomad.<name>`` (`/v1/metrics`).
   A span that had child spans on its own thread also records
   ``nomad.self.<name>``: its duration less what its direct children
-  covered.
+  covered.  `span(name, cpu=True)` also reads `time.thread_time()` at
+  both ends and records ``nomad.cpu.<name>``: the milliseconds of the
+  span in which its thread was running.  The wall time less that is the
+  time a "working" thread waited to run (the GIL, mostly).  A thread
+  clock read is a system call, so only the few call sites whose CPU
+  share a metric reads ask for it.
 * **the profiler's clock**: while a `jax.profiler` session runs, the span
   is a `TraceAnnotation` in the `/host:CPU` lines of the trace, beside
   the device ops.  Each thread shows a FLAT sequence named by its
@@ -32,11 +37,25 @@ the key IS the unsampled state.  The tracer is installed process-wide
 
     NOMAD_TPU_TRACE=1 NOMAD_TPU_TRACE_SAMPLE=0.01 nomad agent ...
 
-Nothing is stamped inside the FSM cone, so replicas replay to
-byte-identical state (see nomad_tpu.analysis.fsm_determinism): spans
-open around the FSM call, never under it, and trace context never rides
-in log payloads.  Durations come from `time.perf_counter()`; the wall
-clock is read only for a Dapper span's display start.
+**The collector's pauses** are spans too: while an agent runs
+(`watch_gc`), every collection is ``gc.collect.gen<n>`` on the thread
+that collects, a child of whatever span was open there, so it leaves
+that span's self time and is a piece of its own on the profiler's
+timeline (an idle gap under a collection reads ``gc.collect.gen2``, not
+the victim's name).  A collection may start while its thread holds the
+registry's or the tracer's lock, so the callback takes no lock: the
+counter and the Dapper span are written by the next `span` or `record`
+to end, on any thread.
+
+No clock reading reaches the store or a log payload, so replicas replay
+to byte-identical state (see nomad_tpu.analysis.fsm_determinism).  A
+span under the FSM's apply (`store.plan_write`, `store.plan_notify`,
+`store.bucket_copy`) is allowed line by line there: its duration goes
+to the three sinks above and to nothing a replica stores
+(`tests/test_span_metrics.py` holds two replicas fed one log to
+byte-identical state).  Trace context never rides in log payloads.
+Durations come from `time.perf_counter()`; the wall clock is read only
+for a Dapper span's display start.
 
 Dapper spans land in a bounded ring `SpanStore` per server
 (`store_for(node)`), queried through `/v1/traces` +
@@ -45,12 +64,13 @@ Dapper spans land in a bounded ring `SpanStore` per server
 """
 from __future__ import annotations
 
+import gc
 import random
 import sys
 import threading
 import time
 from collections import deque
-from time import perf_counter
+from time import perf_counter, thread_time
 from typing import Any, Dict, List, Optional
 
 from nomad_tpu import knobs
@@ -318,13 +338,15 @@ def take_eval_ctx(eval_id: str) -> Optional[dict]:
 _TraceMe = None
 
 
-def _annotate(name: str):
+def _annotate(name: str, load: bool = True):
     """An entered profiler annotation, or None when no profiler session
-    runs (or jax was never imported by this process)."""
+    runs (or jax was never imported by this process).  `load=False`
+    never imports: the collector's callback may run in the middle of
+    jax's own import."""
     global _TraceMe
     tm = _TraceMe
     if tm is None:
-        if "jax" not in sys.modules:
+        if not load or "jax" not in sys.modules:
             return None
         from jax.profiler import TraceAnnotation as tm
         _TraceMe = tm
@@ -338,19 +360,22 @@ def _annotate(name: str):
 class span:
     """``with tracing.span("plan.evaluate"): ...`` — see the module
     docstring for the three sinks.  `wait=True` marks time blocked on
-    another thread's work.  `ctx` starts the Dapper span under that
+    another thread's work.  `cpu=True` also records the thread's CPU
+    time as ``nomad.cpu.<name>``.  `ctx` starts the Dapper span under that
     context instead of the thread's own (ingress, contexts that crossed
     a queue); `node` and `attrs` go to the Dapper span only, and `attrs`
     may be filled while the span is open.  After exit `seconds` holds
     the duration."""
 
-    __slots__ = ("name", "wait", "ctx", "node", "attrs", "seconds",
-                 "_t0", "_children", "_ann", "_dapper", "_prev")
+    __slots__ = ("name", "wait", "cpu", "ctx", "node", "attrs", "seconds",
+                 "_t0", "_c0", "_children", "_ann", "_dapper", "_prev")
 
     def __init__(self, name: str, wait: bool = False,
-                 ctx: Optional[dict] = None, node: str = "", **attrs):
+                 ctx: Optional[dict] = None, node: str = "",
+                 cpu: bool = False, **attrs):
         self.name = name
         self.wait = wait
+        self.cpu = cpu
         self.ctx = ctx
         self.node = node
         self.attrs = attrs
@@ -375,16 +400,24 @@ class span:
                 self._prev = bind(tracer.child_ctx(ctx, self._dapper))
         if not self.wait:
             self._ann = _annotate(self.name)
+        if self.cpu:
+            self._c0 = thread_time()
         self._t0 = perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = self.seconds = perf_counter() - self._t0
+        if self.cpu:
+            ran = thread_time() - self._c0
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
         stack = _tls.stack
         stack.pop()
+        if _gc_done:
+            _flush_gc()
         global_metrics.add_sample("nomad." + self.name, dur * 1e3)
+        if self.cpu:
+            global_metrics.add_sample("nomad.cpu." + self.name, ran * 1e3)
         if self._children is not None:
             global_metrics.add_sample(
                 "nomad.self." + self.name,
@@ -414,6 +447,13 @@ def record(name: str, start: float, end: float, wait: bool = False,
     `time.perf_counter()` readings.  Counted like a span; never on the
     profiler's timeline (it is over), never a child of the thread's open
     span; a Dapper span under `ctx` when one is given."""
+    if _gc_done:
+        _flush_gc()
+    _record(name, start, end, wait, ctx, node, attrs)
+
+
+def _record(name: str, start: float, end: float, wait: bool,
+            ctx: Optional[dict], node: str, attrs: dict) -> None:
     dur = max(0.0, end - start)
     global_metrics.add_sample("nomad." + name, dur * 1e3)
     tracer = active
@@ -422,6 +462,65 @@ def record(name: str, start: float, end: float, wait: bool = False,
             attrs["wait"] = True
         wall = time.time() - (perf_counter() - start)
         tracer.emit(ctx, name, wall, wall + dur, node=node, **attrs)
+
+
+# ================================================================ collector
+
+_GC_NAMES = ("gc.collect.gen0", "gc.collect.gen1", "gc.collect.gen2")
+# the collection under way (one at a time in a process) and the finished
+# ones whose counter and Dapper span are still to be written
+_gc_open: Optional[tuple] = None
+_gc_done: deque = deque()
+_gc_watchers = 0
+_gc_lock = threading.Lock()
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` entry: the collection as a span of the collecting
+    thread.  Takes no lock and touches neither the registry nor the
+    tracer (see the module docstring)."""
+    global _gc_open
+    stack = getattr(_tls, "stack", None)
+    parent = stack[-1] if stack else None
+    if phase == "start":
+        cut = parent is not None and parent._ann is not None
+        if cut:
+            parent._ann.__exit__(None, None, None)
+            parent._ann = None
+        name = _GC_NAMES[info["generation"]]
+        _gc_open = (name, _annotate(name, False), cut, perf_counter())
+    elif _gc_open is not None:
+        end = perf_counter()
+        (name, ann, cut, start), _gc_open = _gc_open, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if parent is not None:
+            parent._children = (parent._children or 0.0) + (end - start)
+            if cut:
+                parent._ann = _annotate(parent.name, False)
+        _gc_done.append((name, start, end, getattr(_tls, "ctx", None)))
+
+
+def _flush_gc() -> None:
+    while True:
+        try:
+            name, start, end, ctx = _gc_done.popleft()
+        except IndexError:
+            return
+        _record(name, start, end, False, ctx, "", {})
+
+
+def watch_gc(on: bool) -> None:
+    """An agent's start (`on`) and shutdown: the collector's callback is
+    registered while at least one agent of the process runs, once."""
+    global _gc_watchers
+    with _gc_lock:
+        _gc_watchers += 1 if on else -1
+        if on and _gc_watchers == 1:
+            gc.callbacks.append(_on_gc)
+        elif not on and _gc_watchers == 0:
+            gc.callbacks.remove(_on_gc)
+            _flush_gc()
 
 
 if knobs.get_bool("NOMAD_TPU_TRACE"):

@@ -77,7 +77,8 @@ def spine_metrics():
     a batch job (bulk path) registered over HTTP and placed, then one
     blocking query.  Returns what `/v1/metrics` served afterwards, as
     {"samples": {name: summary}, "prometheus": text, "processed": evals
-    the workers acked}."""
+    the workers acked}; "later" holds the samples after a second stage (a
+    system job that evicts, `jobs.allocations`, three collections)."""
     import time
     import urllib.request
 
@@ -112,6 +113,23 @@ def spine_metrics():
             "processed": sum(w.stats["processed"]
                              for w in a.server.workers),
         }
+        # a second stage, after the counts above are taken: a system job
+        # that has to evict on the nodes the two jobs filled, the read a
+        # client confirms a job by, and one collection of each generation
+        import gc
+        fleet = mock.system_job()
+        fleet.task_groups[0].tasks[0].resources.cpu = 3000
+        api.jobs.register(fleet)
+        assert a.server.wait_for_idle(30.0)
+        got["fleet_allocs"] = api.jobs.allocations(fleet.id)
+        got["evicted"] = sum(x["DesiredStatus"] == "evict"
+                             for x in api.get("/v1/allocations"))
+        for generation in (0, 1, 2):
+            gc.collect(generation)
+        time.sleep(0.2)
+        api.jobs.allocations(fleet.id)      # a span closes: pauses counted
+        got["later"] = {s["Name"]: s
+                        for s in api.system.metrics()["Samples"]}
     finally:
         # stopped before the first test reads it: no thread of this
         # agent outlives the fixture's set-up

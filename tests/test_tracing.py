@@ -4,6 +4,7 @@ through the spine, raft span attribution, federation-hop survival,
 span-store bounds, sampling, and Chrome-trace export.  This file is also
 the CI `tracing` leg's payload — it must stay green under
 NOMAD_TPU_RACE=1."""
+import gc
 import io
 import json
 import threading
@@ -276,6 +277,193 @@ def test_spans_nest_per_thread():
     assert _sample("nomad.self." + name) is None
 
 
+def _total_ms(name):
+    s = _sample(name)
+    return s["mean"] * s["count"] if s else 0.0
+
+
+def test_cpu_span_records_thread_time_beside_wall_time():
+    """`cpu=True`: `nomad.cpu.<name>` holds the part of the span its
+    thread was running (a sleep is none of it), never more than the
+    wall time; a span without the flag writes no such Sample."""
+    with tracing.span("tcpu.busy", cpu=True) as busy:
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.02:
+            pass
+    with tracing.span("tcpu.asleep", cpu=True) as asleep:
+        time.sleep(0.02)
+    with tracing.span("tcpu.plain"):
+        pass
+    for sp, least in ((busy, 5.0), (asleep, 0.0)):
+        cpu = _sample("nomad.cpu." + sp.name)
+        assert cpu["count"] == 1
+        assert least <= cpu["mean"] <= sp.seconds * 1e3 + 1.0
+    assert _sample("nomad.cpu.tcpu.asleep")["mean"] < 10.0
+    assert _sample("nomad.tcpu.plain")["count"] == 1
+    assert _sample("nomad.cpu.tcpu.plain") is None
+
+
+def test_collection_is_a_child_span_of_the_collecting_thread(annotations):
+    """While an agent runs, a collection is `gc.collect.gen<n>` on the
+    thread that collects: one Sample, out of the open span's self time,
+    a piece of its own on the flat timeline; after the agent's shutdown
+    `gc.callbacks` is as it was found."""
+    from nomad_tpu.agent import Agent, AgentConfig
+
+    found = list(gc.callbacks)
+    a = Agent(AgentConfig(http_port=0, num_schedulers=1))
+    a.start()
+    try:
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        gc.disable()        # no collection but the one asked for
+        try:
+            with tracing.span("tgc.before"):
+                pass        # a span's end writes the pauses before it
+            n0 = _count("nomad.gc.collect.gen2")
+            me = threading.get_ident()
+            del annotations[:]
+            with tracing.span("tgc.outer") as outer:
+                gc.collect(2)
+            mine = [(n, k) for t, n, k in annotations if t == me]
+        finally:
+            gc.enable()
+        pause = _sample("nomad.gc.collect.gen2")
+        assert pause["count"] == n0 + 1
+        self_ms = _sample("nomad.self.tgc.outer")
+        assert self_ms["count"] == 1
+        assert self_ms["mean"] <= outer.seconds * 1e3 - pause["p50"] * 0.5
+        assert mine == [("tgc.outer", "B"), ("tgc.outer", "E"),
+                        ("gc.collect.gen2", "B"), ("gc.collect.gen2", "E"),
+                        ("tgc.outer", "B"), ("tgc.outer", "E")]
+    finally:
+        a.stop()
+    assert tracing._on_gc not in gc.callbacks
+    assert [c for c in gc.callbacks if c in found] == found
+
+
+def test_many_pending_pauses_are_written_by_one_span_end():
+    """Pauses wait for the next span or record to end, however many: a
+    stretch with collections and no span (a comparison after the window)
+    leaves thousands, and one span's end writes them all."""
+    t = time.perf_counter()
+    n0 = _count("nomad.gc.collect.gen0")
+    tracing._gc_done.extend(
+        ("gc.collect.gen0", t, t + 1e-4, None) for _ in range(5000))
+    with tracing.span("tgc.flush"):
+        pass
+    assert not tracing._gc_done
+    assert _count("nomad.gc.collect.gen0") == n0 + 5000
+
+
+def test_two_watchers_register_the_callback_once():
+    found = list(gc.callbacks)
+    tracing.watch_gc(True)
+    tracing.watch_gc(True)
+    assert gc.callbacks == found + [tracing._on_gc]
+    tracing.watch_gc(False)
+    assert gc.callbacks == found + [tracing._on_gc]
+    tracing.watch_gc(False)
+    assert gc.callbacks == found
+
+
+def _store_with_allocs(n_before):
+    """A store with a node, a job and `n_before` of its allocations."""
+    from nomad_tpu.scheduler.testing import Harness
+    h = Harness()
+    node, job = mock.node(), mock.job()
+    h.store.upsert_node(h.next_index(), node)
+    h.store.upsert_job(h.next_index(), job)
+    h.store.upsert_allocs(h.next_index(), [
+        mock.alloc_for(job, node.id, index=i) for i in range(n_before)])
+    return h, node, job
+
+
+def test_plan_write_closes_before_the_first_watcher_hears():
+    """`store.plan_write` is the write alone: its Sample is counted when
+    the first `_notify` of the commit arrives, `store.plan_notify` not
+    yet; after the call both are."""
+    from nomad_tpu.state.store import AppliedPlanResults
+    h, node, job = _store_with_allocs(0)
+    seen = []
+    h.store.watch(lambda table, obj: seen.append(
+        (table, _count("nomad.store.plan_write"),
+         _count("nomad.store.plan_notify"))))
+    w0 = _count("nomad.store.plan_write")
+    n0 = _count("nomad.store.plan_notify")
+    h.store.upsert_plan_results(h.next_index(), AppliedPlanResults(
+        allocs_to_place=[mock.alloc_for(job, node.id, index=i)
+                         for i in range(3)], plan_id="p-write"))
+    assert seen == [("allocs", w0 + 1, n0)] * 3
+    assert _count("nomad.store.plan_write") == w0 + 1
+    assert _count("nomad.store.plan_notify") == n0 + 1
+    assert _count("nomad.cpu.store.plan_write") >= 1
+
+
+def test_bucket_copy_count_is_the_stores_counter():
+    """One `store.bucket_copy` for each bucket `store.stats` counts as
+    copied: a commit after a snapshot copies the buckets it writes into,
+    a second commit in the same generation copies none of them again."""
+    from nomad_tpu.state.store import AppliedPlanResults
+    h, node, job = _store_with_allocs(4096)
+    fresh = iter(range(4096, 8192))
+
+    def commit(tag):
+        c0 = _count("nomad.store.bucket_copy")
+        s0 = h.store.stats["buckets_copied"]
+        h.store.upsert_plan_results(h.next_index(), AppliedPlanResults(
+            allocs_to_place=[mock.alloc_for(job, node.id, index=next(fresh))
+                             for _ in range(8)], plan_id=tag))
+        return (_count("nomad.store.bucket_copy") - c0,
+                h.store.stats["buckets_copied"] - s0)
+
+    h.store.snapshot()
+    spans, counted = commit("p-copy-1")
+    assert spans == counted >= 1
+    spans, counted = commit("p-copy-2")
+    assert spans == counted
+    h.store.snapshot()
+    spans, counted = commit("p-copy-3")
+    assert spans == counted >= 1
+
+
+def test_system_eval_pieces_sum_under_system_place():
+    """One system eval over a small full world: one `sched.system_settle`
+    a task group, one interval an eval for the loop's two pieces, and
+    settle + build + evict copies + the search inside `system_place`."""
+    import copy
+
+    from test_preempt_cell import _world
+    h, *_ = _world(7, 48)
+    job = mock.system_job(priority=50)
+    second = copy.deepcopy(job.task_groups[0])
+    second.name = "web2"
+    job.task_groups.append(second)
+    for tg in job.task_groups:
+        tg.tasks[0].resources.cpu = 1500
+        tg.tasks[0].resources.memory_mb = 1500
+    h.store.upsert_job(h.next_index(), job)
+    ev = mock.eval(job_id=job.id, type="system", priority=50,
+                   triggered_by="job-register")
+    h.store.upsert_evals(h.next_index(), [ev])
+    names = ("sched.system_place", "sched.system_settle",
+             "sched.system_build_alloc", "sched.system_evict_copy",
+             "sched.preempt_find")
+    c0 = {n: _count("nomad." + n) for n in names}
+    t0 = {n: _total_ms("nomad." + n) for n in names}
+    h.process("system", ev)
+    moved = {n: _count("nomad." + n) - c0[n] for n in names}
+    ms = {n: _total_ms("nomad." + n) - t0[n] for n in names}
+    assert 1 <= moved.pop("sched.preempt_find") <= 2    # one a group that asks
+    assert moved == {"sched.system_place": 1, "sched.system_settle": 2,
+                     "sched.system_build_alloc": 1,
+                     "sched.system_evict_copy": 1}
+    assert h.plans and h.plans[0].node_preemptions
+    assert ms["sched.system_evict_copy"] > 0.0
+    assert ms["sched.system_build_alloc"] > 0.0
+    assert sum(ms[n] for n in names[1:]) <= ms["sched.system_place"]
+    assert _count("nomad.cpu.sched.system_place") >= 1
+
+
 def test_profiler_session_shows_flat_work_spans(tmp_path):
     """A real `jax.profiler` session on the CPU, read back with
     ProfileData: the program's names are in /host:CPU, a wait span is
@@ -325,6 +513,47 @@ def test_profiler_session_shows_flat_work_spans(tmp_path):
     assert names == {"tprof.outer.a", "tprof.outer.b", "tprof.child"}
 
 
+def test_profiler_session_shows_a_collection_by_name(tmp_path):
+    """A real `jax.profiler` session: a full collection inside an open
+    span is `gc.collect.gen2` in the thread's /host:CPU line, between
+    two pieces of the span it fell in and overlapping neither."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tracing.span("tprofgc.warm"):
+        pass            # resolves the annotation class: a callback never imports
+    tracing.watch_gc(True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.span("tprofgc.outer"):
+            time.sleep(0.002)
+            gc.collect(2)
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+        tracing.watch_gc(False)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            mine = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                          for e in line.events
+                          if e.name in ("tprofgc.outer", "gc.collect.gen2"))
+            if any(n == "tprofgc.outer" for _s, _e, n in mine):
+                found.append(mine)
+    (mine,) = found
+    names = [n for _s, _e, n in mine]
+    assert names[0] == names[-1] == "tprofgc.outer"
+    assert "gc.collect.gen2" in names
+    for (_s0, e0, n0), (s1, _e1, n1) in zip(mine, mine[1:]):
+        assert e0 <= s1, (n0, n1, e0, s1)
+
+
 # ------------------------------------------------- one job on a dev agent
 
 # every span of README's table that one registered job on a dev agent
@@ -343,11 +572,39 @@ SPINE_SPANS = (
     "native.format_uuids")
 
 
-@pytest.mark.parametrize("name", SPINE_SPANS)
+# the spans with `cpu=True`: each also writes `nomad.cpu.<name>`
+CPU_SPANS = (
+    "sched.reconcile", "sched.feasible", "sched.materialise",
+    "sched.system_diff", "sched.system_place", "plan.evaluate",
+    "plan.flatten", "store.plan_write", "engine.stack", "engine.put",
+    "engine.resolve")
+
+# what the fixture's second stage adds (a system job that evicts, the
+# read that confirms it, a collection of each generation) and the
+# commit's phases, which every plan opens
+LATER_SPANS = (
+    "plan.flatten", "store.plan_write", "store.plan_notify",
+    "store.bucket_copy", "rpc.Job.Allocations",
+    "worker.invoke_scheduler.system", "sched.system_diff",
+    "sched.system_place", "sched.system_settle",
+    "sched.system_build_alloc", "sched.system_evict_copy",
+    "sched.preempt_find", "gc.collect.gen0", "gc.collect.gen1",
+    "gc.collect.gen2") + tuple("cpu." + n for n in CPU_SPANS)
+
+
+@pytest.mark.parametrize("name", SPINE_SPANS + LATER_SPANS)
 def test_span_is_in_v1_metrics_after_one_job(spine_metrics, name):
-    moved = spine_metrics["samples"].get("nomad." + name, {"count": 0})[
+    moved = spine_metrics["later"].get("nomad." + name, {"count": 0})[
         "count"] - spine_metrics["before"].get("nomad." + name, 0)
-    assert moved >= 1, sorted(spine_metrics["samples"])
+    assert moved >= 1, sorted(spine_metrics["later"])
+
+
+def test_system_job_of_the_fixture_evicted(spine_metrics):
+    """The second stage is what it says: every node got the system
+    job's allocation, and some took their room by eviction."""
+    allocs = spine_metrics["fleet_allocs"]
+    assert len({a["NodeID"] for a in allocs}) == 3
+    assert spine_metrics["evicted"] >= 1
 
 
 def test_invoke_scheduler_count_is_evals_processed(spine_metrics):
