@@ -195,7 +195,9 @@ class StateStore:
         # because a snapshot shared them
         self.stats: Dict[str, int] = {
             "snapshots": 0, "roots_copied": 0, "buckets_copied": 0,
-            "sets_copied": 0}
+            "sets_copied": 0,
+            # placements the plan-apply duplicate-name guard dropped
+            "name_guard_drops": 0}
         # the seven tables a snapshot hands out
         self._nodes = Table(FANOUT, self.stats)             # id -> Node
         self._jobs = Table(FANOUT, self.stats)              # (ns, id) -> Job
@@ -207,11 +209,15 @@ class StateStore:
         self._allocs_by_job = IndexTable(FANOUT, self.stats)  # (ns, id) -> alloc ids
         self._allocs_by_node = IndexTable(FANOUT, self.stats)  # node id -> alloc ids
         self._allocs_by_eval: Dict[str, Set[str]] = defaultdict(set)
-        # derived, never serialized: (namespace, job_id, name) -> ids of
-        # non-terminal allocs holding that name (the plan-apply
-        # duplicate-name guard reads it per placement, so it must be
-        # O(1), not a scan of the job's alloc set)
-        self._live_names: Dict[Tuple[str, str, str], Set[str]] = {}
+        # derived, never serialized: (namespace, job_id, name) -> node id
+        # -> ids of the non-terminal allocs holding that name there.  The
+        # plan-apply duplicate-name guard reads it per placement in both
+        # of its scopes by one lookup: "is the name held at all" (service,
+        # batch) is the outer entry, "is it held on this node" (system,
+        # sysbatch: one name, one alloc a node) the inner one; neither may
+        # be a scan of the job's allocs or of the name's holders
+        self._live_names: Dict[Tuple[str, str, str],
+                               Dict[str, Set[str]]] = {}
         self._evals_by_job: Dict[Tuple[str, str], Set[str]] = defaultdict(set)
         self.scheduler_config = SchedulerConfiguration()
         # namespaces table (reference nomad/state/schema.go namespaces)
@@ -755,7 +761,8 @@ class StateStore:
             self._live_name_unset(a)
         else:
             self._live_names.setdefault(
-                (a.namespace, a.job_id, a.name), set()).add(a.id)
+                (a.namespace, a.job_id, a.name), {}).setdefault(
+                    a.node_id, set()).add(a.id)
 
     @requires_lock("_lock")
     def _reindex_applied_plan_ids_locked(self) -> None:
@@ -806,10 +813,16 @@ class StateStore:
     @requires_lock("_lock")
     def _live_name_unset(self, a: Allocation) -> None:
         key = (a.namespace, a.job_id, a.name)
-        ids = self._live_names.get(key)
-        if ids is not None:
-            ids.discard(a.id)
-            if not ids:
+        by_node = self._live_names.get(key)
+        if by_node is None:
+            return
+        ids = by_node.get(a.node_id)
+        if ids is None:
+            return
+        ids.discard(a.id)
+        if not ids:
+            del by_node[a.node_id]
+            if not by_node:
                 del self._live_names[key]
 
     @requires_lock("_lock")
@@ -1258,18 +1271,16 @@ class StateStore:
             # terminal alloc, so a live holder here is always a racer.
             # Updates of existing allocs (same id) always apply.  System
             # and sysbatch allocs all share one name by design (one per
-            # node), so their duplicates are scoped to the node.
+            # node), so their duplicates are scoped to the node: the
+            # index's inner key, a lookup like the outer one.
             if a.id not in self._allocs:
                 holders = self._live_names.get(
                     (a.namespace, a.job_id, a.name))
                 if holders:
                     per_node = a.job is not None and \
                         a.job.type in ("system", "sysbatch")
-                    if not per_node:
-                        continue
-                    if any(o is not None and o.node_id == a.node_id
-                           for o in (self._allocs.get(i)
-                                     for i in holders)):
+                    if not per_node or a.node_id in holders:
+                        self.stats["name_guard_drops"] += 1
                         continue
                 # quota guard: the authoritative, replica-deterministic
                 # admission check.  The applier already checked at propose

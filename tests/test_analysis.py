@@ -531,22 +531,39 @@ def test_restore_rebuilds_derived_indexes_like_a_live_store():
     live_a = mock.alloc_for(job, node.id)
     dead_a = mock.alloc_for(job, node.id, index=1,
                             client_status=AllocClientStatus.COMPLETE)
+    # a system job's allocations share one name: the index's inner key,
+    # the node, is what tells them apart
+    node2 = mock.node()
+    sys_job = mock.system_job(submit_time=1.0)
+    live_s = mock.alloc_for(sys_job, node.id)
+    dead_s = mock.alloc_for(sys_job, node2.id,
+                            client_status=AllocClientStatus.FAILED)
     log = [
         (1, MessageType.NODE_REGISTER, {"node": node}),
         (2, MessageType.JOB_REGISTER, {"job": job}),
         (3, MessageType.ALLOC_UPDATE, {"allocs": [live_a, dead_a]}),
+        (4, MessageType.NODE_REGISTER, {"node": node2}),
+        (5, MessageType.JOB_REGISTER, {"job": sys_job}),
+        (6, MessageType.ALLOC_UPDATE, {"allocs": [live_s, dead_s]}),
     ]
     live = NomadFSM(StateStore())
     for index, msg_type, payload in copy.deepcopy(log):
         live.apply(index, msg_type, payload)
     restored = NomadFSM(StateStore())
+    # onto a used store: a holder the snapshot does not have must not
+    # survive the restore
+    restored.store.upsert_allocs(1, [mock.alloc_for(sys_job, node2.id)])
     restored.restore(live.snapshot())
     ls, rs = live.store, restored.store
     for table in ("_allocs_by_job", "_allocs_by_node", "_allocs_by_eval",
                   "_evals_by_job", "_services_by_alloc"):
         assert dict(getattr(ls, table)) == dict(getattr(rs, table)), table
     assert ls._live_names == rs._live_names
-    assert all(dead_a.id not in ids for ids in rs._live_names.values())
+    # the two live allocations and nothing else: no terminal one, and
+    # not the holder the restore wrote over
+    assert rs._live_names == {
+        ("default", job.id, live_a.name): {node.id: {live_a.id}},
+        ("default", sys_job.id, live_s.name): {node.id: {live_s.id}}}
     assert set(ls._acl_by_secret) == set(rs._acl_by_secret)
     assert ls._applied_plan_ids_set == rs._applied_plan_ids_set
 

@@ -35,7 +35,8 @@ from nomad_tpu.core.worker import TRANSIENT_ERRORS, RemoteWorker
 from nomad_tpu.raft import RaftConfig
 from nomad_tpu.rpc.endpoints import RpcError
 from nomad_tpu.state.store import AppliedPlanResults, StateStore
-from nomad_tpu.structs import EvalStatus, Evaluation
+from nomad_tpu.structs import (AllocClientStatus, AllocDesiredStatus,
+                               EvalStatus, Evaluation)
 from nomad_tpu.structs.node import NodeStatus
 from nomad_tpu.structs.plan import Plan
 from nomad_tpu.utils import generate_uuid
@@ -313,6 +314,131 @@ def test_store_drops_placement_duplicating_live_name():
     assert store.alloc_by_id(s1.id) is not None
     assert store.alloc_by_id(s2.id) is not None
     assert store.alloc_by_id(s3.id) is None
+
+
+def _plan(store, index, **lists):
+    store.upsert_plan_results(index, AppliedPlanResults(
+        eval_id="e", plan_id=generate_uuid(), **lists))
+
+
+def _guard_racer_in_a_later_plan(store, sj, node, node2):
+    s1, s2 = (mock.alloc_for(sj, node_id=node.id) for _ in range(2))
+    _plan(store, 10, allocs_to_place=[s1])
+    _plan(store, 11, allocs_to_place=[s2])
+    return [s1], [s2]
+
+
+def _guard_same_name_on_another_node(store, sj, node, node2):
+    s1 = mock.alloc_for(sj, node_id=node.id)
+    s2 = mock.alloc_for(sj, node_id=node2.id)
+    _plan(store, 10, allocs_to_place=[s1])
+    _plan(store, 11, allocs_to_place=[s2])
+    return [s1, s2], []
+
+
+def _guard_racer_inside_one_plan(store, sj, node, node2):
+    s1 = mock.alloc_for(sj, node_id=node.id)
+    s2 = mock.alloc_for(sj, node_id=node2.id)
+    s3 = mock.alloc_for(sj, node_id=node.id)
+    s4 = mock.alloc_for(sj, node_id=node2.id)
+    _plan(store, 10, allocs_to_place=[s1, s2, s3, s4])
+    return [s1, s2], [s3, s4]
+
+
+def _guard_holder_stopped_by_the_same_plan(store, sj, node, node2):
+    old = mock.alloc_for(sj, node_id=node.id)
+    keeps = mock.alloc_for(sj, node_id=node2.id)
+    _plan(store, 10, allocs_to_place=[old, keeps])
+    stopped = old.copy()
+    stopped.desired_status = AllocDesiredStatus.STOP
+    repl = mock.alloc_for(sj, node_id=node.id)
+    racer = mock.alloc_for(sj, node_id=node2.id)    # its holder stays
+    _plan(store, 11, alloc_updates=[stopped], allocs_to_place=[repl, racer])
+    assert store.alloc_by_id(old.id).desired_status == AllocDesiredStatus.STOP
+    return [repl, keeps], [racer]
+
+
+def _guard_holder_evicted_by_an_earlier_plan(store, sj, node, node2):
+    old = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 10, allocs_to_place=[old])
+    evicted = old.copy()
+    evicted.desired_status = AllocDesiredStatus.EVICT
+    _plan(store, 11, allocs_preempted=[evicted])
+    repl = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 12, allocs_to_place=[repl])
+    return [repl], []
+
+
+def _guard_holder_terminal_by_client_status(store, sj, node, node2):
+    old = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 10, allocs_to_place=[old])
+    done = old.copy()
+    done.client_status = AllocClientStatus.FAILED
+    store.update_allocs_from_client(11, [done])
+    repl = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 12, allocs_to_place=[repl])
+    return [repl], []
+
+
+def _guard_update_of_an_existing_id(store, sj, node, node2):
+    s1 = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 10, allocs_to_place=[s1])
+    upd = s1.copy()
+    upd.deployment_id = "d-join"
+    _plan(store, 11, allocs_to_place=[upd])
+    assert store.alloc_by_id(s1.id).deployment_id == "d-join"
+    return [s1], []
+
+
+def _guard_holder_gone_by_gc(store, sj, node, node2):
+    old = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 10, allocs_to_place=[old])
+    with store._lock:
+        store._drop_alloc(old.id)
+    repl = mock.alloc_for(sj, node_id=node.id)
+    _plan(store, 11, allocs_to_place=[repl])
+    return [repl], []
+
+
+_GUARD_CASES = [
+    _guard_racer_in_a_later_plan, _guard_same_name_on_another_node,
+    _guard_racer_inside_one_plan, _guard_holder_stopped_by_the_same_plan,
+    _guard_holder_evicted_by_an_earlier_plan,
+    _guard_holder_terminal_by_client_status,
+    _guard_update_of_an_existing_id, _guard_holder_gone_by_gc]
+
+
+@pytest.mark.parametrize("make_job", [mock.system_job, mock.sysbatch_job],
+                         ids=["system", "sysbatch"])
+@pytest.mark.parametrize(
+    "case", _GUARD_CASES,
+    ids=[c.__name__[len("_guard_"):] for c in _GUARD_CASES])
+def test_store_scopes_a_system_jobs_name_guard_to_the_node(case, make_job):
+    """A system or sysbatch job's allocations share one name, one a
+    node: the guard drops a second live holder on the SAME node, and
+    only that, and `stats["name_guard_drops"]` counts each drop.  Each
+    case returns the allocations that must hold their node's entry of
+    the liveness index and the placements that must be gone."""
+    store = StateStore()
+    node, node2 = mock.node(), mock.node()
+    store.upsert_node(1, node)
+    store.upsert_node(2, node2)
+    sj = make_job()
+    # bystanders under the same name on nodes of their own: a holder
+    # elsewhere neither drops a placement nor hides a racer
+    for i in range(3):
+        n = mock.node()
+        store.upsert_node(3 + i, n)
+        _plan(store, 6 + i, allocs_to_place=[mock.alloc_for(sj, node_id=n.id)])
+    live, dropped = case(store, sj, node, node2)
+    for a in dropped:
+        assert store.alloc_by_id(a.id) is None
+    assert store.stats["name_guard_drops"] == len(dropped)
+    by_node = store._live_names[(sj.namespace, sj.id, live[0].name)]
+    for a in live:
+        assert store.alloc_by_id(a.id) is not None
+        assert by_node[a.node_id] == {a.id}
+    assert len(by_node) == 3 + len(live)
 
 
 def test_store_allows_same_name_when_holder_stops_in_same_plan():
