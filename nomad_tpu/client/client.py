@@ -14,7 +14,7 @@ import tempfile
 import threading
 import time
 import uuid
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Callable, Dict, List, Optional
 
 from nomad_tpu.client.allocrunner import AllocRunner
@@ -376,7 +376,11 @@ class Client:
         u = ar.alloc.copy()
         u.client_status = ar.client_status
         u.client_description = ar.client_description
-        u.task_states = {n: s for n, s in ar.task_states().items()}
+        # the states as they read now, not the runners' live objects: with
+        # in-process RPC the store keeps what it is handed, and a stored
+        # allocation's parts are never written in place
+        u.task_states = {n: replace(s, events=list(s.events))
+                         for n, s in ar.task_states().items()}
         u.job = None                        # strip for wire size
         if ar.deployment_healthy is not None:
             u.deployment_status = {"healthy": ar.deployment_healthy,

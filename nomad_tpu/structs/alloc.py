@@ -229,6 +229,15 @@ class Allocation:
         import copy as _copy
         return _copy.deepcopy(self)
 
+    def copy_shallow(self) -> "Allocation":
+        """A new record whose fields are this one's own objects (reference
+        `*newAlloc = *alloc`): for a caller that only sets top-level
+        fields on the copy.  The parts (job, resources, metrics, task
+        states, ...) stay shared, which holds because nothing changes a
+        stored allocation's parts in place; use copy() to edit one."""
+        import copy as _copy
+        return _copy.copy(self)
+
 
 def alloc_name(job_id: str, group: str, index: int) -> str:
     return f"{job_id}.{group}[{index}]"

@@ -57,7 +57,7 @@ class Plan:
     def append_stopped_alloc(self, alloc: Allocation, desired_desc: str,
                              client_status: str = "", followup_eval_id: str = "") -> None:
         """Reference Plan.AppendStoppedAlloc."""
-        a = alloc.copy()
+        a = alloc.copy_shallow()
         a.desired_status = AllocDesiredStatus.STOP
         a.desired_description = desired_desc
         if client_status:
@@ -76,7 +76,7 @@ class Plan:
         self.node_allocation.setdefault(alloc.node_id, []).append(alloc)
 
     def append_preempted_alloc(self, alloc: Allocation, preempting_alloc_id: str) -> None:
-        a = alloc.copy()
+        a = alloc.copy_shallow()
         a.desired_status = AllocDesiredStatus.EVICT
         a.preempted_by_allocation = preempting_alloc_id
         a.desired_description = (f"Preempted by alloc ID {preempting_alloc_id}")

@@ -224,6 +224,39 @@ def test_e2e_service_job_runs_and_stops(cluster):
          for a in server.store.allocs_by_job("default", job.id)]
 
 
+def test_e2e_the_store_keeps_no_live_task_state(cluster):
+    """In process the server stores the objects the client hands it, and
+    a plan's copy of a record shares the record's parts: so an update
+    carries the task states as they read, not the runners' own objects,
+    which the runners go on writing."""
+    server, client = cluster
+    job = Job(id="svc-alias", name="svc", type="service",
+              task_groups=[TaskGroup(name="g", count=1, tasks=[
+                  Task(name="t", driver="mock_driver",
+                       config={"run_for": 0})])])
+    job.canonicalize()
+    server.register_job(job)
+    assert _wait(lambda: [
+        a for a in server.store.allocs_by_job("default", job.id)
+        if a.client_status == "running"
+        and a.task_states["t"].state == "running"], 15.0)
+    (held,) = server.store.allocs_by_job("default", job.id)
+    ts = held.task_states["t"]
+    events = list(ts.events)
+    runner = client.alloc_runners[held.id].task_runners["t"]
+    assert ts is not runner.state and ts.events is not runner.state.events
+    server.deregister_job("default", job.id)
+    assert _wait(lambda: all(
+        a.client_terminal_status()
+        for a in server.store.allocs_by_job("default", job.id)), 15.0)
+    assert runner.state.state == "dead"
+    (now,) = server.store.allocs_by_job("default", job.id)
+    assert now.task_states["t"].state == "dead"
+    # what a reader held from before the stop reads as it did
+    assert held.client_status == "running"
+    assert ts.state == "running" and ts.events == events
+
+
 def test_e2e_failed_task_restarts_then_reschedules(cluster):
     server, client = cluster
     job = Job(id="fail-e2e", name="f", type="batch",

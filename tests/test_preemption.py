@@ -414,3 +414,64 @@ def test_a_search_counts_its_passes():
     # the search is a child of the find: the find has self time to report
     assert after.get("nomad.self.sched.preempt_find", 0) \
         == before.get("nomad.self.sched.preempt_find", 0) + 1
+
+
+def test_a_preempting_plan_leaves_three_servers_equal_by_value():
+    """The plan's entry for an evicted allocation shares its parts with
+    the leader's stored record; the log carries an encoding of it, so
+    every server's objects are its own and all read alike."""
+    import time
+
+    from nomad_tpu.core.cluster import Cluster
+
+    def wait(cond, timeout=30.0):
+        deadline = time.time() + timeout
+        while time.time() < deadline and not cond():
+            time.sleep(0.05)
+        return cond()
+
+    c = Cluster(n=3)
+    c.start()
+    try:
+        leader = c.leader(10.0)
+        for _ in range(2):
+            leader.register_node(mock.node())
+        svc = mock.job(priority=50)
+        svc.task_groups[0].tasks[0].resources.cpu = 3500
+        svc.task_groups[0].count = 2
+        leader.endpoints.handle("Job.Register", {"job": svc})
+        assert wait(lambda: len(
+            leader.store.allocs_by_job("default", svc.id)) == 2)
+        sysj = mock.system_job()            # priority 100: preempts
+        sysj.task_groups[0].tasks[0].resources.cpu = 1000
+        leader.endpoints.handle("Job.Register", {"job": sysj})
+
+        def evicted(store):
+            return [a for a in store.allocs_by_job("default", svc.id)
+                    if a.desired_status == AllocDesiredStatus.EVICT]
+
+        assert wait(lambda: len(evicted(leader.store)) == 2)
+        assert wait(lambda: all(
+            len(evicted(s.store)) == 2 and
+            len(s.store.allocs_by_job("default", sysj.id)) == 2
+            for s in c.servers))
+        # later entries (the follow-up evals) touch none of these records
+        assert c.wait_replication(leader.store.latest_index)
+        want = {a.id: a for a in leader.store.allocs()
+                if a.job_id in (svc.id, sysj.id)}
+        placed = {a.id: a for a in want.values() if a.job_id == sysj.id}
+        assert len(want) == 4 and len(placed) == 2
+        for a in evicted(leader.store):
+            assert a.preempted_by_allocation in placed
+            assert placed[a.preempted_by_allocation].preempted_allocations \
+                == [a.id]
+            assert a.job is not None and a.job.id == svc.id   # restored
+        for s in c.followers():
+            got = {a.id: a for a in s.store.allocs() if a.id in want}
+            assert got == want
+            assert all(got[i] is not want[i] and
+                       got[i].metrics is not want[i].metrics and
+                       got[i].allocated_resources is not
+                       want[i].allocated_resources for i in want)
+    finally:
+        c.stop()

@@ -2,7 +2,9 @@
 (state/table.py), not a copy of them: what it answers is held here to a
 plain copy made at the same index, whatever is written later, and what
 taking it and writing past it cost is counted, not timed."""
+import copy
 import gc
+import pickle
 import random
 import sys
 import threading
@@ -10,12 +12,14 @@ import threading
 import pytest
 
 from nomad_tpu import mock
-from nomad_tpu.raft.fsm import NomadFSM
+from nomad_tpu.raft.fsm import MessageType, NomadFSM
 from nomad_tpu.state import StateStore
 from nomad_tpu.state.store import AppliedPlanResults
 from nomad_tpu.state.table import IndexTable, Table
 from nomad_tpu.structs import AllocClientStatus, AllocDesiredStatus
+from nomad_tpu.structs.alloc import TaskState
 from nomad_tpu.structs.deployment import Deployment, DeploymentStatus
+from nomad_tpu.structs.plan import Plan
 from nomad_tpu.utils import generate_uuid
 
 
@@ -564,6 +568,155 @@ def test_restore_refills_the_tables_and_spares_held_snapshots():
     again.restore(blob)
     from nomad_tpu.state.digest import canon
     assert canon(again.snapshot()) == canon(blob) == canon(fsm.snapshot())
+
+
+# ------------------ a plan's entries share their parts with the records
+
+def _evicting_plan(store: StateStore):
+    """`_fleet(2, 1)` with both fillers running on their clients, and a
+    plan that evicts the first node's for a system job's placement and
+    stops the second's.  -> (plan, its payload, evicted, stopped, placed)"""
+    fillers = sorted(store.allocs(), key=lambda a: a.name)
+    running = []
+    for f in fillers:
+        u = f.copy()
+        u.client_status = AllocClientStatus.RUNNING
+        u.task_states = {"web": TaskState(state="running", started_at=1.0,
+                                          events=[{"type": "Started"}])}
+        running.append(u)
+    store.update_allocs_from_client(4, running)
+    evicted, stopped = (store.alloc_by_id(f.id) for f in fillers)
+    sj = mock.system_job()
+    store.upsert_job(5, sj)
+    placed = mock.alloc_for(sj, node_id=evicted.node_id)
+    placed.preempted_allocations = [evicted.id]
+    plan = Plan(job=sj)
+    plan.append_preempted_alloc(evicted, placed.id)
+    plan.append_stopped_alloc(stopped, "alloc not needed due to job update",
+                              followup_eval_id="eval-17")
+    plan.append_alloc(placed, None)
+    applied = AppliedPlanResults(
+        alloc_updates=[a for v in plan.node_update.values() for a in v],
+        allocs_to_place=[a for v in plan.node_allocation.values() for a in v],
+        allocs_preempted=[a for v in plan.node_preemptions.values()
+                          for a in v],
+        plan_id=plan.plan_id)
+    return plan, applied, evicted, stopped, placed
+
+
+def test_a_snapshot_keeps_the_record_a_plan_evicts_or_stops():
+    """The plan's entry becomes the stored record and shares its parts
+    with the record it replaces, which a snapshot still holds: the
+    snapshot reads `run` at its old index, field for field what it read
+    before the commit, and a client's later update of the evicted
+    allocation reaches neither it nor the plan's entry."""
+    store = _fleet(2, 1)
+    plan, applied, evicted, stopped, placed = _evicting_plan(store)
+    snap, frozen = store.snapshot(), Frozen(store)
+    universe = {name: set(getattr(frozen, name))
+                for name in ("nodes", "jobs", "evals", "allocs",
+                             "deployments")}
+    universe["allocs"].add(placed.id)
+    universe["by_job"] = set(frozen.by_job) | {("default", placed.job_id)}
+    universe["by_node"] = set(frozen.by_node)
+    before = {a.id: copy.deepcopy(a) for a in (evicted, stopped)}
+    jobs = {a.id: a.job for a in (evicted, stopped)}
+    store.upsert_plan_results(6, applied)
+    hold(snap, frozen, universe)
+    for old in (evicted, stopped):
+        assert snap.allocs[old.id] is old
+        assert old == before[old.id]            # every field, job included
+        assert old.desired_status == AllocDesiredStatus.RUN
+        assert old.modify_index == 4 and old.job is jobs[old.id]
+    (evict_entry,), (stop_entry,) = (plan.node_preemptions[evicted.node_id],
+                                     plan.node_update[stopped.node_id])
+    live = store.alloc_by_id(evicted.id)
+    assert live is evict_entry and live is not evicted
+    assert live.desired_status == AllocDesiredStatus.EVICT
+    assert live.preempted_by_allocation == placed.id
+    assert live.job is jobs[evicted.id] and live.modify_index == 6
+    assert live.create_index == evicted.create_index
+    assert live.task_states is evicted.task_states      # shared, not copied
+    live = store.alloc_by_id(stopped.id)
+    assert live is stop_entry
+    assert live.desired_status == AllocDesiredStatus.STOP
+    assert live.followup_eval_id == "eval-17"
+    assert live.job is jobs[stopped.id]
+    assert live.client_status == AllocClientStatus.RUNNING
+    # the client reports the evicted allocation's end
+    entry_was = copy.deepcopy(evict_entry)
+    u = evict_entry.copy()
+    u.client_status = AllocClientStatus.COMPLETE
+    u.task_states = {"web": TaskState(state="dead", finished_at=2.0,
+                                      events=[{"type": "Killed"}])}
+    store.update_allocs_from_client(7, [u])
+    now = store.alloc_by_id(evicted.id)
+    assert now is not evict_entry
+    assert now.client_status == AllocClientStatus.COMPLETE
+    assert now.desired_status == AllocDesiredStatus.EVICT
+    assert now.task_states["web"].state == "dead" and now.modify_index == 7
+    assert evict_entry == entry_was
+    assert evict_entry.task_states["web"].state == "running"
+    assert evicted == before[evicted.id]
+    assert evicted.task_states["web"].events == [{"type": "Started"}]
+    hold(snap, frozen, universe)
+
+
+def _by_value(store: StateStore) -> dict:
+    with store._lock:
+        return {"index": store.latest_index,
+                "allocs": dict(store._allocs.items()),
+                "jobs": dict(store._jobs.items()),
+                "by_node": {k: set(v)
+                            for k, v in store._allocs_by_node.items()},
+                "live_names": {k: {n: set(ids) for n, ids in v.items()}
+                               for k, v in store._live_names.items()},
+                "used": store.matrix.used.copy().tolist()}
+
+
+def test_entries_that_share_parts_go_through_the_log_and_a_snapshot():
+    """One store applies the plan's own objects (a dev agent: the
+    applier hands them over by reference), two the raft payload's
+    encoding of them (the servers of a cluster, each its own decoding);
+    then the first is saved and restored.  All four read the same, value
+    for value; the two that applied the encoding, and the restored one
+    beside the blob it came from, the same bytes a table."""
+    from nomad_tpu.state.digest import canon
+    leader = _fleet(2, 1)
+    plan, applied, evicted, stopped, placed = _evicting_plan(leader)
+    blob = NomadFSM(leader).snapshot()
+    follower, other = StateStore(), StateStore()
+    NomadFSM(follower).restore(blob)
+    NomadFSM(other).restore(blob)
+    assert _by_value(follower) == _by_value(leader)
+    payload = {"results": applied}
+    wire = pickle.loads(pickle.dumps(payload))      # raft/node.py `apply`
+    again = pickle.loads(pickle.dumps(payload))
+    entry = wire["results"].allocs_preempted[0]
+    assert entry.job is None and entry is not applied.allocs_preempted[0]
+    assert entry == applied.allocs_preempted[0]
+    NomadFSM(leader).apply(6, MessageType.APPLY_PLAN_RESULTS, payload)
+    NomadFSM(follower).apply(6, MessageType.APPLY_PLAN_RESULTS, wire)
+    NomadFSM(other).apply(6, MessageType.APPLY_PLAN_RESULTS, again)
+    assert leader.alloc_by_id(evicted.id) is applied.allocs_preempted[0]
+    want = _by_value(leader)
+    assert _by_value(follower) == want == _by_value(other)
+    assert canon(NomadFSM(follower).snapshot()) == \
+        canon(NomadFSM(other).snapshot())
+    assert want["allocs"][evicted.id].desired_status == \
+        AllocDesiredStatus.EVICT
+    assert want["allocs"][stopped.id].desired_status == \
+        AllocDesiredStatus.STOP
+    assert want["allocs"][evicted.id].job == evicted.job
+    assert want["allocs"][placed.id].preempted_allocations == [evicted.id]
+    saved = NomadFSM(leader).snapshot()
+    restored = StateStore()
+    NomadFSM(restored).restore(saved)
+    assert _by_value(restored) == want
+    assert canon(NomadFSM(restored).snapshot()) == canon(saved)
+    # the old records a reader may still hold were not written to
+    assert evicted.desired_status == stopped.desired_status == \
+        AllocDesiredStatus.RUN
 
 
 # ------------------------------------------------------ the tables alone

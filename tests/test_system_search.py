@@ -344,3 +344,99 @@ def test_a_sysbatch_job_takes_the_same_path(preempts):
     else:
         assert searched == [0, 0, 0] and not got["evicted"]
         assert sched._preemptor is None and got["queued"]["g0"] > 0
+
+
+# ----------------- the plan's own copies: shared parts, the same plan
+
+def _whole_plan(h, job, monkeypatch, deep):
+    """The `Plan` object the system scheduler makes of `job`, not
+    applied, with the ids it gives out counted from zero and, for
+    `deep`, the plan's copies made as they were until PR 45: a deep copy
+    of the record, its job and its metrics, the job then dropped."""
+    import itertools
+
+    from nomad_tpu.scheduler import placement
+    from nomad_tpu.structs.alloc import Allocation
+
+    ids = itertools.count()
+    with monkeypatch.context() as m:
+        m.setattr(placement, "generate_uuid",
+                  lambda: f"00000000-0000-4000-8000-{next(ids):012x}")
+        if deep:
+            m.setattr(Allocation, "copy_shallow", Allocation.copy)
+        h.plans.clear()
+        h.reject_plan = True
+        sched = system.SystemScheduler(h.store.snapshot(), h)
+        sched.process(mock.eval(id="eval-of-both", job_id=job.id,
+                                type=job.type, priority=job.priority))
+        h.reject_plan = False
+    (plan,) = h.plans
+    h.plans.clear()
+    for allocs in plan.node_allocation.values():
+        for a in allocs:            # the one thing the clock decides
+            a.create_time = a.modify_time = 0.0
+    return plan
+
+
+def _fleet_that_evicts(seed):
+    h, rows, cap, used, res, prio, alive = _world(seed, 96)
+    return h, _job(h, "system", [_ask_for(cap - used, 1)])
+
+
+def _update_and_a_lost_node(seed):
+    """A system job on 8 nodes; then one node goes down and the job's
+    task changes: one `lost`, seven stopped for the update."""
+    from nomad_tpu.structs.node import NodeStatus
+    h = Harness()
+    nodes = [mock.node() for _ in range(8)]
+    for node in nodes:
+        h.store.upsert_node(h.next_index(), node)
+    job = _job(h, "system", [(1000, 256)])
+    h.process("system", mock.eval(job_id=job.id, type="system"))
+    assert len(h.store.allocs_by_job("default", job.id)) == 8
+    h.store.update_node_status(h.next_index(), nodes[seed % 8].id,
+                               NodeStatus.DOWN)
+    new = job.copy()
+    new.task_groups[0].tasks[0].config = {"command": "/bin/true"}
+    h.store.upsert_job(h.next_index(), new)
+    return h, new
+
+
+@pytest.mark.parametrize("seed", [3, 2147483659])
+@pytest.mark.parametrize("world", [_fleet_that_evicts,
+                                   _update_and_a_lost_node],
+                         ids=lambda w: w.__name__[1:])
+@time_limit(120)
+def test_shared_parts_change_no_plan(world, seed, monkeypatch):
+    """The fleet eval at the tests' scale, its ids counted from zero,
+    makes the plan it made when every stopped or evicted allocation was
+    deep-copied: the same placements under the same ids, the same
+    `node_preemptions` and `node_update` value for value, the same
+    `score_meta`.  And it leaves the records it read as they were."""
+    import copy
+    h, job = world(seed)
+    records = {a.id: a for a in h.store.allocs()}
+    before = copy.deepcopy(records)
+    want = _whole_plan(h, job, monkeypatch, deep=True)
+    got = _whole_plan(h, job, monkeypatch, deep=False)
+    assert got.node_allocation == want.node_allocation
+    assert got.node_preemptions == want.node_preemptions
+    assert got.node_update == want.node_update
+    assert [a.id for v in got.node_allocation.values() for a in v] == \
+        [a.id for v in want.node_allocation.values() for a in v]
+    assert [a.metrics.score_meta
+            for v in got.node_allocation.values() for a in v] == \
+        [a.metrics.score_meta
+         for v in want.node_allocation.values() for a in v]
+    gone = [a for v in list(got.node_preemptions.values())
+            + list(got.node_update.values()) for a in v]
+    if world is _fleet_that_evicts:
+        assert len(got.node_preemptions) >= 12 and not got.node_update
+    else:
+        assert sorted(a.client_status == AllocClientStatus.LOST
+                      for a in gone) == [False] * 7 + [True]
+    for a in gone:
+        assert a is not records[a.id] and a.job is None
+        assert a.metrics is records[a.id].metrics
+    assert {a.id: a for a in h.store.allocs()} == before
+    assert all(a is records[a.id] for a in h.store.allocs())
