@@ -81,7 +81,10 @@ class PlanApplier:
         self.stats = {"applied": 0, "rejected_nodes": 0, "partial": 0,
                       "pipelined": 0,
                       # allocations committed: placed, and evicted for them
-                      "placed": 0, "preempted": 0}
+                      "placed": 0, "preempted": 0,
+                      # plan nodes whose placements hold ports, and those
+                      # of them refused because a port was taken
+                      "port_nodes": 0, "port_rejected_nodes": 0}
 
     # ------------------------------------------------------------- public
 
@@ -284,7 +287,7 @@ class PlanApplier:
             vec = np.zeros(NUM_RESOURCE_DIMS, np.float32)
             for a in allocs:
                 vec += comparable_vec(a.comparable_resources())
-                port_claim.setdefault(row, set()).update(_alloc_ports(a))
+                port_claim.setdefault(row, set()).update(a.ports())
             used_delta[row] = used_delta.get(
                 row, np.zeros(NUM_RESOURCE_DIMS, np.float32)) + vec
         # NOTE: stops/preemptions are deliberately NOT overlaid.  The
@@ -376,7 +379,7 @@ class PlanApplier:
                     continue   # already free in committed state
                 cr = src.comparable_resources()
                 vec += comparable_vec(cr)
-                ports.update(_alloc_ports(src))
+                ports.update(src.ports())
             freed[node_id] = vec
             freed_ports[node_id] = ports
 
@@ -391,6 +394,7 @@ class PlanApplier:
         freed_vecs = np.zeros((g, NUM_RESOURCE_DIMS), np.float32)
         group_ports: List[List[int]] = []
         group_freed: List[List[int]] = []
+        ported: List[int] = []      # indexes of nodes whose placements hold ports
         for i, node_id in enumerate(node_ids):
             node = store.node_by_id(node_id)
             row = cm.row_of.get(node_id)
@@ -400,14 +404,22 @@ class PlanApplier:
             for a in plan.node_allocation[node_id]:
                 cr = a.comparable_resources()
                 demand[i] += comparable_vec(cr)
-                ports.extend(_alloc_ports(a))
+                ports.extend(a.ports())
             freed_vecs[i] = freed.get(node_id, 0.0)
             group_ports.append(ports)
             group_freed.append(sorted(freed_ports.get(node_id, ())))
+            if ports:
+                ported.append(i)
         used_eff, port_words_eff = self._overlay_views(cm)
         ok = _native.validate_plan(
             cm.capacity, used_eff, port_words_eff, rows, demand,
             freed_vecs, group_ports, group_freed) if g else []
+        self.stats["port_nodes"] += len(ported)
+        for i in ported:
+            if not ok[i] and rows[i] >= 0 and not _native.ports_check(
+                    port_words_eff, int(rows[i]), group_ports[i],
+                    group_freed[i]):
+                self.stats["port_rejected_nodes"] += 1
 
         rejected: List[str] = []
         # csi write-claim exclusion across concurrent plans (the reference
@@ -668,12 +680,3 @@ class PlanApplier:
             if chaos.active is not None:
                 chaos.fire("plan.crash_after_commit")
         self._post_commit(plan, result, applied, index)
-
-
-def _alloc_ports(a: Allocation) -> List[int]:
-    out = []
-    for net in a.comparable_resources().networks:
-        out += [p.value for p in net.reserved_ports if p.value]
-        out += [p.value for p in net.dynamic_ports if p.value]
-    out += [p.value for p in a.allocated_resources.shared_ports if p.value]
-    return out

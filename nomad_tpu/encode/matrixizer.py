@@ -245,19 +245,6 @@ class ClusterMatrix:
                 out[gid] = out.get(gid, 0) + len(d.get("device_ids", []))
         return out
 
-    @staticmethod
-    def _alloc_ports(alloc) -> Tuple[int, ...]:
-        ports = []
-        for net in alloc.comparable_resources().networks:
-            for p in net.reserved_ports:
-                ports.append(p.value)
-            for p in net.dynamic_ports:
-                if p.value:
-                    ports.append(p.value)
-        for p in alloc.allocated_resources.shared_ports:
-            ports.append(p.value)
-        return tuple(ports)
-
     def _untrack(self, alloc_id: str) -> None:
         node_id = self._alloc_node.pop(alloc_id, None)
         if node_id is None:
@@ -282,7 +269,7 @@ class ClusterMatrix:
         self._untrack(alloc.id)
         if not alloc.terminal_status() and alloc.node_id:
             vec = self._alloc_res_vec(alloc)
-            ports = self._alloc_ports(alloc)
+            ports = alloc.ports()
             devs = self._alloc_devices(alloc)
             self._node_allocs.setdefault(alloc.node_id, {})[alloc.id] = \
                 (vec, ports, devs)

@@ -371,7 +371,11 @@ class PlacementEngine:
                       # bulk_gate): placements of groups with a device
                       # ask, and those whose kernel node had no grantable
                       # instance and went to a top-K alternative
-                      "device_placements": 0, "device_fallbacks": 0}
+                      "device_placements": 0, "device_fallbacks": 0,
+                      # port asks (place_on): placements of groups that
+                      # ask a port, and those the host could not assign
+                      # ("ports exhausted") on the node the kernel chose
+                      "port_placements": 0, "port_fallbacks": 0}
         self._cache = _DeviceCache()
         # device-resident worlds: (id(cm), N, mesh identity) ->
         # DeviceWorld (epoch-uploaded capacity/basis, scatter deltas);

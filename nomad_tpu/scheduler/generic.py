@@ -391,8 +391,7 @@ class GenericScheduler:
             vec = comparable_vec(cr)
             used[row] -= vec
             deltas.append((row, -vec))
-            from nomad_tpu.core.plan_apply import _alloc_ports
-            freed_ports.setdefault(row, set()).update(_alloc_ports(a))
+            freed_ports.setdefault(row, set()).update(a.ports())
 
         # remaining allocs for anti-affinity / spread / distinct_*
         allocs_by_tg: Dict[str, List[Allocation]] = {}
@@ -445,13 +444,12 @@ class GenericScheduler:
         pending_bulk: List[Tuple[int, List[PlacementRequest], object]] = []
         for gi, prs in by_group.items():
             g = groups[gi]
-            from nomad_tpu.scheduler.stack import group_dynamic_port_count
             eligible = (len(prs) >= BULK_MIN and not g.spreads
                         and not g.distinct_hosts_job
                         and not g.distinct_hosts_tg
                         and not g.distinct_property
                         and not g.static_ports
-                        and group_dynamic_port_count(g.tg) == 0
+                        and not g.dynamic_ports
                         and not any(t.resources.devices
                                     for t in g.tg.tasks))
             if not eligible:
@@ -518,7 +516,7 @@ class GenericScheduler:
             stack.device_grants = grants
             with tracing.span("sched.feasible", cpu=True):
                 for gi_, tg_ in enumerate(job.task_groups):
-                    if groups[gi_].place_cap is not None:
+                    if groups[gi_].device_blocked is not None:
                         groups[gi_] = stack.compile_group(job, tg_)
             return place_round(prs)
 
@@ -630,7 +628,7 @@ class GenericScheduler:
             preempted = preempted if preempted is not None else []
             devices = assign_devices(pr, tg, node, row, preempted) \
                 if node is not None else {}
-            if groups[gi].place_cap is not None:
+            if groups[gi].device_blocked is not None:
                 eng.stats["device_placements"] += 1
                 eng.stats["device_fallbacks"] += devices is None
             if devices is None:
@@ -671,6 +669,9 @@ class GenericScheduler:
                 deployment_id=dep_id, is_canary=pr.is_canary,
                 is_rescheduling=pr.is_rescheduling, now=now,
                 task_devices=devices)
+            if groups[gi].static_ports or groups[gi].dynamic_ports:
+                eng.stats["port_placements"] += 1
+                eng.stats["port_fallbacks"] += alloc is None
             if alloc is None:
                 self._fail_placement(pr, metric, "ports exhausted")
                 return None
@@ -723,10 +724,9 @@ class GenericScheduler:
             # commit that (and the usage adjustments) if the placement
             # actually lands, else later placements would claim ports of
             # allocs that keep running
-            from nomad_tpu.core.plan_apply import _alloc_ports
             evicted_ports = set()
             for a in evicted:
-                evicted_ports.update(_alloc_ports(a))
+                evicted_ports.update(a.ports())
             metric = metric_for(i)
             # the kernel found no row for this slot: what the chosen node
             # scores is what the search ranked it by, with this evicted set
@@ -737,7 +737,7 @@ class GenericScheduler:
             # `evicted` may have grown inside place_on (device
             # preemption); account for everything it now holds
             for a in evicted:
-                evicted_ports.update(_alloc_ports(a))
+                evicted_ports.update(a.ports())
                 cr = a.comparable_resources()
                 used[row] -= comparable_vec(cr)
             freed_ports.setdefault(row, set()).update(evicted_ports)
@@ -753,11 +753,9 @@ class GenericScheduler:
                 return
             for a in extra:
                 used[row] -= comparable_vec(a.comparable_resources())
-                freed_ports.setdefault(row, set()).update(_alloc_ports_fn(a))
+                freed_ports.setdefault(row, set()).update(a.ports())
             if preemptor is not None:
                 preemptor.invalidate({a.id for a in extra})
-
-        from nomad_tpu.core.plan_apply import _alloc_ports as _alloc_ports_fn
 
         # rows become Allocation records (and slots that found no row
         # go to the preemption search): one span for the eval

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import uuid
 
+from nomad_tpu import tracing
 from nomad_tpu.utils import generate_uuid
 from typing import Dict, List, Optional, Set
 
@@ -104,28 +105,24 @@ def build_allocation(
     port assignment fails (caller treats as exhausted node).
     `task_devices` carries pre-assigned device instances per task name
     (scheduler/device.go AllocateDevice output)."""
-    tasks: Dict[str, AllocatedTaskResources] = {}
-    for t in tg.tasks:
-        nets = []
-        for net in t.resources.networks:
-            nets.append(_materialize_net(net, row, ports, freed_ports))
-            if nets[-1] is None:
-                return None
-        tasks[t.name] = AllocatedTaskResources(
+    task_nets: Dict[str, List[NetworkResource]] = {}
+    shared_nets: List[NetworkResource] = []
+    if tg.networks or any(t.resources.networks for t in tg.tasks):
+        with tracing.span("sched.assign_ports"):
+            got = _assign_ports(tg, row, ports, freed_ports)
+        if got is None:
+            return None
+        task_nets, shared_nets = got
+    tasks = {
+        t.name: AllocatedTaskResources(
             cpu_shares=t.resources.cpu,
             memory_mb=t.resources.memory_mb,
             memory_max_mb=t.resources.memory_max_mb,
-            networks=[n for n in nets if n is not None],
+            networks=task_nets.get(t.name, []),
             devices=list((task_devices or {}).get(t.name, ())),
-        )
-    shared_nets = []
-    shared_ports: List[NetworkPort] = []
-    for net in tg.networks:
-        m = _materialize_net(net, row, ports, freed_ports)
-        if m is None:
-            return None
-        shared_nets.append(m)
-        shared_ports.extend(m.reserved_ports + m.dynamic_ports)
+        ) for t in tg.tasks}
+    shared_ports: List[NetworkPort] = [
+        p for m in shared_nets for p in m.reserved_ports + m.dynamic_ports]
 
     alloc = Allocation(
         id=generate_uuid(),
@@ -237,6 +234,25 @@ def materialize_bulk_allocs(
             create_time=now,
             modify_time=now))
     return out
+
+
+def _assign_ports(tg: TaskGroup, row: int, ports: PortClaims,
+                  freed: Set[int]):
+    """({task: its networks}, the group's networks) with every asked
+    port claimed on `row`, or None at the first that cannot be."""
+    task_nets: Dict[str, List[NetworkResource]] = {}
+    for t in tg.tasks:
+        nets = task_nets[t.name] = []
+        for net in t.resources.networks:
+            nets.append(_materialize_net(net, row, ports, freed))
+            if nets[-1] is None:
+                return None
+    shared_nets: List[NetworkResource] = []
+    for net in tg.networks:
+        shared_nets.append(_materialize_net(net, row, ports, freed))
+        if shared_nets[-1] is None:
+            return None
+    return task_nets, shared_nets
 
 
 def _materialize_net(net: NetworkResource, row: int, ports: PortClaims,

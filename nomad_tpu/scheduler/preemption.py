@@ -149,7 +149,7 @@ class Preemptor:
             if not conflicted:
                 continue
             cand_port_sets = [
-                set(self.cm._alloc_ports(a)) for a in self.cand_allocs[row]]
+                set(a.ports()) for a in self.cand_allocs[row]]
             for p in conflicted:
                 held_by = [i for i, ps in enumerate(cand_port_sets)
                            if p in ps and self.cand_valid[row, i]]

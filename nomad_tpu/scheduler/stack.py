@@ -82,11 +82,12 @@ class CompiledGroup:
     # and the static ports the group asks for
     feasible_pre_ports: Optional[np.ndarray] = None   # bool[N]
     static_ports: List[int] = field(default_factory=list)
+    dynamic_ports: int = 0
     # nodes with device COUNT capacity but no free instances: preemption
     # targets for PreemptForDevice
     device_blocked: Optional[np.ndarray] = None       # bool[N]
-    # per-node placement capacity for this eval (instances the group may
-    # still place per node; -1 = unlimited)
+    # per-node placement capacity for this eval (what the node's free
+    # device instances and free ports allow the group; -1 = unlimited)
     place_cap: Optional[np.ndarray] = None            # i32[N]
     # the `devices` scorer: the score per node and whether the group's
     # device asks carry affinities at all (feasible.DeviceFit)
@@ -177,11 +178,25 @@ class DenseStack:
             # instances within one eval (deviceAllocator free counts)
             place_cap = fit.place_cap
         static_ports = group_static_ports(tg)
-        if static_ports:
-            mask &= cm.static_ports_free(static_ports)
         dyn = group_dynamic_port_count(tg)
-        if dyn:
-            mask &= cm.free_dynamic_ports() >= dyn
+        if static_ports or dyn:
+            # a node takes one placement of a static port and as many of
+            # a dynamic ask as its free range holds: the kernel's
+            # place_cap carry keeps one eval's slots inside that, since
+            # the host has no second node to offer once it cannot assign
+            # (NetworkIndex is rebuilt per candidate node, rank.go)
+            with tracing.span("sched.port_mask", cpu=True):
+                if static_ports:
+                    mask &= cm.static_ports_free(static_ports)
+                if dyn:
+                    port_cap = cm.free_dynamic_ports() // dyn
+                    if static_ports:
+                        np.minimum(port_cap, 1, out=port_cap)
+                    mask &= port_cap > 0
+                else:
+                    port_cap = np.ones(n, np.int32)
+                place_cap = port_cap if place_cap is None \
+                    else np.minimum(place_cap, port_cap)
 
         # affinity score: sum(weight * match) / sum(|weight|), rank.go:722-749
         aff = np.zeros(n, dtype=np.float32)
@@ -203,6 +218,7 @@ class DenseStack:
                              distinct_property=distinct_property,
                              feasible_pre_ports=feasible_pre_ports,
                              static_ports=static_ports,
+                             dynamic_ports=dyn,
                              device_blocked=device_blocked,
                              place_cap=place_cap,
                              dev_score=fit.score if fit else None,

@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import heapq
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from nomad_tpu.structs.resources import ComparableResources, Resources
 from nomad_tpu.structs.job import Job
@@ -94,6 +94,26 @@ class AllocatedResources:
         c.disk_mb = self.shared_disk_mb
         c.networks.extend(self.shared_networks)
         return c
+
+    def ports(self) -> Tuple[int, ...]:
+        """The host ports held, each once: THE definition the plan
+        applier, the cluster matrix and the scheduler's freed-port
+        bookkeeping share.  A group `network` block is materialised into
+        `shared_networks` and, flattened, into `shared_ports`
+        (build_allocation, as the reference does since 0.12): the flat
+        list stands for the group's ports where it is filled, the
+        networks where it is not (NetworkIndex.AddAllocs).  Task
+        networks are the pre-0.12 form and always count."""
+        shared = self.shared_ports
+        nets = [n for tr in self.tasks.values() for n in tr.networks]
+        if not shared:
+            nets += self.shared_networks
+            if not nets:
+                return ()
+        out = [p.value for n in nets
+               for p in n.reserved_ports + n.dynamic_ports if p.value]
+        out += [p.value for p in shared if p.value]
+        return tuple(out)
 
 
 @dataclass
@@ -208,6 +228,9 @@ class Allocation:
         c = ar.comparable()
         self._cmp_cache = (ar, c)
         return c
+
+    def ports(self) -> Tuple[int, ...]:
+        return self.allocated_resources.ports()
 
     def index(self) -> int:
         """Parse the bracketed index out of the alloc name."""

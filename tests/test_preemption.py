@@ -344,7 +344,7 @@ def test_find_many_with_rows_the_rules_add_back(rule):
     if rule == "static_ports":
         assert len(added) < (pre & held).sum(), "a tier-45 holder went"
         for f in added:
-            assert any(PORT in cm._alloc_ports(a) for a in f.evicted)
+            assert any(PORT in a.ports() for a in f.evicted)
     for count in (1, 8, 10**6):
         got = search.find_many(feasible, demand, used, count, **kw)
         assert _plain(got) == _plain(want)[:count]
