@@ -69,6 +69,10 @@ class ClusterMatrix:
         self.port_words = np.zeros((cap, _PORT_WORDS), dtype=np.uint32)
         self.dyn_port_lo = np.full(cap, 20000, dtype=np.int32)
         self.dyn_port_hi = np.full(cap, 32000, dtype=np.int32)
+        # free ports of each row's own [lo, hi], 0 for a row with no node:
+        # moved where a bit of `port_words` flips, recounted where a row's
+        # words are rebuilt, so an eval reads it and counts nothing
+        self._dyn_ports_free = np.zeros(cap, dtype=np.int32)
         # device-group id -> i32[N] instance capacity / committed usage
         self.device_caps: Dict[str, np.ndarray] = {}
         self.device_used: Dict[str, np.ndarray] = {}
@@ -109,6 +113,8 @@ class ClusterMatrix:
         self.port_words = np.vstack([self.port_words, np.zeros((old, _PORT_WORDS), np.uint32)])
         self.dyn_port_lo = np.concatenate([self.dyn_port_lo, np.full(old, 20000, np.int32)])
         self.dyn_port_hi = np.concatenate([self.dyn_port_hi, np.full(old, 32000, np.int32)])
+        self._dyn_ports_free = np.concatenate(
+            [self._dyn_ports_free, np.zeros(old, np.int32)])
         self.node_ids.extend([None] * old)
         self._free_rows.extend(range(new - 1, old - 1, -1))
         self.class_codes = np.concatenate(
@@ -202,6 +208,7 @@ class ClusterMatrix:
                     gid, np.zeros(self._n_rows, dtype=np.int32))
                 col[row] += cnt
         self.port_words[row] = words
+        self._dyn_ports_free[row] = self._count_free_dynamic_ports(row)
         self.generation += 1
         return row
 
@@ -215,6 +222,7 @@ class ClusterMatrix:
         self.ready[row] = False
         self.class_codes[row] = -1
         self.port_words[row] = 0
+        self._dyn_ports_free[row] = 0
         for col in self.device_caps.values():
             col[row] = 0
         for col in self.device_used.values():
@@ -245,6 +253,27 @@ class ClusterMatrix:
                 out[gid] = out.get(gid, 0) + len(d.get("device_ids", []))
         return out
 
+    def _write_ports(self, row: int, ports: Sequence[int],
+                     held: bool) -> None:
+        """Set (or clear) `ports` in the row's bitset.  The row's free
+        count follows the bits, not the allocations: it moves by one for
+        each bit inside the row's dynamic range that really flips, so a
+        port two allocations hold, or one the node also reserves, counts
+        exactly as `port_words` shows it."""
+        if not ports:
+            return
+        words = self.port_words[row]
+        lo, hi = int(self.dyn_port_lo[row]), int(self.dyn_port_hi[row])
+        flipped = 0
+        for p in ports:
+            w, bit = p >> 5, np.uint32(1 << (p & 31))
+            if bool(words[w] & bit) == held:
+                continue
+            words[w] ^= bit
+            flipped += lo <= p <= hi
+        if flipped:
+            self._dyn_ports_free[row] += -flipped if held else flipped
+
     def _untrack(self, alloc_id: str) -> None:
         node_id = self._alloc_node.pop(alloc_id, None)
         if node_id is None:
@@ -253,8 +282,7 @@ class ClusterMatrix:
         row = self.row_of.get(node_id)
         if row is not None:
             self.used[row] -= vec
-            for p in ports:
-                self.port_words[row, p >> 5] &= ~np.uint32(1 << (p & 31))
+            self._write_ports(row, ports, False)
             for gid, n in devs.items():
                 col = self.device_used.get(gid)
                 if col is not None:
@@ -277,8 +305,7 @@ class ClusterMatrix:
             row = self.row_of.get(alloc.node_id)
             if row is not None:
                 self.used[row] += vec
-                for p in ports:
-                    self.port_words[row, p >> 5] |= np.uint32(1 << (p & 31))
+                self._write_ports(row, ports, True)
                 for gid, n in devs.items():
                     col = self.device_used.setdefault(
                         gid, np.zeros(self._n_rows, dtype=np.int32))
@@ -302,27 +329,29 @@ class ClusterMatrix:
         return np.array([v in want for v in col.values], dtype=bool)
 
     def free_dynamic_ports(self) -> np.ndarray:
-        """Count of free ports in each node's own dynamic range [lo, hi],
-        exact at bit granularity.  Nodes are grouped by their (lo, hi) range
-        (a handful of distinct values in practice) and each group gets a
-        masked vectorized popcount over its own word window."""
+        """i32[N], the caller's own: free ports in each node's own dynamic
+        range [lo, hi], exact at bit granularity; 0 for a row that holds
+        no node.  A copy of the column the writers of `port_words` keep."""
+        return self._dyn_ports_free.copy()
+
+    def _count_free_dynamic_ports(self, row: int) -> int:
+        """The row's free count from its words: a masked popcount over
+        the word window of its range (an inverted range holds nothing)."""
+        lo, hi = int(self.dyn_port_lo[row]), int(self.dyn_port_hi[row])
+        if hi < lo:
+            return 0
+        words = self.port_words[row, lo >> 5:(hi >> 5) + 1].copy()
+        # mask off bits below lo in the first word / above hi in the last
+        words[0] &= np.uint32(0xFFFFFFFF) << np.uint32(lo & 31)
+        words[-1] &= np.uint32((1 << ((hi & 31) + 1)) - 1)
+        return (hi - lo + 1) - int(_POPCOUNT_TABLE[words.view(np.uint8)].sum())
+
+    def _recount_free_dynamic_ports(self) -> np.ndarray:
+        """What `free_dynamic_ports` has to equal, counted from
+        `port_words` row by row: the tests' oracle, on no eval's path."""
         out = np.zeros(self._n_rows, dtype=np.int32)
-        ranges: Dict[Tuple[int, int], List[int]] = {}
         for row in self.row_of.values():
-            key = (int(self.dyn_port_lo[row]), int(self.dyn_port_hi[row]))
-            ranges.setdefault(key, []).append(row)
-        for (lo, hi), rows in ranges.items():
-            rows_a = np.array(rows, dtype=np.int64)
-            w0, w1 = lo >> 5, (hi >> 5) + 1
-            words = self.port_words[rows_a, w0:w1].copy()
-            # mask off bits below lo in the first word / above hi in the last
-            words[:, 0] &= np.uint32(0xFFFFFFFF) << np.uint32(lo & 31)
-            hi_bit = hi & 31
-            last_mask = (np.uint64(1) << np.uint64(hi_bit + 1)) - np.uint64(1)
-            words[:, -1] &= np.uint32(last_mask)
-            byte_view = words.view(np.uint8)
-            used = _POPCOUNT_TABLE[byte_view].reshape(words.shape[0], -1).sum(axis=1)
-            out[rows_a] = (hi - lo + 1) - used
+            out[row] = self._count_free_dynamic_ports(row)
         return out
 
     def static_ports_free(self, ports: Sequence[int]) -> np.ndarray:

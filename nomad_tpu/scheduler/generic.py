@@ -522,7 +522,7 @@ class GenericScheduler:
 
         result = place_round(rounds[0]) if rounds else None
 
-        ports = PortClaims(cm)
+        ports = PortClaims(cm, self.eval.id)
         now = _time.time()
         deployment = self.plan.deployment or self.deployment
 

@@ -8,6 +8,7 @@ reference split where the plan applier re-validates).
 from __future__ import annotations
 
 import uuid
+import zlib
 
 from nomad_tpu import tracing
 from nomad_tpu.utils import generate_uuid
@@ -31,9 +32,14 @@ class PortClaims:
     """In-plan port claims per node row (plan-local view on top of the
     committed bitsets)."""
 
-    def __init__(self, cm: ClusterMatrix):
+    def __init__(self, cm: ClusterMatrix, eval_id: str = ""):
         self.cm = cm
         self.claimed: Dict[int, Set[int]] = {}
+        # where in a node's dynamic range the search for a free value
+        # begins (modulo the range's length): the eval's own, so that two
+        # evals in flight that choose one node do not both take its lowest
+        # free value and lose the node at the applier
+        self.start = zlib.crc32(eval_id.encode())
 
     def _is_free(self, row: int, port: int, freed: Set[int]) -> bool:
         if port in self.claimed.get(row, ()):
@@ -50,9 +56,11 @@ class PortClaims:
         return True
 
     def assign_dynamic(self, row: int, freed: Set[int]) -> Optional[int]:
-        """First free port in the node's dynamic range, via a vectorized
-        scan of the port bitset words (the naive per-port loop was O(range)
-        per assignment in the placement hot path)."""
+        """First free port of the node's dynamic range at or after the
+        claims' `start`, wrapping to the range's low end (the reference
+        draws at random and then walks, NetworkIndex.AssignPorts), via a
+        vectorized scan of the port bitset words (the naive per-port loop
+        was O(range) per assignment in the placement hot path)."""
         lo = int(self.cm.dyn_port_lo[row])
         hi = int(self.cm.dyn_port_hi[row])
         w0, w1 = lo >> 5, (hi >> 5) + 1
@@ -72,11 +80,18 @@ class PortClaims:
         last_mask = np.uint32(
             (np.uint64(1) << np.uint64(hi_bit + 1)) - np.uint64(1))
         words[-1] |= ~last_mask
-        free = np.flatnonzero(words != np.uint32(0xFFFFFFFF))
-        if len(free) == 0:
-            return None
-        w = int(free[0])
-        inv = int(~words[w] & np.uint32(0xFFFFFFFF))
+        first = lo + self.start % (hi - lo + 1)
+        w = (first >> 5) - w0
+        # the start's own word with the values below the start taken
+        word = int(words[w]) | ((1 << (first & 31)) - 1)
+        if word == 0xFFFFFFFF:
+            free = np.flatnonzero(words != np.uint32(0xFFFFFFFF))
+            if len(free) == 0:
+                return None
+            after = int(np.searchsorted(free, w + 1))
+            w = int(free[after if after < len(free) else 0])
+            word = int(words[w])
+        inv = ~word & 0xFFFFFFFF
         bit = (inv & -inv).bit_length() - 1   # lowest free bit
         p = ((w0 + w) << 5) + bit
         self.claimed.setdefault(row, set()).add(p)

@@ -125,7 +125,7 @@ class SystemScheduler:
         answers every row that does not, and the node loop builds the
         allocations."""
         cm = self.state.matrix
-        ports = PortClaims(cm)
+        ports = PortClaims(cm, self.eval.id)
         now = _time.time()
         self._build_s = self._evict_s = 0.0
         for gi, tg in enumerate(job.task_groups):

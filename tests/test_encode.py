@@ -1,5 +1,8 @@
 """ClusterMatrix / AttrTable incremental-mirror tests."""
+import copy
+
 import numpy as np
+import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.encode import ClusterMatrix, RES_CPU, RES_MEM, pad_to_bucket
@@ -70,6 +73,285 @@ def test_port_accounting():
     cm.upsert_alloc(a)
     assert cm.free_dynamic_ports()[r] == 12000
     assert not cm.static_ports_free([20005])[r]
+
+
+# dynamic ranges a node registers with: the default, the narrowed one of
+# `ports-10k` (hi on a word's last bit), one with neither end on a word
+# boundary and one inside a single word
+_RANGES = [(20000, 32000), (20000, 20031), (20005, 20100), (30000, 30010)]
+# ports of every range's edges, a step outside them, the word boundaries
+# near them, and some far from any range
+_PORTS = sorted({p + d for lo, hi in _RANGES for p in (lo, hi)
+                 for d in (-32, -1, 0, 1, 31, 32)}
+                | {22, 80, 8080, 65535})
+
+
+def _ported_node(rng, lo_hi=None):
+    n = mock.node()
+    lo, hi = lo_hi or _RANGES[rng.integers(len(_RANGES))]
+    n.node_resources.min_dynamic_port = lo
+    n.node_resources.max_dynamic_port = hi
+    n.reserved_resources.reserved_ports = [
+        int(p) for p in rng.choice(_PORTS, rng.integers(0, 4), replace=False)]
+    return n
+
+
+def _ported_alloc(node_id, ports):
+    from nomad_tpu.structs.resources import NetworkPort
+    a = mock.alloc_for(mock.job(), node_id)
+    a.allocated_resources.shared_ports = [
+        NetworkPort(label=f"p{i}", value=int(p)) for i, p in enumerate(ports)]
+    return a
+
+
+def _free_by_unpacked_bits(cm, row):
+    bits = np.unpackbits(cm.port_words[row].view(np.uint8),
+                         bitorder="little")
+    lo, hi = int(cm.dyn_port_lo[row]), int(cm.dyn_port_hi[row])
+    return (hi - lo + 1) - int(bits[lo:hi + 1].sum())
+
+
+def _assert_column_is_recount(cm, what):
+    got, want = cm.free_dynamic_ports(), cm._recount_free_dynamic_ports()
+    assert got.dtype == np.int32 and got.shape == (cm.n_rows,), what
+    assert np.array_equal(got, want), (what, np.flatnonzero(got != want))
+
+
+@pytest.mark.parametrize("seed", range(10))
+def test_free_dynamic_ports_column_follows_every_bit(seed):
+    """The kept column against the recount from `port_words`, after every
+    step of the named cases and then of a random walk over them."""
+    rng = np.random.default_rng(seed)
+    cm = ClusterMatrix()
+    nodes, absent, allocs = {}, {}, {}
+
+    def pick(pool):
+        return pool[sorted(pool)[rng.integers(len(pool))]]
+
+    def some_ports(node=None):
+        ports = [int(p) for p in
+                 rng.choice(_PORTS, rng.integers(1, 5), replace=False)]
+        if node is not None and rng.random() < 0.5:
+            # one another allocation of the node holds, or one it reserves
+            held = [p for _v, ps, _d in
+                    cm._node_allocs.get(node.id, {}).values() for p in ps]
+            held += node.reserved_resources.reserved_ports
+            if held:
+                ports.append(int(held[rng.integers(len(held))]))
+        return ports
+
+    def add_node(lo_hi=None):
+        n = _ported_node(rng, lo_hi)
+        cm.upsert_node(n)
+        nodes[n.id] = n
+
+    def reregister():
+        n = copy.deepcopy(pick(nodes))
+        other = _ported_node(rng)
+        n.node_resources = other.node_resources
+        n.reserved_resources = other.reserved_resources
+        cm.upsert_node(n)
+        nodes[n.id] = n
+
+    def remove_node():
+        n = pick(nodes)
+        cm.remove_node(n.id)
+        del nodes[n.id]
+        absent[n.id] = n           # its allocations stay tracked
+
+    def node_appears():
+        n = pick(absent)
+        cm.upsert_node(n)
+        nodes[n.id] = absent.pop(n.id)
+
+    def add_alloc(node=None, ports=None):
+        n = node or pick(nodes)
+        a = _ported_alloc(n.id, ports or some_ports(n))
+        cm.upsert_alloc(a)
+        allocs[a.id] = a
+
+    def alloc_before_node():
+        n = _ported_node(rng)
+        absent[n.id] = n
+        add_alloc(n)
+
+    def update_alloc():
+        a = copy.deepcopy(pick(allocs))
+        if rng.random() < 0.3 and nodes:
+            a.node_id = pick(nodes).id
+        a.allocated_resources = _ported_alloc(
+            a.node_id, some_ports(nodes.get(a.node_id))).allocated_resources
+        cm.upsert_alloc(a)
+        allocs[a.id] = a
+
+    def terminal():
+        a = copy.deepcopy(pick(allocs))
+        a.client_status = "failed"
+        cm.upsert_alloc(a)
+        del allocs[a.id]
+
+    def remove_alloc():
+        cm.remove_alloc(allocs.pop(pick(allocs).id).id)
+
+    # every named case once, in an order that does not depend on the seed
+    add_node((20000, 32000))
+    _assert_column_is_recount(cm, "default range")
+    add_node((20000, 20031))
+    narrowed = pick({k: v for k, v in nodes.items()
+                     if v.node_resources.max_dynamic_port == 20031})
+    row = cm.row_of[narrowed.id]
+    base = int(cm.free_dynamic_ports()[row])
+    assert base == 32 - sum(20000 <= p <= 20031 for p in
+                            narrowed.reserved_resources.reserved_ports)
+    add_alloc(narrowed, [19999, 20032, 8080])          # outside: no move
+    assert cm.free_dynamic_ports()[row] == base
+    free_in = [p for p in (20000, 20031, 20015) if p not in
+               narrowed.reserved_resources.reserved_ports]
+    add_alloc(narrowed, free_in)                       # lo, hi, inside
+    assert cm.free_dynamic_ports()[row] == base - len(free_in)
+    add_alloc(narrowed, free_in[:1])                   # held twice: no move
+    assert cm.free_dynamic_ports()[row] == base - len(free_in)
+    _assert_column_is_recount(cm, "narrowed node's allocations")
+    for step in (alloc_before_node, node_appears, reregister, terminal,
+                 update_alloc, remove_alloc, remove_node, add_node):
+        step()
+        _assert_column_is_recount(cm, step.__name__)
+    while cm.n_rows < 32:                              # _grow, twice
+        add_node()
+        _assert_column_is_recount(cm, "grow")
+
+    steps = [(add_node, 2, None), (reregister, 2, nodes),
+             (remove_node, 1, nodes), (node_appears, 1, absent),
+             (add_alloc, 6, nodes), (alloc_before_node, 1, None),
+             (update_alloc, 2, allocs), (terminal, 2, allocs),
+             (remove_alloc, 2, allocs)]
+    weights = np.array([w for _f, w, _n in steps], dtype=float)
+    for i in range(250):
+        fn, _w, needs = steps[rng.choice(len(steps), p=weights / weights.sum())]
+        if needs is not None and not needs:
+            continue
+        fn()
+        _assert_column_is_recount(cm, (i, fn.__name__))
+    # the oracle itself, against a count that shares none of its code
+    for row in cm.row_of.values():
+        assert cm.free_dynamic_ports()[row] == _free_by_unpacked_bits(cm, row)
+    empty = np.ones(cm.n_rows, bool)
+    empty[list(cm.row_of.values())] = False
+    assert not cm.free_dynamic_ports()[empty].any()
+
+
+def test_free_dynamic_ports_is_the_callers_array():
+    cm = ClusterMatrix()
+    n = mock.node()
+    r = cm.upsert_node(n)
+    got = cm.free_dynamic_ports()
+    got //= 2
+    np.minimum(got, 1, out=got)
+    got[:] = -7
+    assert cm.free_dynamic_ports()[r] == 12001
+    cm.upsert_alloc(_ported_alloc(n.id, [20005]))
+    assert cm.free_dynamic_ports()[r] == 12000
+    _assert_column_is_recount(cm, "after a caller wrote into its copy")
+
+
+def test_a_node_with_an_inverted_dynamic_range_registers_and_offers_none():
+    cm = ClusterMatrix()
+    rng = np.random.default_rng(0)
+    n = _ported_node(rng, (30000, 20000))
+    r = cm.upsert_node(n)
+    cm.upsert_alloc(_ported_alloc(n.id, [20000, 25000, 30000]))
+    assert cm.free_dynamic_ports()[r] == 0
+    _assert_column_is_recount(cm, "inverted range")
+
+
+def test_free_port_column_under_writers_and_a_lockless_reader():
+    """The store's writers (serialised by its lock) against a reader that
+    takes no lock, as the scheduler's `compile_group` does: more threads
+    than cores, a short switch interval, and at the end the column is the
+    recount; a lost update would leave it off by the ports it missed."""
+    import os
+    import sys
+    import threading
+    from nomad_tpu.state import StateStore
+
+    store = StateStore()
+    rng = np.random.default_rng(11)
+    nodes = [_ported_node(rng, (20000, 20031)) for _ in range(4)]
+    for i, n in enumerate(nodes, 1):
+        n.reserved_resources.reserved_ports = [22]
+        store.upsert_node(i, n)
+    cm = store.matrix
+    index = iter(range(100, 10**6))
+    stop = threading.Event()
+    seen_bad = []
+
+    def writer(k):
+        mine = np.random.default_rng(k)
+        for _ in range(40):
+            n = nodes[mine.integers(len(nodes))]
+            a = _ported_alloc(n.id, 20000 + mine.choice(32, 3, replace=False))
+            store.upsert_allocs(next(index), [a])
+            if mine.random() < 0.5:
+                a = copy.deepcopy(a)
+                a.client_status = "complete"
+                store.upsert_allocs(next(index), [a])
+
+    def reader():
+        while not stop.is_set():
+            free = cm.free_dynamic_ports()
+            if free.min() < 0 or free.max() > 32:
+                seen_bad.append(free)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=writer, args=(k,))
+                   for k in range(2 * (os.cpu_count() or 4))]
+        watch = threading.Thread(target=reader)
+        watch.start()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        stop.set()
+        watch.join(10)
+    finally:
+        sys.setswitchinterval(old)
+    assert not watch.is_alive() and not any(t.is_alive() for t in threads)
+    assert not seen_bad
+    _assert_column_is_recount(cm, "after the writers")
+    assert (cm.free_dynamic_ports()[[cm.row_of[n.id] for n in nodes]]
+            < 32).all()
+
+
+def test_fsm_restore_ends_with_the_free_port_column_exact():
+    from nomad_tpu.raft.fsm import MessageType, NomadFSM
+    from nomad_tpu.state import StateStore
+
+    rng = np.random.default_rng(5)
+    wide, narrow = _ported_node(rng, (20000, 32000)), \
+        _ported_node(rng, (20000, 20031))
+    wide.reserved_resources.reserved_ports = [80]
+    narrow.reserved_resources.reserved_ports = [22, 20001]
+    held = [_ported_alloc(wide.id, [20000, 25000, 32000, 32001]),
+            _ported_alloc(narrow.id, [20001, 20002, 20031, 20032]),
+            _ported_alloc(narrow.id, [20002, 8080])]
+    stopped = _ported_alloc(wide.id, [20007])
+    stopped.client_status = "complete"
+    live = NomadFSM(StateStore())
+    for i, (kind, payload) in enumerate([
+            (MessageType.NODE_REGISTER, {"node": wide}),
+            (MessageType.NODE_REGISTER, {"node": narrow}),
+            (MessageType.ALLOC_UPDATE, {"allocs": held + [stopped]})], 1):
+        live.apply(i, kind, copy.deepcopy(payload))
+    restored = NomadFSM(StateStore())
+    restored.restore(live.snapshot())
+    for fsm in (live, restored):
+        cm = fsm.store.matrix
+        _assert_column_is_recount(cm, fsm)
+        free = cm.free_dynamic_ports()
+        assert free[cm.row_of[wide.id]] == 12001 - 3
+        assert free[cm.row_of[narrow.id]] == 32 - 3
 
 
 def test_attr_ordinals_lexical():

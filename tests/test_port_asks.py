@@ -182,6 +182,85 @@ def test_a_narrowed_node_gives_out_no_more_than_its_range_holds():
     assert not h.results[0].rejected_nodes
 
 
+def _held(cm, row, p):
+    return bool((int(cm.port_words[row, p >> 5]) >> (p & 31)) & 1)
+
+
+def _walk_from_start(cm, row, start, taken, freed):
+    """What `assign_dynamic` has to give: the first value of the row's
+    range at or after its start, else the first below it, that this plan
+    has not taken and that is free or freed."""
+    lo, hi = int(cm.dyn_port_lo[row]), int(cm.dyn_port_hi[row])
+    first = lo + start % (hi - lo + 1)
+    for p in list(range(first, hi + 1)) + list(range(lo, first)):
+        if p not in taken and (p in freed or not _held(cm, row, p)):
+            return p
+    return None
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_a_dynamic_port_is_the_first_free_from_the_evals_own_start(seed):
+    """Ranges with either end inside a word and inside one word, random
+    held ports, freed ones and a random eval id: every value the claims
+    give out is the naive walk's from the eval's start, around the
+    range's end, until the range is exhausted and the answer is None."""
+    import zlib
+    from nomad_tpu.encode import ClusterMatrix
+    rng = np.random.default_rng(seed)
+    cm = ClusterMatrix()
+    rows = []
+    for lo, hi in [(20000, 20031), (20005, 20100), (30000, 30010),
+                   (20000, 20200)]:
+        n = mock.node()
+        n.node_resources.min_dynamic_port = lo
+        n.node_resources.max_dynamic_port = hi
+        held = rng.choice(np.arange(lo - 3, hi + 4),
+                          int(rng.integers(0, hi - lo)), replace=False)
+        n.reserved_resources.reserved_ports = [int(p) for p in held]
+        rows.append(cm.upsert_node(n))
+    eval_id = f"eval-{seed}-{rng.integers(1 << 30)}"
+    claims = PortClaims(cm, eval_id)
+    assert claims.start == zlib.crc32(eval_id.encode())
+    for row in rows:
+        lo, hi = int(cm.dyn_port_lo[row]), int(cm.dyn_port_hi[row])
+        freed = {int(p) for p in rng.choice(np.arange(lo, hi + 1), 3)}
+        free = int(cm.free_dynamic_ports()[row]) + sum(
+            _held(cm, row, p) for p in freed)
+        taken: set = set()
+        for _ in range(free):
+            got = claims.assign_dynamic(row, freed)
+            assert got == _walk_from_start(cm, row, claims.start, taken,
+                                           freed)
+            taken.add(got)
+        assert len(taken) == free and all(lo <= p <= hi for p in taken)
+        assert claims.assign_dynamic(row, freed) is None
+
+
+def test_two_evals_that_choose_one_node_take_different_dynamic_ports():
+    """The default claims (no eval) walk from the range's low end, as
+    they always did; two evals' claims over the same committed bits start
+    apart, so neither plan's node is refused for the other's port."""
+    from nomad_tpu.encode import ClusterMatrix
+    cm = ClusterMatrix()
+    wide, narrow = mock.node(), mock.node()
+    narrow.node_resources.min_dynamic_port = 20000
+    narrow.node_resources.max_dynamic_port = 20031
+    rows = [cm.upsert_node(wide), cm.upsert_node(narrow)]
+    plain = PortClaims(cm)
+    assert [plain.assign_dynamic(r, set()) for r in rows] == [20000, 20000]
+    assert plain.assign_dynamic(rows[0], set()) == 20001
+    for row, span in zip(rows, (12001, 32)):
+        picks = []
+        for i in range(16):
+            claims = PortClaims(cm, f"e{i}")
+            pair = [claims.assign_dynamic(row, set()) for _ in range(2)]
+            assert pair[1] == 20000 + (pair[0] - 20000 + 1) % span
+            picks.append(pair[0])
+        # crc32 of sixteen ids: distinct starts on the wide range, and
+        # most of them apart even on the 32 values of the narrowed node
+        assert len(set(picks)) == 16 if span > 32 else len(set(picks)) > 8
+
+
 JOBSPEC = """
 job "web" {
   datacenters = ["dc1"]
@@ -306,6 +385,61 @@ def test_the_preload_holds_its_ports_without_collision():
     free = [sum(1 for p in range(cl.lo[r], cl.hi[r] + 1)
                 if p not in cl.held[r]) for r in range(cl.n)]
     assert free == cl.dyn_free0.tolist()
+
+
+@pytest.mark.parametrize("shape", ["web", "edge"])
+@time_limit(120)
+def test_compile_group_reads_the_counts_a_recount_gives(shape):
+    """The cell's cluster at 512 nodes and its two shapes: the place_cap
+    and the feasible mask `compile_group` gets from the matrix's kept
+    column are those the recount from `port_words` gives, and before any
+    plan those of the cluster's own free counts; again after a job of
+    the cell has committed its plan."""
+    import types
+    from benchmark import traffic
+    from benchmark.ports import jobs as ports_jobs
+    from nomad_tpu.scheduler.stack import DenseStack
+
+    cl = ports_cluster.Cluster(harness.load_config("ports-10k"), 7, 512)
+    h = Harness()
+    cl.install(types.SimpleNamespace(server=types.SimpleNamespace(
+        store=h.store, next_index=h.next_index)))
+    cm = h.store.matrix
+    rows = np.array([cm.row_of[i] for i in cl.node_ids])
+    job = ports_jobs.build(traffic.load("web-services")["shapes"][shape],
+                           f"{shape}-5")
+    tg = job.task_groups[0]
+    static = [p.value for p in tg.networks[0].reserved_ports]
+    dyn = len(tg.networks[0].dynamic_ports)
+    assert (len(static), dyn) == {"web": (0, 2), "edge": (1, 1)}[shape]
+
+    def check(free):
+        got = DenseStack(cm).compile_group(job, tg)
+        want_cap = free // dyn
+        want_ok = want_cap > 0
+        if static:
+            want_cap = np.minimum(want_cap, 1)
+            want_ok &= cm.static_ports_free(static)
+        assert np.array_equal(got.place_cap, want_cap)
+        assert np.array_equal(got.feasible,
+                              got.feasible_pre_ports & want_ok)
+        return got
+
+    before = cm._recount_free_dynamic_ports()
+    assert np.array_equal(before[rows], cl.dyn_free0)
+    got = check(before)
+    narrowed = rows[cl.narrow]
+    assert np.array_equal(got.place_cap[narrowed],
+                          np.minimum(cl.dyn_free0[cl.narrow], 1) if static
+                          else cl.dyn_free0[cl.narrow] // 2)
+    assert got.place_cap[rows[~cl.narrow]].min() >= (1 if static else 5000)
+
+    allocs = _process(h, job)
+    assert len(allocs) == 96 and not h.results[0].rejected_nodes
+    after = cm._recount_free_dynamic_ports()
+    assert before.sum() - after.sum() == 96 * dyn
+    assert np.array_equal(cm.free_dynamic_ports(), after)
+    check(after)
 
 
 @time_limit(60)
