@@ -13,12 +13,14 @@ recompiles; SURVEY.md section 7 "dynamic shapes").
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from nomad_tpu.encode.attrs import AttrTable
+from nomad_tpu.telemetry import global_metrics
 
 # Resource dimension layout of the dense matrices.  Network bandwidth is a
 # first-class dimension: where the reference accounts MBits inside
@@ -94,9 +96,25 @@ class ClusterMatrix:
         self.generation = 0
         # authoritative live-alloc usage, keyed by node id so it survives node
         # churn and alloc-before-node replay order:
-        #   node_id -> {alloc_id: (res_vec, ports)}
-        self._node_allocs: Dict[str, Dict[str, Tuple[np.ndarray, Tuple[int, ...]]]] = {}
+        #   node_id -> {alloc_id: (res_vec, ports, devices, job priority)}
+        self._node_allocs: Dict[str, Dict[str, Tuple[
+            np.ndarray, Tuple[int, ...], Dict[str, int], int]]] = {}
         self._alloc_node: Dict[str, str] = {}  # alloc_id -> node_id
+        # the slot table: every live allocation of every row, whatever its
+        # priority, a slot each, a row by priority (what a preemption
+        # search takes its candidates from).  The writers of
+        # `used` mark the row they change; `candidates` lays the marked
+        # rows out again from `_node_allocs` before it reads, so a write
+        # costs one set add and a reader the rows written since the last
+        self.alloc_res = np.zeros((cap, 4, NUM_RESOURCE_DIMS), np.float32)
+        self.alloc_prio = np.zeros((cap, 4), np.int32)
+        self.alloc_live = np.zeros((cap, 4), bool)
+        # row -> the ids of its slots, a tuple a layout replaces whole
+        self.alloc_ids: List[Tuple[str, ...]] = [()] * cap
+        self._stale_rows: set = set()
+        # the writers' lock, the store's own once a store holds the matrix:
+        # a reader that wants several arrays of one commit takes it
+        self.lock = threading.RLock()
 
     # ------------------------------------------------------------- rows
 
@@ -115,6 +133,8 @@ class ClusterMatrix:
         self.dyn_port_hi = np.concatenate([self.dyn_port_hi, np.full(old, 32000, np.int32)])
         self._dyn_ports_free = np.concatenate(
             [self._dyn_ports_free, np.zeros(old, np.int32)])
+        self._double_slot_table(axis=0)
+        self.alloc_ids.extend([()] * old)
         self.node_ids.extend([None] * old)
         self._free_rows.extend(range(new - 1, old - 1, -1))
         self.class_codes = np.concatenate(
@@ -141,6 +161,10 @@ class ClusterMatrix:
             row = self._free_rows.pop()
             self.row_of[node.id] = row
             self.node_ids[row] = node.id
+            # what was tracked before the row was (allocations that
+            # arrived before their node, a node that comes back) gets its
+            # slots; a row that stays keeps them: no field of a node moves one
+            self._stale_rows.add(row)
         res = node.node_resources
         rr = node.reserved_resources
         self.capacity[row, RES_CPU] = res.cpu.cpu_shares - rr.cpu_shares
@@ -199,7 +223,8 @@ class ClusterMatrix:
         self.used[row] = 0
         for col in self.device_used.values():
             col[row] = 0
-        for vec, ports, devs in self._node_allocs.get(node.id, {}).values():
+        for vec, ports, devs, _prio in \
+                self._node_allocs.get(node.id, {}).values():
             self.used[row] += vec
             for p in ports:
                 words[p >> 5] |= np.uint32(1 << (p & 31))
@@ -229,6 +254,7 @@ class ClusterMatrix:
             col[row] = 0
         self._clear_device_attrs(row)
         self.attrs.clear_row(row)
+        self._stale_rows.add(row)
         self._free_rows.append(row)
         self.generation += 1
 
@@ -274,13 +300,21 @@ class ClusterMatrix:
         if flipped:
             self._dyn_ports_free[row] += -flipped if held else flipped
 
+    def _double_slot_table(self, axis: int) -> None:
+        """Twice the rows (axis 0) or twice the slots a row (axis 1)."""
+        for name in ("alloc_res", "alloc_prio", "alloc_live"):
+            old = getattr(self, name)
+            setattr(self, name, np.concatenate(
+                [old, np.zeros_like(old)], axis=axis))
+
     def _untrack(self, alloc_id: str) -> None:
         node_id = self._alloc_node.pop(alloc_id, None)
         if node_id is None:
             return
-        vec, ports, devs = self._node_allocs[node_id].pop(alloc_id)
+        vec, ports, devs, _prio = self._node_allocs[node_id].pop(alloc_id)
         row = self.row_of.get(node_id)
         if row is not None:
+            self._stale_rows.add(row)
             self.used[row] -= vec
             self._write_ports(row, ports, False)
             for gid, n in devs.items():
@@ -299,11 +333,13 @@ class ClusterMatrix:
             vec = self._alloc_res_vec(alloc)
             ports = alloc.ports()
             devs = self._alloc_devices(alloc)
+            prio = alloc.job.priority if alloc.job is not None else 50
             self._node_allocs.setdefault(alloc.node_id, {})[alloc.id] = \
-                (vec, ports, devs)
+                (vec, ports, devs, prio)
             self._alloc_node[alloc.id] = alloc.node_id
             row = self.row_of.get(alloc.node_id)
             if row is not None:
+                self._stale_rows.add(row)
                 self.used[row] += vec
                 self._write_ports(row, ports, True)
                 for gid, n in devs.items():
@@ -354,6 +390,60 @@ class ClusterMatrix:
             out[row] = self._count_free_dynamic_ports(row)
         return out
 
+    def candidates(self, max_prio: int):
+        """-> (res f32[N, A, R], prio i32[N, A], valid bool[N, A], ids),
+        the caller's own: each row's live allocations of job priority
+        `max_prio` or less, from index 0, `A` the bucket of the widest row
+        of them, and row -> the ids of the row's slots, as one commit left
+        them (under the writers' lock).  A row lies by priority, so what
+        may go is a prefix of it."""
+        with self.lock:
+            stale = len(self._stale_rows)
+            self._lay_out_stale_rows()
+            valid = self.alloc_live & (self.alloc_prio <= max_prio)
+            width = pad_to_bucket(
+                int(valid.sum(axis=1).max(initial=1)), minimum=4)
+            got = (self.alloc_res[:, :width].copy(),
+                   self.alloc_prio[:, :width].copy(),
+                   valid[:, :width].copy(), list(self.alloc_ids))
+        global_metrics.incr("nomad.matrix.cand_views")
+        global_metrics.incr("nomad.matrix.cand_rows_laid_out", stale)
+        return got
+
+    def _lay_out_stale_rows(self) -> None:
+        """The slots of every row written since the last time, from what
+        its node tracks now: lowest priority first, and among equals in
+        the order the node tracks them; none for a row whose node has
+        gone."""
+        for row in self._stale_rows:
+            tracked = self._node_allocs.get(self.node_ids[row], {})
+            n = len(tracked)
+            while n > self.alloc_live.shape[1]:
+                self._double_slot_table(axis=1)
+            held = sorted(tracked.items(), key=_by_priority)
+            self.alloc_live[row] = False
+            self.alloc_ids[row] = tuple(alloc_id for alloc_id, _h in held)
+            if n:
+                self.alloc_res[row, :n] = [h[0] for _id, h in held]
+                self.alloc_prio[row, :n] = [h[3] for _id, h in held]
+                self.alloc_live[row, :n] = True
+        self._stale_rows.clear()
+
+    def _recount_candidates(self) -> Dict[int, Dict[str, tuple]]:
+        """What the slot table has to hold, row -> {allocation id ->
+        (resources, priority)}, from `_node_allocs` row by row: the tests'
+        oracle, on no eval's path."""
+        return {row: {alloc_id: (tuple(vec.tolist()), prio)
+                      for alloc_id, (vec, _ports, _devs, prio)
+                      in self._node_allocs.get(node_id, {}).items()}
+                for node_id, row in self.row_of.items()}
+
+    def alloc_ports(self, row: int, alloc_id: str) -> Tuple[int, ...]:
+        """The ports a live allocation of the row's node holds; none for
+        one that is no longer tracked there."""
+        held = self._node_allocs.get(self.node_ids[row], {}).get(alloc_id)
+        return held[1] if held is not None else ()
+
     def static_ports_free(self, ports: Sequence[int]) -> np.ndarray:
         """bool[N]: True where none of `ports` is already claimed."""
         if not ports:
@@ -363,6 +453,10 @@ class ClusterMatrix:
             bit = (self.port_words[:, p >> 5] >> np.uint32(p & 31)) & np.uint32(1)
             mask &= bit == 0
         return mask
+
+
+def _by_priority(tracked_item) -> int:
+    return tracked_item[1][3]
 
 
 _POPCOUNT_TABLE = np.array([bin(i).count("1") for i in range(256)], dtype=np.int64)

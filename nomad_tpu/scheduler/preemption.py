@@ -2,7 +2,8 @@
 
 Reference: scheduler/preemption.go Preemptor.  The device kernel
 (ops.preempt) answers met/picked for every node at once; this module
-builds the padded candidate matrices from the snapshot, ranks the eligible
+takes the padded candidate matrices from the slot table the cluster
+matrix keeps (encode/matrixizer.py `candidates`), ranks the eligible
 nodes (fit score after preemption + logistic preemption score, mirroring
 PreemptionScoringIterator rank.go:817-868), and applies the reference's
 final superset-filter pass (preemption.go:702-732) to the chosen node.
@@ -27,12 +28,12 @@ from typing import Dict, List, NamedTuple, Optional, Set, Tuple
 import numpy as np
 
 from nomad_tpu import tracing
-from nomad_tpu.encode.matrixizer import NUM_RESOURCE_DIMS, comparable_vec, pad_to_bucket
 from nomad_tpu.ops.preempt import (
     net_priority,
     preempt_for_task_group_np,
     preemption_score,
 )
+from nomad_tpu.telemetry import global_metrics
 
 PRIORITY_DELTA = 10   # preemption.go:663-697: need >= 10 priority gap
 
@@ -92,32 +93,14 @@ class Preemptor:
     # ------------------------------------------------------------- build
 
     def _build(self) -> None:
-        """Pad per-node preemptible-alloc matrices."""
-        cm = self.cm
-        N = cm.n_rows
-        per_node: List[List] = [[] for _ in range(N)]
-        for node_id, row in cm.row_of.items():
-            for a in self.snapshot.allocs_by_node(node_id):
-                if a.terminal_status():
-                    continue
-                prio = a.job.priority if a.job is not None else 50
-                if self.job_priority - prio < PRIORITY_DELTA:
-                    continue
-                per_node[row].append(a)
-        A = pad_to_bucket(max([len(x) for x in per_node] + [1]), minimum=4)
-        self.cand_allocs = per_node
-        self.cand_res = np.zeros((N, A, NUM_RESOURCE_DIMS), np.float32)
-        self.cand_prio = np.zeros((N, A), np.int32)
-        self.cand_valid = np.zeros((N, A), bool)
-        self._cand_index = {}          # alloc id -> (row, i)
-        for row, allocs in enumerate(per_node):
-            for i, a in enumerate(allocs):
-                cr = a.comparable_resources()
-                self.cand_res[row, i] = comparable_vec(cr)
-                self.cand_prio[row, i] = a.job.priority if a.job else 50
-                self.cand_valid[row, i] = True
-                self._cand_index[a.id] = (row, i)
-        self.max_steps = min(A, 32)
+        """The allocations this job may evict, from the matrix's slot
+        table as one commit left it, padded to the widest row of them.
+        Candidates fill a row from index 0, lowest priority first and
+        among equals in the order their node tracks them, so a tie between
+        equal candidates goes to the one tracked first."""
+        self.cand_res, self.cand_prio, self.cand_valid, self.cand_ids = \
+            self.cm.candidates(self.job_priority - PRIORITY_DELTA)
+        self.max_steps = min(self.cand_valid.shape[1], 32)
         self._built = True
 
     def invalidate(self, alloc_ids: Set[str]) -> None:
@@ -125,9 +108,27 @@ class Preemptor:
         if not self._built:
             return
         for aid in alloc_ids:
-            loc = self._cand_index.get(aid)
-            if loc is not None:
-                self.cand_valid[loc[0], loc[1]] = False
+            a = self.snapshot.allocs.get(aid)
+            row = self.cm.row_of.get(a.node_id) if a is not None else None
+            if row is not None and aid in self.cand_ids[row]:
+                at = self.cand_ids[row].index(aid)
+                if at < self.cand_valid.shape[1]:   # past it, none may go
+                    self.cand_valid[row, at] = False
+
+    def _records(self, row: int, picked) -> Optional[List]:
+        """The candidates at indices `picked` of `row` as this eval's
+        snapshot has them.  The matrix is live and the snapshot is a read
+        point: an allocation committed since is no candidate of this eval,
+        so it leaves `cand_valid` and the row answers None."""
+        seen = self.snapshot.allocs
+        ids = self.cand_ids[row]
+        found = [seen.get(ids[k]) for k in picked]
+        unseen = [k for k, a in zip(picked, found) if a is None]
+        if unseen:
+            self.cand_valid[row, unseen] = False
+            global_metrics.incr("nomad.sched.preempt_unseen", len(unseen))
+            return None
+        return found
 
     # ------------------------------------------------------------- ports
 
@@ -148,8 +149,9 @@ class Preemptor:
                 if (self.cm.port_words[row, p >> 5] >> np.uint32(p & 31)) & 1}
             if not conflicted:
                 continue
-            cand_port_sets = [
-                set(a.ports()) for a in self.cand_allocs[row]]
+            cand_port_sets = [set(self.cm.alloc_ports(row, aid))
+                              for aid in self.cand_ids[row][
+                                  :self.cand_valid.shape[1]]]
             for p in conflicted:
                 held_by = [i for i, ps in enumerate(cand_port_sets)
                            if p in ps and self.cand_valid[row, i]]
@@ -250,11 +252,6 @@ class Preemptor:
             for i in first])[which]
         score = (fit + p_score) / 2.0
 
-        def record(i):
-            row = int(rows[i])
-            return Eviction(
-                row, [self.cand_allocs[row][k] for k in np.flatnonzero(on[i])],
-                float(score[i]), float(fit[i]), float(p_score[i]))
         # the first of the best in row order, then every other met row
         # that evicts best-first, for find_many: eviction sets on distinct
         # rows are disjoint, so one search can serve a whole batch of
@@ -262,8 +259,17 @@ class Preemptor:
         best = int(np.argmax(score))
         order = np.lexsort((rows, score))[::-1]
         order = order[(order != best) & on.any(axis=1)[order]]
-        self._last_ranked = [record(i) for i in order[:count - 1]]
-        return record(best)
+        ranked: List[Eviction] = []
+        for i in [best, *order]:
+            if len(ranked) == count:
+                break
+            row = int(rows[i])
+            evicted = self._records(row, np.flatnonzero(on[i]))
+            if evicted is not None:
+                ranked.append(Eviction(row, evicted, float(score[i]),
+                                       float(fit[i]), float(p_score[i])))
+        self._last_ranked = ranked[1:]
+        return ranked[0] if ranked else None
 
     def _greedy(self, feasible, remaining, ask):
         """The greedy passes over the rows that can answer: feasible, not

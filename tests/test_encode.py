@@ -133,7 +133,7 @@ def test_free_dynamic_ports_column_follows_every_bit(seed):
                  rng.choice(_PORTS, rng.integers(1, 5), replace=False)]
         if node is not None and rng.random() < 0.5:
             # one another allocation of the node holds, or one it reserves
-            held = [p for _v, ps, _d in
+            held = [p for _v, ps, *_ in
                     cm._node_allocs.get(node.id, {}).values() for p in ps]
             held += node.reserved_resources.reserved_ports
             if held:
@@ -352,6 +352,219 @@ def test_fsm_restore_ends_with_the_free_port_column_exact():
         free = cm.free_dynamic_ports()
         assert free[cm.row_of[wide.id]] == 12001 - 3
         assert free[cm.row_of[narrow.id]] == 32 - 3
+
+
+# ------------------------------------------------------- the slot table
+#
+# `alloc_res` / `alloc_prio` / `alloc_live` / `alloc_ids`: the writers of
+# `used` mark the rows they change and `candidates` lays those out again;
+# `_recount_candidates` rebuilds what every row has to hold from
+# `_node_allocs`.
+
+_TABLE_JOBS = {p: mock.job(priority=p) for p in (20, 35, 45, 50, 70)}
+
+
+def _table_alloc(rng, node_id):
+    prio = (None, 20, 35, 45, 50, 70)[rng.integers(6)]
+    a = mock.alloc_for(_TABLE_JOBS[prio or 50], node_id)
+    if prio is None:
+        a.job = None                       # reads as priority 50
+    (task,) = a.allocated_resources.tasks.values()
+    task.cpu_shares = int(rng.integers(1, 500))
+    task.memory_mb = int(rng.integers(1, 900))
+    a.allocated_resources.shared_disk_mb = int(rng.integers(0, 300))
+    return a
+
+
+def _assert_table_is_recount(cm, what):
+    want = cm._recount_candidates()
+    # what a search is handed: the rows' eligible prefixes
+    handed = {p: cm.candidates(p) for p in (20, 40, 90)}
+    assert not cm._stale_rows, what
+    width = cm.alloc_live.shape[1]
+    assert cm.alloc_res.shape == (cm.n_rows, width, 4), what
+    assert cm.alloc_prio.shape == cm.alloc_live.shape == (cm.n_rows, width)
+    assert len(cm.alloc_ids) == cm.n_rows
+    assert width == pad_to_bucket(width, minimum=4)
+    assert set(want) == set(cm.row_of.values())
+    # a row names its live slots and no other, one with no node none
+    assert [len(ids) for ids in cm.alloc_ids] == \
+        cm.alloc_live.sum(axis=1).tolist(), what
+    empty = np.ones(cm.n_rows, bool)
+    empty[list(want)] = False
+    assert not cm.alloc_live[empty].any(), what
+    for row, allocs in want.items():
+        slots = np.flatnonzero(cm.alloc_live[row])
+        got = {cm.alloc_ids[row][s]: (tuple(cm.alloc_res[row, s].tolist()),
+                                      int(cm.alloc_prio[row, s]))
+               for s in slots}
+        assert len(got) == len(slots) and got == allocs, (what, row)
+        # a row fills from slot 0, lowest priority first, equals in the
+        # order the node tracks them
+        assert slots.tolist() == list(range(len(slots))), (what, row)
+        assert list(cm.alloc_ids[row]) == sorted(
+            allocs, key=lambda i: allocs[i][1]), (what, row)
+    for max_prio, (res, prio, valid, ids) in handed.items():
+        may_go = cm.alloc_live & (cm.alloc_prio <= max_prio)
+        width = valid.shape[1]
+        assert width == pad_to_bucket(int(may_go.sum(axis=1).max(initial=1)),
+                                      minimum=4), what
+        assert not may_go[:, width:].any(), what
+        assert np.array_equal(valid, may_go[:, :width]), what
+        assert (valid[:, :-1] >= valid[:, 1:]).all(), "a prefix of each row"
+        assert np.array_equal(res, cm.alloc_res[:, :width]), what
+        assert np.array_equal(prio, cm.alloc_prio[:, :width]), what
+        assert ids == cm.alloc_ids and ids is not cm.alloc_ids, what
+        assert {i for a in want.values() for i, (_v, p) in a.items()
+                if p <= max_prio} == {ids[r][k] for r, k in
+                                      zip(*np.nonzero(valid))}, what
+        res[:], prio[:], valid[:], ids[:] = 7, 7, True, [()] * len(ids)
+    assert not cm.alloc_live[empty].any(), "a caller wrote into its own"
+
+
+# a kind of history -> how often each step is taken in its walk
+_HISTORIES = {
+    "allocations come and go": dict(
+        add_node=1, add_alloc=8, remove_alloc=5, update_alloc=2),
+    "clients report them terminal": dict(
+        add_node=1, add_alloc=8, terminal=6),
+    "nodes register again": dict(
+        add_node=1, add_alloc=6, remove_alloc=2, reregister=5),
+    "nodes leave and come back": dict(
+        add_node=2, add_alloc=6, remove_alloc=2, terminal=1, remove_node=2,
+        node_appears=2),
+    "allocations arrive before their node": dict(
+        add_alloc=4, alloc_before_node=4, node_appears=2, remove_alloc=2,
+        terminal=1),
+    "the rows grow": dict(add_node=8, add_alloc=8, remove_alloc=1),
+    "the slots grow": dict(crowd=10, add_alloc=2, remove_alloc=6, add_node=1),
+    "everything at once": dict(
+        add_node=2, add_alloc=8, remove_alloc=3, update_alloc=2, terminal=3,
+        reregister=2, remove_node=1, node_appears=1, alloc_before_node=1,
+        crowd=2),
+}
+
+
+@pytest.mark.parametrize("history", sorted(_HISTORIES))
+def test_the_slot_table_is_the_recount_after(history):
+    """Some thousands of writes of one kind of history, and the table a
+    view finds is the one rebuilt from `_node_allocs`: slot set for slot
+    set on every row, though only the rows written since the last view
+    were laid out again."""
+    rng = np.random.default_rng([len(history), 0x51075])
+    cm = ClusterMatrix()
+    nodes, absent, allocs = {}, {}, {}
+
+    def pick(pool):
+        return pool[sorted(pool)[rng.integers(len(pool))]]
+
+    def add_node():
+        n = mock.node()
+        cm.upsert_node(n)
+        nodes[n.id] = n
+
+    def reregister():
+        n = copy.deepcopy(pick(nodes))
+        n.node_resources.memory_mb += 1
+        cm.upsert_node(n)
+        nodes[n.id] = n
+
+    def remove_node():
+        n = pick(nodes)
+        cm.remove_node(n.id)
+        absent[n.id] = nodes.pop(n.id)     # its allocations stay tracked
+
+    def node_appears():
+        n = pick(absent)
+        cm.upsert_node(n)
+        nodes[n.id] = absent.pop(n.id)
+
+    def add_alloc(node=None):
+        a = _table_alloc(rng, (node or pick(nodes)).id)
+        cm.upsert_alloc(a)
+        allocs[a.id] = a
+
+    def crowd():
+        add_alloc(nodes[sorted(nodes)[0]])  # one node fills its slots
+
+    def alloc_before_node():
+        n = mock.node()
+        absent[n.id] = n
+        add_alloc(n)
+
+    def update_alloc():
+        old = pick(allocs)
+        a = _table_alloc(rng, pick(nodes).id if rng.random() < 0.3
+                         else old.node_id)
+        a.id = old.id
+        cm.upsert_alloc(a)
+        allocs[a.id] = a
+
+    def terminal():
+        a = copy.copy(pick(allocs))
+        a.client_status = "failed"
+        cm.upsert_alloc(a)
+        del allocs[a.id]
+
+    def remove_alloc():
+        cm.remove_alloc(allocs.pop(pick(allocs).id).id)
+
+    steps = {f.__name__: (f, needs) for f, needs in [
+        (add_node, None), (reregister, nodes), (remove_node, nodes),
+        (node_appears, absent), (add_alloc, nodes), (crowd, nodes),
+        (alloc_before_node, None), (update_alloc, allocs),
+        (terminal, allocs), (remove_alloc, allocs)]}
+    add_node()
+    names = sorted(_HISTORIES[history])
+    weights = np.array([_HISTORIES[history][k] for k in names], dtype=float)
+    rows0, width0 = cm.n_rows, cm.alloc_live.shape[1]
+    taken = 0
+    for i in range(3000):
+        fn, needs = steps[names[rng.choice(len(names),
+                                           p=weights / weights.sum())]]
+        if needs is not None and (not needs or (needs is allocs and not nodes)):
+            continue
+        fn()
+        taken += 1
+        if i % 100 == 0 or i < 40:
+            _assert_table_is_recount(cm, (history, i, fn.__name__))
+    _assert_table_is_recount(cm, (history, "the end"))
+    assert taken > 2000
+    # the oracle's own priorities, against the records
+    for a in allocs.values():
+        if a.node_id in nodes:
+            row = cm.row_of[a.node_id]
+            slot = cm.alloc_ids[row].index(a.id)
+            assert cm.alloc_prio[row, slot] == (
+                a.job.priority if a.job is not None else 50)
+    if history == "the rows grow":
+        assert cm.n_rows >= 4 * rows0
+    if history == "the slots grow":
+        assert cm.alloc_live.shape[1] >= 4 * width0
+
+
+def test_fsm_restore_ends_with_the_slot_table_exact():
+    from nomad_tpu.raft.fsm import MessageType, NomadFSM
+    from nomad_tpu.state import StateStore
+
+    rng = np.random.default_rng(9)
+    nodes = [mock.node() for _ in range(3)]
+    held = [_table_alloc(rng, nodes[i % 3].id) for i in range(14)]
+    stopped = _table_alloc(rng, nodes[0].id)
+    stopped.client_status = "complete"
+    live = NomadFSM(StateStore())
+    for i, (kind, payload) in enumerate(
+            [(MessageType.NODE_REGISTER, {"node": n}) for n in nodes]
+            + [(MessageType.ALLOC_UPDATE, {"allocs": held + [stopped]})], 1):
+        live.apply(i, kind, copy.deepcopy(payload))
+    restored = NomadFSM(StateStore())
+    restored.restore(live.snapshot())
+    for fsm in (live, restored):
+        cm = fsm.store.matrix
+        assert cm.lock is fsm.store._lock
+        _assert_table_is_recount(cm, fsm)
+        assert cm.alloc_live.sum() == len(held)
+        assert stopped.id not in {i for ids in cm.alloc_ids for i in ids}
 
 
 def test_attr_ordinals_lexical():

@@ -3,6 +3,7 @@ import numpy as np
 import pytest
 
 from nomad_tpu import mock
+from nomad_tpu.encode import pad_to_bucket
 from nomad_tpu.ops.preempt import (
     net_priority,
     preempt_for_task_group,
@@ -225,7 +226,7 @@ def _search_of(h, rows, seed):
     rng = np.random.default_rng([seed, 0xFEA5])
     search = preemption.Preemptor(h.store.snapshot(), 50)
     search._build()
-    ids = sorted(search._cand_index, key=search._cand_index.get)
+    ids = _valid_ids(search)
     search.invalidate({ids[i] for i in rng.choice(
         len(ids), len(ids) // 10, replace=False)})
     feasible = np.zeros(h.store.matrix.n_rows, bool)
@@ -270,8 +271,8 @@ def _by_the_loop(search, feasible, demand, used, static_ports=None,
         cm.capacity, used - freed_all + demand[None, :]) / 18.0
     ranked = []
     for row in rows:
-        evicted = [search.cand_allocs[row][i]
-                   for i in np.flatnonzero(picked[row])]
+        evicted = search.snapshot.allocs.rows(
+            search.cand_ids[row][k] for k in np.flatnonzero(picked[row]))
         p_score = preemption_score(net_priority(
             [a.job.priority for a in evicted]))
         fit = float(fit_all[row])
@@ -282,6 +283,12 @@ def _by_the_loop(search, feasible, demand, used, static_ports=None,
     best = max(ranked, key=lambda e: e.score)
     ranked.sort(key=lambda e: (e.score, e.row), reverse=True)
     return [best] + [e for e in ranked if e is not best and e.evicted]
+
+
+def _valid_ids(search):
+    """The ids of the valid candidates, by row and index."""
+    return [search.cand_ids[row][k]
+            for row, k in zip(*np.nonzero(search.cand_valid))]
 
 
 def _plain(found):
@@ -375,6 +382,187 @@ def test_a_single_feasible_row_is_searched_alone(monkeypatch):
         if got is not None:
             assert _plain([got]) == _plain([want])
     assert seen and set(seen) == {1}
+
+
+# ------------------------------------- the view against the walk it replaced
+
+def _by_the_walk(snapshot, job_priority):
+    """The candidates as `Preemptor._build` found them until the matrix
+    kept them: every node's allocations read from the snapshot.
+    -> {row: {allocation id: (resources, priority)}}, rows with none left
+    out"""
+    from nomad_tpu.encode.matrixizer import comparable_vec
+    out = {}
+    for node_id, row in snapshot.matrix.row_of.items():
+        for a in snapshot.allocs_by_node(node_id):
+            if a.terminal_status():
+                continue
+            prio = a.job.priority if a.job is not None else 50
+            if job_priority - prio < preemption.PRIORITY_DELTA:
+                continue
+            out.setdefault(row, {})[a.id] = (
+                tuple(comparable_vec(a.comparable_resources()).tolist()),
+                prio)
+    return out
+
+
+def _of_the_view(search):
+    out = {}
+    for row, k in zip(*np.nonzero(search.cand_valid)):
+        out.setdefault(int(row), {})[search.cand_ids[row][k]] = (
+            tuple(search.cand_res[row, k].tolist()),
+            int(search.cand_prio[row, k]))
+    assert sum(map(len, out.values())) == search.cand_valid.sum()
+    return out
+
+
+@pytest.mark.parametrize("job_priority", [30, 50, 100])
+def test_the_view_holds_what_the_walk_of_the_snapshot_found(job_priority):
+    """A few hundred nodes with a history behind them (fillers stopped,
+    reported failed, replaced, one without a job, a node gone): per row
+    the same candidates with the same resources and priorities, and the
+    padding the walk would have chosen."""
+    h, rows = _random_world(13)
+    rng = np.random.default_rng(13)
+    cm = h.store.matrix
+    fillers = sorted(h.store.snapshot().allocs.values(), key=lambda a: a.name)
+    gone = [fillers[i] for i in rng.choice(len(fillers), 300, replace=False)]
+    h.store.delete_eval(h.next_index(), [], [a.id for a in gone[:100]])
+    failed = [a.copy_shallow() for a in gone[100:200]]
+    for a in failed:
+        a.client_status = "failed"
+    h.store.upsert_allocs(h.next_index(), failed)
+    stray = mock.alloc_for(mock.job(priority=20), cm.node_ids[rows[3]])
+    stray.job = None                     # no such job: priority 50
+    late = [mock.alloc_for(gone[200 + i].job, cm.node_ids[rows[i]])
+            for i in range(40)]
+    h.store.upsert_allocs(h.next_index(), late + [stray])
+    h.store.delete_node(h.next_index(), cm.node_ids[rows[7]])
+    assert h.store.snapshot().allocs.get(stray.id).job is None
+
+    snapshot = h.store.snapshot()
+    search = preemption.Preemptor(snapshot, job_priority)
+    search._build()
+    want = _by_the_walk(snapshot, job_priority)
+    assert _of_the_view(search) == want
+    assert (stray.id in want[rows[3]]) == (job_priority == 100)
+    widest = max(map(len, want.values()))
+    assert search.cand_valid.shape == (
+        cm.n_rows, pad_to_bucket(widest, minimum=4))
+    assert search.max_steps == search.cand_valid.shape[1]
+    # candidates fill a row from index 0
+    assert (search.cand_valid[:, :-1] >= search.cand_valid[:, 1:]).all()
+
+
+def test_an_allocation_committed_after_the_read_point_is_never_evicted():
+    """The matrix is live and the snapshot is a read point: fillers that
+    commit after the eval took its snapshot are in the table and not in
+    any `Eviction`; a row that could only answer with one is skipped."""
+    h, rows = _random_world(21)
+    cm = h.store.matrix
+    snapshot = h.store.snapshot()
+    seen = set(snapshot.allocs.keys())
+    # every node gets a large tier-20 filler the snapshot has not seen:
+    # the lowest tier and the closest to the ask, so the search wants it
+    low = mock.job(priority=20)
+    h.store.upsert_job(h.next_index(), low)
+    late = []
+    for row in rows:
+        a = mock.alloc_for(low, cm.node_ids[row], index=len(late))
+        (task,) = a.allocated_resources.tasks.values()
+        task.cpu_shares, task.memory_mb = 400, 700
+        a.allocated_resources.shared_disk_mb = 0
+        late.append(a)
+    h.store.upsert_allocs(h.next_index(), late)
+    before = _counter("nomad.sched.preempt_unseen")
+    search = preemption.Preemptor(snapshot, 50)
+    feasible = np.zeros(cm.n_rows, bool)
+    feasible[rows] = True
+    used = cm.used.copy()
+    demand = _ask(h, rows, 2)
+    in_view = 0
+    for _ in range(4):                    # later rounds use what is left
+        found = search.find_many(feasible, demand, used, 64)
+        in_view += len(found)
+        for f in found:
+            assert f.evicted and {a.id for a in f.evicted} <= seen
+            search.invalidate({a.id for a in f.evicted})
+    assert {a.id for a in late} <= {i for ids in search.cand_ids for i in ids}
+    assert _counter("nomad.sched.preempt_unseen") > before
+    assert in_view > 0, "rows that answer with what the snapshot holds"
+
+
+def _counter(name):
+    return {c["Name"]: c["Count"] for c in
+            global_metrics.snapshot()["Counters"]}.get(name, 0)
+
+
+def test_views_taken_while_a_writer_commits_are_never_torn():
+    """A writer commits and stops allocations whose resources spell their
+    own name, on slots it keeps reusing, while views are taken: no slot of
+    any view pairs one allocation's id with another's resources, and a
+    plan's allocations are in a view all together or not at all."""
+    import sys
+    import threading
+
+    h = Harness()
+    job = mock.job(priority=20)
+    h.store.upsert_job(h.next_index(), job)
+    nodes = [mock.node() for _ in range(24)]
+    for n in nodes:
+        h.store.upsert_node(h.next_index(), n)
+    spelled, batch_of = {}, {}            # id -> resources, id -> its plan
+    stop = threading.Event()
+    failed = []
+
+    def writer():
+        rng = np.random.default_rng(4)
+        live = []
+        try:
+            for plan in range(400):
+                batch = []
+                for k in range(3):
+                    a = mock.alloc_for(job, nodes[rng.integers(24)].id,
+                                       index=3 * plan + k)
+                    (task,) = a.allocated_resources.tasks.values()
+                    task.cpu_shares = 3 * plan + k + 1
+                    task.memory_mb = 7 * (3 * plan + k) + 1
+                    spelled[a.id] = (task.cpu_shares, task.memory_mb)
+                    batch_of[a.id] = plan
+                    batch.append(a)
+                h.store.upsert_allocs(h.next_index(), batch)
+                live.append(batch)
+                if len(live) > 6:         # stop an older plan: slots free
+                    old = live.pop(int(rng.integers(len(live))))
+                    for a in old:
+                        a.client_status = "complete"
+                    h.store.upsert_allocs(h.next_index(), old)
+        except Exception as e:            # pragma: no cover
+            failed.append(e)
+        finally:
+            stop.set()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    views = 0
+    try:
+        t = threading.Thread(target=writer)
+        t.start()
+        while not stop.is_set() or views < 3:
+            search = preemption.Preemptor(h.store.snapshot(), 50)
+            search._build()
+            res = search.cand_res[search.cand_valid]
+            plans = {}
+            for aid, vec in zip(_valid_ids(search), res.tolist()):
+                assert (vec[0], vec[1]) == spelled.get(aid)
+                plans[batch_of[aid]] = plans.get(batch_of[aid], 0) + 1
+            assert set(plans.values()) <= {3}, plans
+            views += 1
+        t.join(60)
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+    assert not failed and not t.is_alive() and views >= 3
 
 
 def _sample_counts():
