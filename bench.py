@@ -835,6 +835,7 @@ def bench_kernel_c2m_scale():
     """Kernel-only: one dense placement scan at 10K-node scale."""
     from nomad_tpu import mock
     from nomad_tpu.encode import ClusterMatrix
+    from nomad_tpu.parallel.engine import get_engine
     from nomad_tpu.scheduler.stack import DenseStack
 
     cm = ClusterMatrix(initial_rows=16384)
@@ -851,10 +852,13 @@ def bench_kernel_c2m_scale():
     groups = [stack.compile_group(job, tg) for tg in job.task_groups]
     inp = stack.build_inputs(job, groups, [0] * 1024, {})
 
-    res = stack.place(inp)          # compile + run
+    eng, alg = get_engine(), stack.spread_algorithm
+    res, ticket = eng.place(cm, inp, spread_algorithm=alg)  # compile + run
+    eng.complete(ticket)
     t0 = time.time()
-    res = stack.place(inp)
+    res, ticket = eng.place(cm, inp, spread_algorithm=alg)
     dt = time.time() - t0
+    eng.complete(ticket)
     placed = int((res.node[:1024] >= 0).sum())
     log(f"kernel: {placed} placements over 10K nodes in {dt:.3f}s "
         f"({placed/dt:.0f} placements/s {_on_devices()})")

@@ -16,7 +16,7 @@ from typing import Dict, List, Optional, Set
 
 import numpy as np
 
-from nomad_tpu.encode.matrixizer import ClusterMatrix
+from nomad_tpu.encode.matrixizer import ClusterMatrix, comparable_vec
 from nomad_tpu.structs import Allocation, AllocClientStatus, AllocDesiredStatus, Job, TaskGroup
 from nomad_tpu.structs.alloc import (
     AllocatedResources,
@@ -96,6 +96,23 @@ class PortClaims:
         p = ((w0 + w) << 5) + bit
         self.claimed.setdefault(row, set()).add(p)
         return p
+
+
+def allocs_leave(used: np.ndarray, row: int, allocs, freed_ports=None,
+                 preemptor=None, deltas=None) -> None:
+    """Allocations that leave node `row` (stopped, preempted) give their
+    room back to `used`; where the caller keeps them, their ports are
+    free ({row: set}), the `preemptor` offers them to no later slot and
+    `deltas` takes (row, what was given back) for the engine."""
+    for a in allocs:
+        vec = comparable_vec(a.comparable_resources())
+        used[row] -= vec
+        if deltas is not None:
+            deltas.append((row, -vec))
+        if freed_ports is not None:
+            freed_ports.setdefault(row, set()).update(a.ports())
+    if preemptor is not None and allocs:
+        preemptor.invalidate({a.id for a in allocs})
 
 
 def build_allocation(

@@ -26,8 +26,7 @@ from nomad_tpu.encode.matrixizer import (
     RES_NET,
     pad_to_bucket,
 )
-from nomad_tpu.ops.place import PlaceInputs, PlaceResult
-from nomad_tpu.parallel.engine import get_engine
+from nomad_tpu.ops.place import PlaceInputs
 from nomad_tpu.scheduler import feasible as fz
 from nomad_tpu.structs.job import Constraint, Job, Operand, Spread, TaskGroup
 from nomad_tpu.structs.config import (
@@ -83,6 +82,8 @@ class CompiledGroup:
     feasible_pre_ports: Optional[np.ndarray] = None   # bool[N]
     static_ports: List[int] = field(default_factory=list)
     dynamic_ports: int = 0
+    # [(task, its DeviceRequest)] every device ask of the group
+    device_asks: List[Tuple[object, object]] = field(default_factory=list)
     # nodes with device COUNT capacity but no free instances: preemption
     # targets for PreemptForDevice
     device_blocked: Optional[np.ndarray] = None       # bool[N]
@@ -101,6 +102,16 @@ class CompiledGroup:
     # blocked-eval unblocking — a down node or exhausted device must not
     # mark its whole class permanently ineligible
     class_feasible: Optional[np.ndarray] = None       # bool[N]
+
+    @property
+    def uncoupled(self) -> bool:
+        """No placement of the group bears on its next (no spread, no
+        distinct_*, no port or device instance for the host to hand out):
+        generic.BULK_MIN such slots or more go to the bulk wave, else scan."""
+        return not (self.spreads or self.distinct_hosts_job
+                    or self.distinct_hosts_tg or self.distinct_property
+                    or self.static_ports or self.dynamic_ports
+                    or self.device_asks)
 
 
 class DenseStack:
@@ -131,13 +142,13 @@ class DenseStack:
         job_constraints = list(job.constraints)
         tg_constraints = list(tg.constraints)
         drivers = []
-        dev_reqs = []
+        device_asks = []
         affinities = list(job.affinities) + list(tg.affinities)
         for t in tg.tasks:
             tg_constraints += list(t.constraints)
             affinities += list(t.affinities)
             drivers.append(t.driver)
-            dev_reqs.extend(t.resources.devices)
+            device_asks.extend((t, r) for r in t.resources.devices)
         constraints = job_constraints + tg_constraints
 
         distinct_hosts_job = any(c.operand == Operand.DISTINCT_HOSTS
@@ -162,9 +173,10 @@ class DenseStack:
         # preemption-eligibility snapshot so device preemption can still
         # target instance-exhausted nodes
         fit = None
-        if dev_reqs:
+        if device_asks:
             with tracing.span("sched.device_mask"):
-                fit = fz.device_fit(cm, dev_reqs, self.device_grants)
+                fit = fz.device_fit(cm, [r for _, r in device_asks],
+                                    self.device_grants)
             mask &= fit.capable
         feasible_pre_ports = mask.copy()
         device_blocked = None
@@ -218,7 +230,7 @@ class DenseStack:
                              distinct_property=distinct_property,
                              feasible_pre_ports=feasible_pre_ports,
                              static_ports=static_ports,
-                             dynamic_ports=dyn,
+                             dynamic_ports=dyn, device_asks=device_asks,
                              device_blocked=device_blocked,
                              place_cap=place_cap,
                              dev_score=fit.score if fit else None,
@@ -381,24 +393,3 @@ class DenseStack:
             place_cap=place_cap, dev_score=dev_score, has_dev=has_dev,
             demand=demand, slot_tg=slot_tg, slot_active=slot_active,
         )
-
-    def place(self, inputs: PlaceInputs, deltas=None) -> PlaceResult:
-        """Run the placement kernel.  Routed through the process-wide
-        PlacementEngine so concurrent evals coalesce into one device
-        dispatch; `deltas` is the sparse (row, f32[R]) usage-adjustment
-        list already applied to inputs.used (the engine re-applies it to a
-        dispatch-time basis in the batched path).
-
-        Sets `self.last_ticket`: the caller must hand it back to
-        `engine.complete()` once the resulting plan is submitted (the
-        generic scheduler does), releasing the in-flight usage overlay."""
-        result, self.last_ticket = get_engine().place(
-            self.cm, inputs, deltas, spread_algorithm=self.spread_algorithm)
-        return result
-
-    def release(self) -> None:
-        """Release the in-flight usage contribution of the last place()."""
-        ticket = getattr(self, "last_ticket", None)
-        if ticket is not None:
-            get_engine().complete(ticket)
-            self.last_ticket = None

@@ -24,9 +24,8 @@ from typing import Dict, List, Optional, Set, Tuple
 import numpy as np
 
 from nomad_tpu import tracing
-from nomad_tpu.encode.matrixizer import comparable_vec
 
-from nomad_tpu.scheduler.placement import PortClaims, build_allocation
+from nomad_tpu.scheduler.placement import PortClaims, allocs_leave, build_allocation
 from nomad_tpu.scheduler.preemption import Eviction, Preemptor, fit_score_meta
 from nomad_tpu.scheduler.reconcile import tasks_updated
 from nomad_tpu.scheduler.stack import DenseStack
@@ -114,8 +113,7 @@ class SystemScheduler:
                 del live[key]
                 row = cm.row_of.get(a.node_id)
                 if row is not None:
-                    cr = a.comparable_resources()
-                    used[row] -= comparable_vec(cr)
+                    allocs_leave(used, row, (a,))
         return plan, job, groups, live, terminal_newest, used
 
     def _place_nodes(self, plan, job, groups, live, terminal_newest, used):
@@ -175,8 +173,7 @@ class SystemScheduler:
                     continue
                 plan.append_stopped_alloc(
                     cur, "alloc not needed due to job update")
-                cr = cur.comparable_resources()
-                used[row] -= comparable_vec(cr)
+                allocs_leave(used, row, (cur,))
             elif self.sysbatch:
                 t = terminal_newest.get(key)
                 if t is not None and t.ran_successfully():
@@ -221,9 +218,7 @@ class SystemScheduler:
             for a in evict.evicted:
                 plan.append_preempted_alloc(a, alloc.id)
             self._evict_s += perf_counter() - t0
-            for a in evict.evicted:
-                cr = a.comparable_resources()
-                used[row] -= comparable_vec(cr)
+            allocs_leave(used, row, evict.evicted)
         used[row] += d
         plan.append_alloc(alloc, None)
 

@@ -5,6 +5,7 @@ import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.encode import ClusterMatrix
+from nomad_tpu.parallel.engine import get_engine
 from nomad_tpu.scheduler.stack import DenseStack
 from nomad_tpu.structs.config import SchedulerConfiguration
 from nomad_tpu.structs.job import Affinity, Constraint, Operand, Spread, SpreadTarget
@@ -25,7 +26,10 @@ def run_place(cm, job, count=None, allocs_by_tg=None, config=None, penalty=None)
     for gi, g in enumerate(groups):
         slots += [gi] * (count if count is not None else g.tg.count)
     inp = stack.build_inputs(job, groups, slots, allocs_by_tg or {}, penalty_nodes=penalty)
-    return stack.place(inp), inp, slots
+    eng = get_engine()
+    res, ticket = eng.place(cm, inp, spread_algorithm=stack.spread_algorithm)
+    eng.complete(ticket)
+    return res, inp, slots
 
 
 def test_basic_placement_fills_all_slots():

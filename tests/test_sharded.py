@@ -6,6 +6,7 @@ import pytest
 from nomad_tpu import mock
 from nomad_tpu.encode import ClusterMatrix
 from nomad_tpu.parallel import make_mesh, place_eval_batch_sharded, stack_inputs
+from nomad_tpu.parallel.engine import get_engine
 from nomad_tpu.scheduler.stack import DenseStack
 
 
@@ -58,7 +59,9 @@ def test_sharded_with_spread_and_affinity():
     st = DenseStack(cm)
     groups = [st.compile_group(j, tg) for tg in j.task_groups]
     inp = st.build_inputs(j, groups, [0] * 4, {})
-    single = st.place(inp)
+    single, ticket = get_engine().place(
+        cm, inp, spread_algorithm=st.spread_algorithm)
+    get_engine().complete(ticket)
 
     mesh = make_mesh(n_wave_shards=1, n_node_shards=8)
     batch = stack_inputs([inp])
