@@ -4,9 +4,11 @@ One jitted `lax.scan` places every missing allocation of an evaluation:
 each step scores ALL candidate nodes at once (feasibility mask -> resource
 fit -> binpack/spread fit score -> anti-affinity / reschedule-penalty /
 affinity / spread scoring -> normalization -> masked argmax) and the carry
-threads the proposed usage matrix, per-taskgroup co-placement counts, and
-per-spread-attribute value counts, so sequential placement coupling
-(reference scheduler/context.go:173-210 ProposedAllocs) is preserved.
+threads the proposed usage matrix, per-taskgroup co-placement counts,
+per-spread-attribute value counts and, for distinct_hosts and
+distinct_property, the rows a scope has taken and its allocations a
+value, so sequential placement coupling (reference
+scheduler/context.go:173-210 ProposedAllocs) is preserved.
 
 This single kernel replaces the reference's entire iterator stack for one
 eval (scheduler/stack.go:344-439 GenericStack.Select and everything it
@@ -99,6 +101,18 @@ class PlaceInputs:
     # without device affinities has `has_dev` false and the scorer off.
     dev_score: jax.Array       # f32[G, N]
     has_dev: jax.Array         # bool[G]
+    # distinct_hosts and distinct_property (feasible.go DistinctHosts- and
+    # DistinctPropertyIterator, propertyset.go) as a carry: H host scopes
+    # and P property constraints, both 0 for a job with neither.  A
+    # job-level constraint is one scope that every group checks and
+    # marks, a group-level one its group's own.  Values are indexed as
+    # spread's are (W = the node lacks the attribute, and takes none).
+    hosts_taken: jax.Array     # bool[H, N] rows that hold one of the scope
+    hosts_of: jax.Array        # bool[G, H]
+    prop_vidx: jax.Array       # i32[P, N]
+    prop_counts: jax.Array     # i32[P, W+1] allocations of the scope a value
+    prop_limit: jax.Array      # i32[P]
+    prop_of: jax.Array         # bool[G, P]
     # slots
     demand: jax.Array          # f32[S, R]
     slot_tg: jax.Array         # i32[S]
@@ -161,13 +175,48 @@ def _spread_boost(inp: PlaceInputs, g: jax.Array, counts: jax.Array) -> jax.Arra
     return jnp.sum(jnp.where(active[:, None], boost, 0.0), axis=0)
 
 
+def place_carry0(inp: PlaceInputs, used: jax.Array):
+    """What a scan over one eval's slots starts from."""
+    return (used, inp.tg_count, inp.spread_counts, inp.place_cap,
+            inp.hosts_taken, inp.prop_counts)
+
+
+def distinct_open(inp: PlaceInputs, g: jax.Array, hosts_taken: jax.Array,
+                  prop_counts: jax.Array) -> jax.Array:
+    """bool[N]: the rows distinct_hosts and distinct_property leave open
+    to group `g`: no allocation of a host scope of the group there, and
+    every property of the group set there with its value under the limit.
+    Shared with the node-sharded step (the counts are replicated)."""
+    open_ = jnp.ones(inp.feasible.shape[1], bool)
+    if hosts_taken.shape[0]:
+        open_ &= ~jnp.any(inp.hosts_of[g][:, None] & hosts_taken, axis=0)
+    if prop_counts.shape[0]:
+        W = prop_counts.shape[1] - 1
+        cur = jnp.take_along_axis(
+            prop_counts, jnp.minimum(inp.prop_vidx, W), axis=1)   # [P, N]
+        full = (inp.prop_vidx >= W) | (cur >= inp.prop_limit[:, None])
+        open_ &= ~jnp.any(inp.prop_of[g][:, None] & full, axis=0)
+    return open_
+
+
+def prop_counts_add(inp: PlaceInputs, g: jax.Array, prop_counts: jax.Array,
+                    v: jax.Array, ok: jax.Array) -> jax.Array:
+    """The counts with one more of group `g` on a node of values `v`
+    i32[P] (W = missing), where `ok`."""
+    Wp1 = prop_counts.shape[1]
+    hit = jax.nn.one_hot(jnp.minimum(v, Wp1 - 1), Wp1, dtype=jnp.int32)
+    return prop_counts + hit * (inp.prop_of[g] & (v < Wp1 - 1)
+                                & ok)[:, None]
+
+
 def _place_step(inp: PlaceInputs, spread_algorithm: bool, carry, slot):
-    used, tg_count, spread_counts, place_cap = carry
+    used, tg_count, spread_counts, place_cap, hosts_taken, prop_counts = carry
     g = inp.slot_tg[slot]
     d = inp.demand[slot]
     active = inp.slot_active[slot]
 
-    feas = inp.feasible[g] & (place_cap[g] != 0)
+    feas = inp.feasible[g] & (place_cap[g] != 0) \
+        & distinct_open(inp, g, hosts_taken, prop_counts)
     util = used + d
     fits = jnp.all(util <= inp.capacity, axis=-1) & feas
 
@@ -218,6 +267,9 @@ def _place_step(inp: PlaceInputs, spread_algorithm: bool, carry, slot):
     upd = jax.nn.one_hot(jnp.minimum(v, Vp1 - 1), Vp1, dtype=spread_counts.dtype)
     upd = upd * (inp.spread_active[g] & (v < Vp1 - 1))[:, None] * ok
     spread_counts = spread_counts.at[g].add(upd)
+    hosts_taken = hosts_taken | (inp.hosts_of[g][:, None] & sel_onehot[None, :])
+    prop_counts = prop_counts_add(inp, g, prop_counts, inp.prop_vidx[:, sel],
+                                  ok)
 
     top_scores, top_nodes = jax.lax.top_k(masked, TOP_K)
     out = (
@@ -229,7 +281,8 @@ def _place_step(inp: PlaceInputs, spread_algorithm: bool, carry, slot):
         top_nodes.astype(jnp.int32),
         top_scores,
     )
-    return (used, tg_count, spread_counts, place_cap), out
+    return (used, tg_count, spread_counts, place_cap, hosts_taken,
+            prop_counts), out
 
 
 def _pack_outputs(node, score, fit_s, n_eval, n_exh, top_n, top_s) -> jax.Array:
@@ -265,10 +318,10 @@ def place_eval_packed_jit(inp: PlaceInputs, spread_algorithm: bool = False):
     """Single-eval kernel with packed output: returns (f32[S, 5+2K]
     packed outputs, f32[N, R] final usage)."""
     S = inp.demand.shape[0]
-    carry0 = (inp.used, inp.tg_count, inp.spread_counts, inp.place_cap)
     step = functools.partial(_place_step, inp, spread_algorithm)
-    (used, _, _, _), outs = jax.lax.scan(step, carry0, jnp.arange(S))
-    return _pack_outputs(*outs), used
+    carry, outs = jax.lax.scan(step, place_carry0(inp, inp.used),
+                               jnp.arange(S))
+    return _pack_outputs(*outs), carry[0]
 
 
 @functools.partial(jax.jit, static_argnames=("spread_algorithm",))
@@ -276,13 +329,13 @@ def place_eval_jit(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceRes
     """Place all slots of one evaluation.  Shapes are static; callers bucket
     N/G/S/K/V so the jit cache stays small."""
     S = inp.demand.shape[0]
-    carry0 = (inp.used, inp.tg_count, inp.spread_counts, inp.place_cap)
     step = functools.partial(_place_step, inp, spread_algorithm)
-    (used, _, _, _), outs = jax.lax.scan(step, carry0, jnp.arange(S))
+    carry, outs = jax.lax.scan(step, place_carry0(inp, inp.used),
+                               jnp.arange(S))
     node, score, fit_s, n_eval, n_exh, top_n, top_s = outs
     return PlaceResult(node=node, score=score, fit_score=fit_s,
                        nodes_evaluated=n_eval, nodes_exhausted=n_exh,
-                       top_nodes=top_n, top_scores=top_s, used=used)
+                       top_nodes=top_n, top_scores=top_s, used=carry[0])
 
 
 # --------------------------------------------------------------------------
@@ -310,17 +363,23 @@ def place_eval_jit(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceRes
 # --------------------------------------------------------------------------
 
 def heavy_dims(inp: PlaceInputs):
-    """(G, N, K, Vp1) of one eval's inputs."""
+    """(G, N, K, Vp1, H, P, Wp1) of one eval's inputs."""
     G, N = inp.feasible.shape
     K = inp.spread_wfrac.shape[1]
     Vp1 = inp.spread_desired.shape[2]
-    return G, N, K, Vp1
+    H = inp.hosts_taken.shape[0]
+    P, Wp1 = inp.prop_counts.shape
+    return G, N, K, Vp1, H, P, Wp1
 
 
 _HEAVY_FIELDS = ("feasible", "affinity", "penalty", "tg_count", "place_cap",
                  "spread_vidx", "spread_desired", "spread_counts",
                  "has_affinity", "desired_count", "spread_targeted",
-                 "spread_wfrac", "spread_active", "dev_score", "has_dev")
+                 "spread_wfrac", "spread_active", "dev_score", "has_dev",
+                 # empty for a job with no distinct_* constraint: its
+                 # block and digest are what they were without these
+                 "hosts_taken", "hosts_of", "prop_vidx", "prop_counts",
+                 "prop_limit", "prop_of")
 
 
 def pack_heavy(inp: PlaceInputs) -> np.ndarray:
@@ -340,7 +399,8 @@ def heavy_digest(inp: PlaceInputs) -> bytes:
     return h.digest()
 
 
-def _unpack_heavy(h: jax.Array, G: int, N: int, K: int, Vp1: int):
+def _unpack_heavy(h: jax.Array, G: int, N: int, K: int, Vp1: int,
+                  H: int, P: int, Wp1: int):
     """In-kernel inverse of pack_heavy; returns a field dict."""
     o = 0
     def take(n, shape):
@@ -364,6 +424,12 @@ def _unpack_heavy(h: jax.Array, G: int, N: int, K: int, Vp1: int):
         spread_active=take(G * K, (G, K)) > 0.5,
         dev_score=take(G * N, (G, N)),
         has_dev=take(G, (G,)) > 0.5,
+        hosts_taken=take(H * N, (H, N)) > 0.5,
+        hosts_of=take(G * H, (G, H)) > 0.5,
+        prop_vidx=take(P * N, (P, N)).astype(jnp.int32),
+        prop_counts=take(P * Wp1, (P, Wp1)).astype(jnp.int32),
+        prop_limit=take(P, (P,)).astype(jnp.int32),
+        prop_of=take(G * P, (G, P)) > 0.5,
     )
 
 
@@ -417,7 +483,7 @@ def place_batch_packed_jit(capacity: jax.Array,     # f32[N, R]
                            used0: jax.Array,        # f32[N, R] (device)
                            heavy: tuple,            # E x f32[Lh] (device)
                            dyn: jax.Array,          # f32[E*Ll]
-                           dims: tuple,             # (G, N, K, Vp1, S, D)
+                           dims: tuple,             # heavy_dims + (S, D)
                            spread_algorithm: bool = False):
     """Chained batch placement over the packed transport: `heavy` is a
     tuple of E device-resident per-eval blocks (cache hits ship nothing),
@@ -434,7 +500,7 @@ def place_batch_packed_jit(capacity: jax.Array,     # f32[N, R]
     plan_apply.go partial commit) with a conflict-free device-side
     pipeline; the serialized plan applier still re-validates as defense
     in depth."""
-    G, N, K, Vp1, S, D = dims
+    *hdims, S, D = dims
     R = capacity.shape[1]
     E = len(heavy)
     hstack = jnp.stack(heavy)
@@ -442,16 +508,16 @@ def place_batch_packed_jit(capacity: jax.Array,     # f32[N, R]
 
     def eval_step(used, hl):
         h, l = hl
-        f = _unpack_heavy(h, G, N, K, Vp1)
+        f = _unpack_heavy(h, *hdims)
         demand, slot_tg, slot_active, delta_rows, delta_vals = \
             _unpack_light(l, S, R, D)
         used = used.at[delta_rows].add(delta_vals, mode="drop")
         inp = PlaceInputs(capacity=capacity, used=used, demand=demand,
                           slot_tg=slot_tg, slot_active=slot_active, **f)
-        carry0 = (used, f["tg_count"], f["spread_counts"], f["place_cap"])
         step = functools.partial(_place_step, inp, spread_algorithm)
-        (used_f, _, _, _), outs = jax.lax.scan(step, carry0, jnp.arange(S))
-        return used_f, _pack_outputs(*outs)
+        carry, outs = jax.lax.scan(step, place_carry0(inp, used),
+                                   jnp.arange(S))
+        return carry[0], _pack_outputs(*outs)
 
     used_final, packed = jax.lax.scan(eval_step, used0, (hstack, light))
     return packed, used_final

@@ -222,6 +222,7 @@ class _Request:
         # sharing a bucket batch together regardless of raw slot count
         return (id(self.cm), self.spread_algorithm, i.feasible.shape,
                 i.spread_vidx.shape, i.spread_desired.shape,
+                i.hosts_taken.shape, i.prop_counts.shape,
                 _s_bucket(i.demand.shape[0]), i.demand.shape[1])
 
 
@@ -375,7 +376,12 @@ class PlacementEngine:
                       # port asks (place_on): placements of groups that
                       # ask a port, and those the host could not assign
                       # ("ports exhausted") on the node the kernel chose
-                      "port_placements": 0, "port_fallbacks": 0}
+                      "port_placements": 0, "port_fallbacks": 0,
+                      # distinct_hosts / distinct_property
+                      # (_materialise_round): slots of groups under
+                      # either that a kernel pass was given, and those
+                      # it returned no row for
+                      "distinct_slots": 0, "distinct_unplaced": 0}
         self._cache = _DeviceCache()
         # device-resident worlds: (id(cm), N, mesh identity) ->
         # DeviceWorld (epoch-uploaded capacity/basis, scatter deltas);
@@ -1109,8 +1115,9 @@ class PlacementEngine:
         "feasible", "affinity", "has_affinity", "desired_count",
         "penalty", "tg_count", "spread_vidx", "spread_desired",
         "spread_targeted", "spread_wfrac", "spread_counts",
-        "spread_active", "place_cap", "dev_score", "has_dev", "demand",
-        "slot_tg", "slot_active")
+        "spread_active", "place_cap", "dev_score", "has_dev",
+        "hosts_taken", "hosts_of", "prop_vidx", "prop_counts", "prop_limit",
+        "prop_of", "demand", "slot_tg", "slot_active")
 
     def _stack_deltas(self, deltas_per_req, E: int, N: int):
         R = NUM_RESOURCE_DIMS
@@ -1530,7 +1537,6 @@ class PlacementEngine:
         import jax
 
         i0 = reqs[0].inputs
-        G, N, K, Vp1 = heavy_dims(i0)
         S = _s_bucket(i0.demand.shape[0])
         R = NUM_RESOURCE_DIMS
         D = _DELTA_BUCKET
@@ -1556,7 +1562,8 @@ class PlacementEngine:
             self.stats["cache_misses"] = self._cache.misses
             dyn_dev = jax.device_put(dyn)  # analysis: allow(transfer-purity) — per-dispatch dynamic leaf (basis deltas + light blocks): payload that must ship, sent explicitly so the runtime guard stays armed
             packed, _used_final = place_batch_packed_jit(
-                cap_dev, used_dev, tuple(heavy), dyn_dev, (G, N, K, Vp1, S, D),
+                cap_dev, used_dev, tuple(heavy), dyn_dev,
+                heavy_dims(i0) + (S, D),
                 spread_algorithm=reqs[0].spread_algorithm)
         self.stats["put_s"] += sp.seconds
         return packed

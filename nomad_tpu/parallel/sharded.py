@@ -36,7 +36,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from nomad_tpu import knobs
 from nomad_tpu.analysis import recompile
 from nomad_tpu.ops.fit import score_fit
-from nomad_tpu.ops.place import PlaceInputs, PlaceResult, TOP_K
+from nomad_tpu.ops.place import (
+    PlaceInputs, PlaceResult, TOP_K, distinct_open, place_carry0,
+    prop_counts_add)
 
 # transfer-purity / recompile-budget (nomad_tpu.analysis): mesh dispatch
 # is hot-path code; every jit built here is registered with the budget
@@ -131,6 +133,9 @@ _NODE_AXIS = {
     "capacity": 0, "used": 0,
     "feasible": 1, "affinity": 1, "penalty": 1, "tg_count": 1,
     "spread_vidx": 2, "place_cap": 1, "dev_score": 1,
+    "hosts_taken": 1, "prop_vidx": 1,
+    "hosts_of": None, "prop_counts": None, "prop_limit": None,
+    "prop_of": None,
     "has_affinity": None, "desired_count": None, "has_dev": None,
     "spread_desired": None, "spread_targeted": None, "spread_wfrac": None,
     "spread_counts": None, "spread_active": None,
@@ -138,17 +143,20 @@ _NODE_AXIS = {
 }
 
 
+_NDIM = {"capacity": 2, "used": 2, "feasible": 2, "affinity": 2,
+         "penalty": 2, "tg_count": 2, "spread_vidx": 3, "place_cap": 2,
+         "dev_score": 2, "has_dev": 1, "has_affinity": 1,
+         "desired_count": 1, "spread_desired": 3, "spread_targeted": 2,
+         "spread_wfrac": 2, "spread_counts": 3, "spread_active": 2,
+         "hosts_taken": 2, "hosts_of": 2, "prop_vidx": 2, "prop_counts": 2,
+         "prop_limit": 1, "prop_of": 2,
+         "demand": 2, "slot_tg": 1, "slot_active": 1}
+
+
 def _input_specs(batched: bool) -> PlaceInputs:
     specs = {}
     for name, axis in _NODE_AXIS.items():
-        ndim = {"capacity": 2, "used": 2, "feasible": 2, "affinity": 2,
-                "penalty": 2, "tg_count": 2, "spread_vidx": 3,
-                "place_cap": 2, "dev_score": 2, "has_dev": 1,
-                "has_affinity": 1, "desired_count": 1, "spread_desired": 3,
-                "spread_targeted": 2, "spread_wfrac": 2, "spread_counts": 3,
-                "spread_active": 2, "demand": 2, "slot_tg": 1,
-                "slot_active": 1}[name]
-        parts = [None] * ndim
+        parts = [None] * _NDIM[name]
         if axis is not None:
             parts[axis] = NODE_AXIS_NAME
         if batched:
@@ -162,14 +170,15 @@ def _place_step_sharded(inp: PlaceInputs, spread_algorithm: bool,
     """One placement step on a node shard (mirrors ops.place._place_step;
     the selection and carry updates go through 'node_shard'
     collectives)."""
-    used, tg_count, spread_counts, place_cap = carry
+    used, tg_count, spread_counts, place_cap, hosts_taken, prop_counts = carry
     g = inp.slot_tg[slot]
     d = inp.demand[slot]
     active = inp.slot_active[slot]
     n_local = used.shape[0]
     global_rows = shard_offset + jnp.arange(n_local)
 
-    feas = inp.feasible[g] & (place_cap[g] != 0)
+    feas = inp.feasible[g] & (place_cap[g] != 0) \
+        & distinct_open(inp, g, hosts_taken, prop_counts)
     util = used + d
     fits = jnp.all(util <= inp.capacity, axis=-1) & feas
 
@@ -232,6 +241,14 @@ def _place_step_sharded(inp: PlaceInputs, spread_algorithm: bool,
     upd = jax.nn.one_hot(jnp.minimum(v, Vp1 - 1), Vp1, dtype=spread_counts.dtype)
     upd = upd * (inp.spread_active[g] & (v < Vp1 - 1))[:, None] * ok
     spread_counts = spread_counts.at[g].add(upd)
+    # distinct_*: the owning shard marks its row; the selected node's
+    # property values come as the spread's do
+    hosts_taken = hosts_taken | (inp.hosts_of[g][:, None] & sel_local[None, :])
+    if prop_counts.shape[0]:
+        pv = jax.lax.psum(
+            jnp.sum(jnp.where(sel_local[None, :], inp.prop_vidx, 0), axis=1),
+            NODE_AXIS_NAME)
+        prop_counts = prop_counts_add(inp, g, prop_counts, pv, ok)
 
     # per-slot metrics (global)
     fit_sel = jax.lax.psum(
@@ -254,7 +271,8 @@ def _place_step_sharded(inp: PlaceInputs, spread_algorithm: bool,
         top_i[order].astype(jnp.int32),
         top_s[order],
     )
-    return (used, tg_count, spread_counts, place_cap), out
+    return (used, tg_count, spread_counts, place_cap, hosts_taken,
+            prop_counts), out
 
 
 def _shard_body(inp: PlaceInputs, spread_algorithm: bool):
@@ -263,12 +281,12 @@ def _shard_body(inp: PlaceInputs, spread_algorithm: bool):
     n_local = inp.used.shape[0]
     shard_offset = idx * n_local
     S = inp.demand.shape[0]
-    carry0 = (inp.used, inp.tg_count, inp.spread_counts, inp.place_cap)
     step = functools.partial(_place_step_sharded, inp, spread_algorithm,
                              shard_offset)
-    (used, _, _, _), outs = jax.lax.scan(step, carry0, jnp.arange(S))
+    carry, outs = jax.lax.scan(step, place_carry0(inp, inp.used),
+                               jnp.arange(S))
     node, score, fit_s, n_eval, n_exh, top_i, top_s = outs
-    return node, score, fit_s, n_eval, n_exh, top_i, top_s, used
+    return node, score, fit_s, n_eval, n_exh, top_i, top_s, carry[0]
 
 
 def place_eval_batch_sharded(mesh: Mesh, stacked: PlaceInputs,
@@ -392,14 +410,7 @@ def _field_specs_batched() -> dict:
     for name, axis in _NODE_AXIS.items():
         if name in ("capacity", "used"):
             continue
-        ndim = {"feasible": 2, "affinity": 2, "penalty": 2, "tg_count": 2,
-                "spread_vidx": 3, "place_cap": 2, "dev_score": 2,
-                "has_dev": 1, "has_affinity": 1,
-                "desired_count": 1, "spread_desired": 3,
-                "spread_targeted": 2, "spread_wfrac": 2,
-                "spread_counts": 3, "spread_active": 2, "demand": 2,
-                "slot_tg": 1, "slot_active": 1}[name]
-        parts = [None] * ndim
+        parts = [None] * _NDIM[name]
         if axis is not None:
             parts[axis] = NODE_AXIS_NAME
         specs[name] = P(*([None] + parts))
@@ -444,13 +455,11 @@ def place_batch_sharded(mesh: Mesh, capacity, used0, fields: dict,
             used = _apply_deltas_local(used, dr, dv, shard_offset)
             inp = PlaceInputs(capacity=cap, used=used, **one)
             S = inp.demand.shape[0]
-            carry0 = (used, inp.tg_count, inp.spread_counts,
-                      inp.place_cap)
             step = functools.partial(_place_step_sharded, inp,
                                      spread_algorithm, shard_offset)
-            (used_f, _, _, _), outs = jax.lax.scan(step, carry0,
-                                                   jnp.arange(S))
-            return used_f, _pack_outputs(*outs)
+            carry, outs = jax.lax.scan(step, place_carry0(inp, used),
+                                       jnp.arange(S))
+            return carry[0], _pack_outputs(*outs)
 
         used_final, packed = jax.lax.scan(eval_step, u0,
                                           (flds, drows, dvals))

@@ -200,6 +200,15 @@ def _scalar_check(op: str, lval: Optional[str], rval: Optional[str]) -> bool:
     return False
 
 
+def more_than_an_equality(constraints: List[Constraint]) -> bool:
+    """Whether `constraints_mask` has more to do than one hash compare
+    over the rows (the `${attr.kernel.name} = linux` every job carries):
+    several constraints, or one whose operator walks the column's values."""
+    return len(constraints) > 1 or any(
+        c.operand not in (Operand.EQ, "==", "is", Operand.NEQ, "not")
+        for c in constraints)
+
+
 def constraints_mask(cm: ClusterMatrix, constraints: List[Constraint]) -> np.ndarray:
     mask = np.ones(cm.n_rows, dtype=bool)
     for c in constraints:

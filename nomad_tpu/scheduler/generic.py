@@ -22,7 +22,8 @@ from nomad_tpu.scheduler.preemption import Preemptor
 from nomad_tpu.scheduler.placement import (
     PortClaims, allocs_leave, build_allocation, materialize_bulk_allocs)
 from nomad_tpu.scheduler.reconcile import AllocReconciler, PlacementRequest
-from nomad_tpu.scheduler.stack import CompiledGroup, DenseStack
+from nomad_tpu.scheduler.stack import (
+    CompiledGroup, DenseStack, DistinctCarry)
 from nomad_tpu.scheduler.util import (
     adjust_queued_allocations, progress_made, tainted_nodes)
 from nomad_tpu.structs import Allocation, Evaluation, EvalStatus, Job
@@ -367,6 +368,11 @@ class PlacementPass:
         self.stopped_ids: Set[str] = set()
         # remaining allocs for anti-affinity / spread / distinct_*
         self.allocs_by_tg: Dict[str, List[Allocation]] = {}
+        # distinct_hosts / distinct_property: started from those, takes
+        # every placement of the plan (place_on); the kernel carries its
+        # own copy through a pass, and a row the host picks itself
+        # (sticky slot, device alternative, preemption) is asked of this
+        self.distinct: Optional[DistinctCarry] = None
         self.penalty_nodes: Dict[str, Set[str]] = {}
         self.preemptor: Optional[Preemptor] = None
         self.preempt_cache: Dict[int, List] = {}    # group -> found, unused
@@ -412,9 +418,15 @@ class PlacementPass:
         out of it and puts sticky slots back on their nodes.
         -> ([(request, row)] placed here, [request] still to place)"""
         cm, job, stack = self.cm, self.job, self.stack
+        self.stopped_ids = {sr.alloc.id for sr in stops}
+        for a in all_allocs:
+            if a.id in self.stopped_ids or a.terminal_status():
+                continue
+            self.allocs_by_tg.setdefault(a.task_group, []).append(a)
         with tracing.span("sched.feasible", cpu=True):
             self.groups = [stack.compile_group(job, tg)
                            for tg in job.task_groups]
+            self.distinct = DistinctCarry(cm, self.groups, self.allocs_by_tg)
         # constraint-only union, NOT g.feasible: readiness and capacity
         # are transient, and a blocked eval keyed on them would mark its
         # class ineligible forever (a down node or full device must not
@@ -426,15 +438,10 @@ class PlacementPass:
             if cm.used.shape[0] == cm.capacity.shape[0] else cm.used.copy()
         for sr in stops:
             a = sr.alloc
-            self.stopped_ids.add(a.id)
             row = cm.row_of.get(a.node_id)
             if row is not None:
                 allocs_leave(self.used, row, (a,), self.freed_ports,
                              deltas=self.deltas)
-        for a in all_allocs:
-            if a.id in self.stopped_ids or a.terminal_status():
-                continue
-            self.allocs_by_tg.setdefault(a.task_group, []).append(a)
         for pr in places:
             if pr.is_rescheduling and pr.previous_alloc is not None:
                 self.penalty_nodes.setdefault(pr.task_group, set()).add(
@@ -445,14 +452,19 @@ class PlacementPass:
         preplaced: List[Tuple[PlacementRequest, int]] = []
         rest: List[PlacementRequest] = []
         for pr in places:
-            g = groups[self.tg_index[pr.task_group]]
+            gi = self.tg_index[pr.task_group]
+            g = groups[gi]
             if (g.tg.ephemeral_disk.sticky and pr.previous_alloc is not None
                     and not pr.is_rescheduling):
                 row = cm.row_of.get(pr.previous_alloc.node_id)
                 if row is not None and g.feasible[row] \
+                        and self.distinct.allows(gi, row) \
                         and np.all(used[row] + g.demand <= cm.capacity[row]):
                     used[row] += g.demand
                     self.deltas.append((row, g.demand.astype(np.float32)))
+                    # taken now, not when it materialises: the slots
+                    # after it and the kernel's pass have to see it
+                    self.distinct.take(gi, row)
                     preplaced.append((pr, row))
                     continue
             rest.append(pr)
@@ -545,7 +557,7 @@ class PlacementPass:
                 self.job, self.groups,
                 [self.tg_index[pr.task_group] for pr in prs],
                 self.allocs_by_tg, penalty_nodes=self.penalty_nodes,
-                used_override=self.used)
+                used_override=self.used, distinct=self.distinct)
         result, ticket = self.eng.place(
             self.cm, inputs, self.deltas,
             spread_algorithm=self.stack.spread_algorithm)
@@ -602,7 +614,7 @@ class PlacementPass:
         self.now = _time.time()
         with tracing.span("sched.materialise", cpu=True):
             for pr, row in preplaced:
-                self._place(pr, row, AllocMetric())
+                self._place(pr, row, AllocMetric(), held=True)
             for group_placed in placed:
                 self._materialise_bulk(*group_placed)
             for n_round, prs in enumerate(rounds):
@@ -658,9 +670,13 @@ class PlacementPass:
 
     def _materialise_round(self, prs, result, one_by_one: bool) -> None:
         cm, used, metric_for = self.cm, self.used, self.metric_for
+        stats = self.eng.stats
         for i, pr in enumerate(prs):
             row = int(result.node[i])
+            binds = bool(self.distinct.binds[self.tg_index[pr.task_group]])
+            stats["distinct_slots"] += binds
             if row < 0:
+                stats["distinct_unplaced"] += binds
                 if not self.try_preempt(pr, result, prs, i):
                     self.fail(pr, metric_for(result, prs, i), "exhausted")
                 continue
@@ -679,6 +695,14 @@ class PlacementPass:
         cm = self.cm
         m = AllocMetric(nodes_evaluated=int(result.nodes_evaluated[i]),
                         nodes_exhausted=int(result.nodes_exhausted[i]))
+        gi = self.tg_index[prs[i].task_group]
+        if int(result.node[i]) < 0 and self.distinct.binds[gi]:
+            # what distinct_* closed to the slot, as the upstream's
+            # iterators record it: the carry stands where the kernel's
+            # stood when it reached this slot
+            m.constraint_filtered = self.distinct.filtered(
+                gi, self.groups[gi].feasible)
+            m.nodes_filtered = sum(m.constraint_filtered.values())
         entries = []
         for k in range(result.top_nodes.shape[1]):
             r = int(result.top_nodes[i, k])
@@ -704,22 +728,24 @@ class PlacementPass:
 
     # ------------------------------------------------- one slot on one row
 
-    def _place(self, pr, row, metric, alt_rows=None) -> Optional[Allocation]:
+    def _place(self, pr, row, metric, alt_rows=None,
+               held: bool = False) -> Optional[Allocation]:
         """place_on for a slot that evicts nothing by plan: what device
         preemption inside it evicts all the same still frees usage, and
         must not be chosen again by later slots."""
         extra: List[Allocation] = []
         alloc = self.place_on(pr, row, metric, preempted=extra,
-                              alt_rows=alt_rows)
+                              alt_rows=alt_rows, held=held)
         allocs_leave(self.used, row, extra, self.freed_ports, self.preemptor)
         return alloc
 
     def place_on(self, pr: PlacementRequest, row: int, metric: AllocMetric,
-                 preempted=None, extra_freed=None,
-                 alt_rows=None) -> Optional[Allocation]:
+                 preempted=None, extra_freed=None, alt_rows=None,
+                 held: bool = False) -> Optional[Allocation]:
         """The allocation placed (on `row`, or for a device ask on
         the first of `alt_rows` that can grant instances), or None
-        with the failure recorded."""
+        with the failure recorded.  `held`: the slot took the row in
+        the distinct_* carry already (a sticky slot, in prepare)."""
         gi = self.tg_index[pr.task_group]
         g, stats = self.groups[gi], self.eng.stats
         node = self.state.node_by_id(self.cm.node_ids[row])
@@ -727,8 +753,13 @@ class PlacementPass:
         # assign_devices must stay visible to the caller for
         # usage/invalidate bookkeeping
         preempted = preempted if preempted is not None else []
-        devices = self.assign_devices(gi, node, preempted) \
-            if node is not None else {}
+        # a row the kernel chose is open unless the host has since put
+        # an earlier slot elsewhere than the kernel did (a device
+        # alternative): then this slot looks for an alternative too
+        devices = None
+        if held or self.distinct.allows(gi, row):
+            devices = self.assign_devices(gi, node, preempted) \
+                if node is not None else {}
         if g.device_asks:
             stats["device_placements"] += 1
             stats["device_fallbacks"] += devices is None
@@ -736,9 +767,11 @@ class PlacementPass:
             found = self._next_best_with_devices(gi, row, alt_rows,
                                                  preempted)
             if found is None:
-                self.fail(pr, metric, "devices exhausted")
+                self.fail(pr, metric, "devices exhausted" if g.device_asks
+                          else "exhausted")
                 return None
             row, node, devices = found
+            held = False
         freed = set(self.freed_ports.get(row, ()))
         if extra_freed:
             freed |= extra_freed
@@ -756,6 +789,8 @@ class PlacementPass:
         if alloc is None:
             self.fail(pr, metric, "ports exhausted")
             return None
+        if not held:
+            self.distinct.take(gi, row)
         if pr.previous_alloc is not None:
             pr.previous_alloc.next_allocation = alloc.id
         if preempted:
@@ -786,6 +821,7 @@ class PlacementPass:
             alt = int(alt)
             if alt < 0 or alt == row or not cm.node_ids[alt] \
                     or not g.feasible[alt] \
+                    or not self.distinct.allows(gi, alt) \
                     or not np.all(used[alt] + g.demand <= cm.capacity[alt]):
                 continue
             node = self.state.node_by_id(cm.node_ids[alt])
@@ -862,12 +898,20 @@ class PlacementPass:
         if not cache:
             # one find round serves a batch of failed slots (each
             # find rebuilds the per-node candidate tensors)
+            # among the rows distinct_* leaves the group now; one found
+            # earlier may have closed since (another slot took its host,
+            # or filled its value), so each is asked again as it is used
+            masks = [g.feasible, g.feasible_pre_ports, g.device_blocked]
+            if self.distinct.binds[gi]:
+                open_rows = self.distinct.open_rows(gi)
+                masks = [m if m is None else m & open_rows for m in masks]
             with tracing.span("sched.preempt_find"):
                 cache.extend(preemptor.find_many(
-                    g.feasible, g.demand, self.used, 64,
+                    masks[0], g.demand, self.used, 64,
                     static_ports=g.static_ports,
-                    feasible_pre_ports=g.feasible_pre_ports,
-                    device_blocked=g.device_blocked))
+                    feasible_pre_ports=masks[1], device_blocked=masks[2]))
+        while cache and not self.distinct.allows(gi, cache[0].row):
+            cache.pop(0)
         if not cache:
             return False
         found = cache.pop(0)

@@ -69,7 +69,7 @@ def group_dynamic_port_count(tg: TaskGroup) -> int:
 class CompiledGroup:
     """Per-task-group dense artifacts."""
     tg: TaskGroup
-    feasible: np.ndarray          # bool[N] static part (no distinct_* yet)
+    feasible: np.ndarray          # bool[N] static part (no distinct_*: DistinctCarry)
     affinity: np.ndarray          # f32[N]
     has_affinity: bool
     demand: np.ndarray            # f32[R]
@@ -112,6 +112,126 @@ class CompiledGroup:
                     or self.distinct_hosts_tg or self.distinct_property
                     or self.static_ports or self.dynamic_ports
                     or self.device_asks)
+
+
+class DistinctCarry:
+    """distinct_hosts and distinct_property of one eval (feasible.go
+    DistinctHostsIterator and DistinctPropertyIterator, propertyset.go),
+    started from the job's existing allocations: what the kernel's carry
+    starts from (`inputs`), and its twin on the host, which takes every
+    placement the plan makes (`take`) and is asked about every row the
+    host picks itself (`allows`, `open_rows`).  A job-level constraint is
+    one scope that every group checks and marks (it collides with any
+    allocation of the job), a group-level one is its group's own.  A
+    node without a property's attribute takes none; a limit left out is
+    1.  Shapes are those of `PlaceInputs`; a job with neither constraint
+    has no scope and every array is empty."""
+
+    def __init__(self, cm: ClusterMatrix, groups: Sequence[CompiledGroup],
+                 allocs_by_tg: Dict[str, List]):
+        N, G = cm.n_rows, len(groups)
+        # scope -> the indices of its groups (None: the job's, all of them)
+        hosts: List[Optional[int]] = []
+        props: List[Tuple[str, int, Optional[int]]] = []
+        if any(g.distinct_hosts_job for g in groups):
+            hosts.append(None)
+        else:
+            hosts += [gi for gi, g in enumerate(groups) if g.distinct_hosts_tg]
+        for gi, g in enumerate(groups):
+            for target, limit, job_level in g.distinct_property:
+                scope = (target, limit, None if job_level else gi)
+                if scope not in props:
+                    props.append(scope)
+        self.hosts_taken = np.zeros((len(hosts), N), bool)
+        self.hosts_of = np.zeros((G, len(hosts)), bool)
+        self.prop_vidx = np.zeros((len(props), N), np.int32)
+        self.prop_limit = np.array([limit for _t, limit, _g in props],
+                                   np.int32)
+        self.prop_of = np.zeros((G, len(props)), bool)
+        self.prop_counts = np.zeros((len(props), 1), np.int32)
+        # bool[G]: the groups either constraint binds; the others are
+        # asked and marked at no cost
+        self.binds = np.zeros(G, bool)
+        if not hosts and not props:
+            return
+        with tracing.span("sched.distinct_inputs", cpu=True):
+            def rows_of(scope: Optional[int]) -> np.ndarray:
+                names = allocs_by_tg if scope is None \
+                    else (groups[scope].tg.name,)
+                rows = [cm.row_of.get(a.node_id) for name in names
+                        for a in allocs_by_tg.get(name, ())]
+                return np.array([r for r in rows if r is not None], np.int64)
+
+            for h, scope in enumerate(hosts):
+                self.hosts_of[:, h] = True if scope is None \
+                    else np.arange(G) == scope
+                self.hosts_taken[h, rows_of(scope)] = True
+            ordinals = []
+            for target, _limit, _scope in props:
+                col_name = AttrTable.target_to_column(target)
+                col = cm.attrs.columns.get(col_name) if col_name else None
+                ordinals.append(np.full(N, -1, np.int32) if col is None
+                                else col.ordinals())
+            W = max([int(o.max(initial=-1)) + 1 for o in ordinals] + [0])
+            self.prop_counts = np.zeros((len(props), W + 1), np.int32)
+            for p, (ords, (_t, _limit, scope)) in enumerate(
+                    zip(ordinals, props)):
+                self.prop_of[:, p] = True if scope is None \
+                    else np.arange(G) == scope
+                self.prop_vidx[p] = np.where(ords < 0, W, ords)
+                np.add.at(self.prop_counts[p],
+                          self.prop_vidx[p, rows_of(scope)], 1)
+            self.prop_counts[:, W] = 0
+            self.binds = self.hosts_of.any(axis=1) | self.prop_of.any(axis=1)
+
+    def inputs(self) -> Dict[str, np.ndarray]:
+        """The `PlaceInputs` fields, as they stand now (copies: the
+        engine keeps what it is handed)."""
+        return dict(hosts_taken=self.hosts_taken.copy(),
+                    hosts_of=self.hosts_of, prop_vidx=self.prop_vidx,
+                    prop_counts=self.prop_counts.copy(),
+                    prop_limit=self.prop_limit, prop_of=self.prop_of)
+
+    def _closed(self, gi: int, rows) -> Tuple[np.ndarray, np.ndarray]:
+        """(bool[...] `rows` a host scope of the group closes, those a
+        property of the group closes): ops.place.distinct_open on the host."""
+        by_host = self.hosts_taken[self.hosts_of[gi]][:, rows].any(axis=0)
+        ps = np.flatnonzero(self.prop_of[gi])
+        v = self.prop_vidx[ps][:, rows]
+        W = self.prop_counts.shape[1] - 1
+        full = (v >= W) | (self.prop_counts[ps[:, None], v]
+                           >= self.prop_limit[ps, None])
+        return by_host, full.any(axis=0)
+
+    def open_rows(self, gi: int) -> np.ndarray:
+        """bool[N]: the rows the group's constraints leave open."""
+        by_host, by_prop = self._closed(gi, slice(None))
+        return ~(by_host | by_prop)
+
+    def allows(self, gi: int, row: int) -> bool:
+        if not self.binds[gi]:
+            return True
+        by_host, by_prop = self._closed(gi, np.array([row]))
+        return not (by_host[0] or by_prop[0])
+
+    def take(self, gi: int, row: int) -> None:
+        """One allocation of group `gi` goes to `row`."""
+        if not self.binds[gi]:
+            return
+        self.hosts_taken[self.hosts_of[gi], row] = True
+        ps = np.flatnonzero(self.prop_of[gi])
+        v = self.prop_vidx[ps, row]
+        held = v < self.prop_counts.shape[1] - 1
+        self.prop_counts[ps[held], v[held]] += 1
+
+    def filtered(self, gi: int, feasible: np.ndarray) -> Dict[str, int]:
+        """How many of the `feasible` rows each constraint closes to the
+        group now, under the names the upstream's iterators filter by
+        (hosts first, as its chain has them)."""
+        by_host, by_prop = self._closed(gi, slice(None))
+        out = {"distinct_hosts": int((feasible & by_host).sum()),
+               "distinct_property": int((feasible & ~by_host & by_prop).sum())}
+        return {k: n for k, n in out.items() if n}
 
 
 class DenseStack:
@@ -159,7 +279,11 @@ class DenseStack:
             (c.ltarget, int(c.rtarget) if c.rtarget else 1, c in job_constraints)
             for c in constraints if c.operand == Operand.DISTINCT_PROPERTY]
 
-        static = fz.constraints_mask(cm, constraints)
+        if fz.more_than_an_equality(constraints):
+            with tracing.span("sched.constraint_mask", cpu=True):
+                static = fz.constraints_mask(cm, constraints)
+        else:
+            static = fz.constraints_mask(cm, constraints)
         static &= fz.driver_mask(cm, drivers)
         static &= fz.host_volume_mask(cm, tg.volumes)
         class_feasible = cm.dc_mask(job.datacenters) & static
@@ -248,6 +372,7 @@ class DenseStack:
         allocs_by_tg: Dict[str, List],             # existing (non-terminal) job allocs
         penalty_nodes: Optional[Dict[str, set]] = None,   # tg name -> node ids
         used_override: Optional[np.ndarray] = None,
+        distinct: Optional[DistinctCarry] = None,   # default: from the allocs
     ) -> PlaceInputs:
         cm = self.cm
         N = cm.n_rows
@@ -301,34 +426,6 @@ class DenseStack:
                 row = cm.row_of.get(a.node_id)
                 if row is not None:
                     tg_count[gi, row] += 1
-            # distinct_hosts: co-hosted nodes infeasible (feasible.go:523-620);
-            # job-level collides with any job alloc, group-level with same group
-            if g.distinct_hosts_job or g.distinct_hosts_tg:
-                for tg_name, allocs in allocs_by_tg.items():
-                    if not g.distinct_hosts_job and tg_name != g.tg.name:
-                        continue
-                    for a in allocs:
-                        row = cm.row_of.get(a.node_id)
-                        if row is not None:
-                            feas[gi, row] = False
-            # distinct_property: value counts >= limit infeasible (propertyset.go)
-            for target, limit, job_level in g.distinct_property:
-                col_name = AttrTable.target_to_column(target)
-                col = cm.attrs.columns.get(col_name) if col_name else None
-                if col is None:
-                    continue
-                counts: Dict[str, int] = {}
-                for tg_name, allocs in allocs_by_tg.items():
-                    if not job_level and tg_name != g.tg.name:
-                        continue
-                    for a in allocs:
-                        row = cm.row_of.get(a.node_id)
-                        if row is not None and col.values[row] is not None:
-                            counts[col.values[row]] = counts.get(col.values[row], 0) + 1
-                for row in range(N):
-                    v = col.values[row]
-                    if v is not None and counts.get(v, 0) >= limit:
-                        feas[gi, row] = False
 
             sum_w = sum(sp.weight for sp, _, _ in spread_specs[gi]) or 1
             for ki, (sp, col, values) in enumerate(spread_specs[gi]):
@@ -382,6 +479,11 @@ class DenseStack:
             slot_tg[si] = gi
             slot_active[si] = True
 
+        # distinct_*: the existing allocations (and what the host has
+        # placed of this eval) are where the kernel's carry starts
+        if distinct is None:
+            distinct = DistinctCarry(cm, groups, allocs_by_tg)
+
         used = used_override if used_override is not None else self.cm.used
         return PlaceInputs(
             capacity=np.ascontiguousarray(cm.capacity),
@@ -391,5 +493,5 @@ class DenseStack:
             spread_vidx=vidx, spread_desired=sdesired, spread_targeted=stargeted,
             spread_wfrac=swfrac, spread_counts=scounts, spread_active=sactive,
             place_cap=place_cap, dev_score=dev_score, has_dev=has_dev,
-            demand=demand, slot_tg=slot_tg, slot_active=slot_active,
+            **distinct.inputs(), demand=demand, slot_tg=slot_tg, slot_active=slot_active,
         )

@@ -92,13 +92,22 @@ def test_tg_level_distinct_hosts_scoped_to_group():
     groups = [st.compile_group(j, tg) for tg in j.task_groups]
     b_alloc = mock.alloc_for(j, node.id)
     b_alloc.task_group = "b"
+    from nomad_tpu.ops.place import distinct_open
+
+    def open_to_web(inp):
+        # the existing allocations are where the kernel's carry starts
+        # (PlaceInputs.hosts_taken), not a mask over `feasible`
+        assert inp.feasible[0, cm.row_of[node.id]]
+        return bool(distinct_open(inp, 0, inp.hosts_taken,
+                                  inp.prop_counts)[cm.row_of[node.id]])
+
     inp = st.build_inputs(j, groups, [0], {"b": [b_alloc]})
     # a group-level constraint on "web" must not collide with "b"'s alloc
-    assert inp.feasible[0, cm.row_of[node.id]]
+    assert open_to_web(inp)
     # but a job-level one must
     j2 = mock.job()
     j2.task_groups.append(TaskGroup(name="b", count=1, tasks=[Task(name="b", driver="exec")]))
     j2.constraints.append(Constraint(operand=Operand.DISTINCT_HOSTS))
     groups2 = [st.compile_group(j2, tg) for tg in j2.task_groups]
     inp2 = st.build_inputs(j2, groups2, [0], {"b": [b_alloc]})
-    assert not inp2.feasible[0, cm.row_of[node.id]]
+    assert not open_to_web(inp2)
