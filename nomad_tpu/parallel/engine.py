@@ -325,7 +325,11 @@ class PlacementEngine:
         # ONE bulk dispatch is held in flight while the next part is
         # prepared and dispatched against the adopted carry, which is
         # what makes the in-flight placements visible to the chained
-        # dispatch without a resolve barrier
+        # dispatch without a resolve barrier.  The carry is the only
+        # place they live until the resolve (no ticket, not in the
+        # world's host snapshot), so the chained part's update() ADDS
+        # what commits and releases changed on the host to the carry's
+        # rows and never sets a row (world.update force_scatter)
         self._pending: Optional[_PendingBulk] = None
         self._serving_mesh = None
         self._mesh_checked = False
@@ -924,12 +928,14 @@ class PlacementEngine:
                 # upload/compute overlap: the previous bulk dispatch may
                 # still be computing.  Chaining behind it is sound ONLY
                 # when this part scores against the same world via the
-                # adopted donated carry (which already holds the
-                # in-flight placements) and update() can proceed by
-                # dirty-row scatter — a full upload from the host
-                # snapshot would erase those placements, and chaos
-                # injection may force exactly that, so both bail to a
-                # drain-first barrier.
+                # adopted donated carry, which holds the in-flight
+                # placements and is the one place that does: update()
+                # keeps them by ADDING each dirty row's host change to
+                # the carry (force_scatter).  Setting a row, or a full
+                # upload from the host snapshot, would put the host's
+                # value where they are — a new epoch does the one and
+                # chaos injection may force the other, so both bail to
+                # a drain-first barrier.
                 chained = (chaos.active is None
                            and self._pending is not None
                            and self._pending.world is world

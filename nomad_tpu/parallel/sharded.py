@@ -367,6 +367,13 @@ def _set_rows_local(dev, rows, vals):
     return dev.at[lrows].set(vals, mode="drop")
 
 
+def _add_rows_local(dev, rows, vals):
+    """Shard-local row ADD, `_set_rows_local`'s twin for a matrix that
+    holds more than the host knows (DeviceWorld's chained update)."""
+    return _apply_deltas_local(
+        dev, rows, vals, jax.lax.axis_index(NODE_AXIS_NAME) * dev.shape[0])
+
+
 def _add_rank1_local(dev, rows, counts, demand):
     """Shard-local twin of the native scatter_add_rank1 export:
     dev[rows[k]] += counts[k] * demand, rows translated per shard."""
@@ -379,7 +386,7 @@ def _add_rank1_local(dev, rows, counts, demand):
 
 
 def serving_update_fns(mesh: Mesh):
-    """Jitted (set_rows, add_rank1) scatter pair for a node-sharded
+    """Jitted (set_rows, add_rank1, add_rows) scatters for a node-sharded
     [N, R] resident matrix (parallel.world.DeviceWorld).  Rows/values are
     replicated operands (KBs); the sharded matrix never moves — each
     shard scatters its own rows, no cross-device gather of the operand."""
@@ -395,9 +402,14 @@ def serving_update_fns(mesh: Mesh):
             _add_rank1_local, mesh=mesh,
             in_specs=(P(NS, None), P(None), P(None), P(None)),
             out_specs=P(NS, None), check_vma=False))
+        add_rows_fn = jax.jit(jax.shard_map(
+            _add_rows_local, mesh=mesh,
+            in_specs=(P(NS, None), P(None), P(None, None)),
+            out_specs=P(NS, None), check_vma=False))
         recompile.register("sharded.serving_set", set_fn)
         recompile.register("sharded.serving_add", add_fn)
-        fns = (set_fn, add_fn)
+        recompile.register("sharded.serving_add_rows", add_rows_fn)
+        fns = (set_fn, add_fn, add_rows_fn)
         _SERVING_FN_CACHE[key] = fns
     return fns
 

@@ -20,6 +20,10 @@ dirty-row scatters:
   snapshot shipped last time and scatters only the changed rows
   (bucketed pad so the row count never forks an XLA compile variant;
   >25% churn or a shape change falls back to one full device_put).
+  Under a pending donated dispatch (`force_scatter`) the resident basis
+  holds placements the host does not know yet, so a changed row ships
+  its CHANGE (`host[row] - last[row]`) and the device adds it: the
+  row's in-flight placements survive (`chained_rows_added`).
 - `apply_rank1(rows, counts, demand)` is the commit/overlay hand-off
   twin of the native `scatter_add_rank1` export: the same rank-1
   update lands in the host snapshot (native scatter) and in the device
@@ -77,12 +81,13 @@ from nomad_tpu.parallel.sharded import mesh_key  # noqa: E402,F401
 
 _set_rows_fn = None
 _add_rank1_fn = None
+_add_rows_fn = None
 
 
 def _single_device_fns():
-    """Jitted (set_rows, add_rank1) scatter pair for the unsharded world
-    (rows == N pad slots drop)."""
-    global _set_rows_fn, _add_rank1_fn
+    """Jitted (set_rows, add_rank1, add_rows) scatters for the unsharded
+    world (rows == N pad slots drop)."""
+    global _set_rows_fn, _add_rank1_fn, _add_rows_fn
     if _set_rows_fn is None:
         import jax
         import jax.numpy as jnp
@@ -91,33 +96,43 @@ def _single_device_fns():
         _add_rank1_fn = jax.jit(
             lambda d, r, c, dem: d.at[r].add(
                 c[:, None].astype(jnp.float32) * dem, mode="drop"))
+        _add_rows_fn = jax.jit(
+            lambda d, r, v: d.at[r].add(v, mode="drop"))
         recompile.register("world.set_rows", _set_rows_fn)
         recompile.register("world.add_rank1", _add_rank1_fn)
-    return _set_rows_fn, _add_rank1_fn
+        recompile.register("world.add_rows", _add_rows_fn)
+    return _set_rows_fn, _add_rank1_fn, _add_rows_fn
+
+
+def _scatter_fns(mesh):
+    """(set_rows, add_rank1, add_rows) for a world resident on `mesh`
+    (None: one device)."""
+    if mesh is None:
+        return _single_device_fns()
+    from nomad_tpu.parallel.sharded import serving_update_fns
+    return serving_update_fns(mesh)
 
 
 def warm_scatter(shape: tuple, mesh=None) -> None:
-    """Compile the row-scatter kernel for a world of `shape` (N, R) and
-    every ROW_BUCKET before a measured window.  The first dirty-row
-    update of an epoch otherwise pays its bucket's XLA compile inside
-    the steady state (the recompile gate flags it).  Dispatches are
-    pad-only no-ops — every row index is N, so `mode="drop"` discards
-    them — against a throwaway zero world, never a resident one."""
+    """Compile the row scatters (set, and the chained dispatch's add)
+    for a world of `shape` (N, R) and every ROW_BUCKET before a measured
+    window.  The first dirty-row update of an epoch otherwise pays its
+    bucket's XLA compile inside the steady state (the recompile gate
+    flags it).  Dispatches are pad-only no-ops — every row index is N,
+    so `mode="drop"` discards them — against a throwaway zero world,
+    never a resident one."""
     import jax
 
     N, R = shape
     w = DeviceWorld(mesh)
     dev = w._put_full(np.zeros((N, R), np.float32))
-    if mesh is None:
-        set_fn, _ = _single_device_fns()
-    else:
-        from nomad_tpu.parallel.sharded import serving_update_fns
-        set_fn, _ = serving_update_fns(mesh)
+    set_fn, _, add_fn = _scatter_fns(mesh)
     for b in ROW_BUCKETS:
         rows = np.full(b, N, np.int32)
         vals = np.zeros((b, R), np.float32)
         rows_dev, vals_dev = w._put_operands(rows, vals)
         jax.block_until_ready(set_fn(dev, rows_dev, vals_dev))
+        jax.block_until_ready(add_fn(dev, rows_dev, vals_dev))
 
 
 class DeviceWorld:
@@ -146,6 +161,10 @@ class DeviceWorld:
                       # fallback or injected device loss): the bench's
                       # steady-state gate asserts this stays 0
                       "steady_reuploads": 0,
+                      # rows brought up to date by ADDING the host's
+                      # change under a pending donated dispatch (a
+                      # subset of rows_scattered)
+                      "chained_rows_added": 0,
                       # donated-carry lifecycle (loan_basis/adopt_basis)
                       "basis_loans": 0, "basis_adopts": 0}
 
@@ -185,75 +204,64 @@ class DeviceWorld:
                                             P(*([None] * a.ndim))))
             for a in arrays)
 
-    def _set_rows(self, dev, rows: np.ndarray, vals: np.ndarray):
-        rows_dev, vals_dev = self._put_operands(rows, vals)
-        if self.mesh is None:
-            fn, _ = _single_device_fns()
-            return fn(dev, rows_dev, vals_dev)
-        from nomad_tpu.parallel.sharded import serving_update_fns
-        fn, _ = serving_update_fns(self.mesh)
-        return fn(dev, rows_dev, vals_dev)
-
     def _update_one(self, host: np.ndarray, last: Optional[np.ndarray],
                     dev, force_scatter: bool = False
                     ) -> Tuple[np.ndarray, object, bool]:
         """Sync one matrix; returns (new snapshot, new device array,
         full-upload?).  Caller holds self.lock.  `force_scatter` is the
         chained-dispatch (donated-carry pipeline) discipline: the device
-        array holds in-flight placements the host snapshot lacks, so a
-        full upload would silently erase them — large churn scatters in
-        bucket-sized chunks instead of falling back."""
+        array is the snapshot PLUS in-flight placements the host lacks
+        until the pending dispatch resolves.  Neither a full upload nor
+        a row set may touch it — both would put the host's value where
+        those placements are — so every dirty row ships
+        `host[row] - last[row]` and the device adds it, in bucket-sized
+        chunks however large the churn.  Usage is whole MHz / MB in
+        float32, so the sum is exact: once the pending dispatch has
+        resolved (apply_rank1_host) device and snapshot agree bit for
+        bit."""
         if chaos.active is not None and \
                 chaos.active.should("world.scatter_fail"):
             # injected device loss: forget what shipped so this update
             # falls through to one full re-upload (deterministic
             # recovery, nothing raises mid-dispatch)
             last, dev = None, None
-        N = host.shape[0]
-        B = None
-        changed = None
-        if last is not None and last.shape == host.shape and \
-                dev is not None:
-            changed = np.nonzero(np.any(last != host, axis=1))[0]
-            if changed.size == 0:
-                self.stats["clean_hits"] += 1
-                return last, dev, False
-            if changed.size <= N // 4 or force_scatter:
-                B = next((b for b in ROW_BUCKETS if b >= changed.size),
-                         None)
-            if B is None and force_scatter:
-                # churn beyond the largest bucket: chunked bucket
-                # scatters (every chunk a warmed compile variant)
-                Bmax = ROW_BUCKETS[-1]
-                changed_vals = np.array(host[changed], dtype=np.float32)
-                snap = last.copy()
-                snap[changed] = changed_vals
-                for off in range(0, changed.size, Bmax):
-                    cr = changed[off:off + Bmax]
-                    cv = changed_vals[off:off + Bmax]
-                    b = next(b for b in ROW_BUCKETS if b >= cr.size)
-                    rows = np.full(b, N, np.int32)
-                    rows[:cr.size] = cr
-                    vals = np.zeros((b, host.shape[1]), np.float32)
-                    vals[:cr.size] = cv
-                    dev = self._set_rows(dev, rows, vals)
-                self.stats["rows_scattered"] += int(changed.size)
-                return snap, dev, False
-        if B is None:
+        if last is None or last.shape != host.shape or dev is None:
+            snap = np.array(host, dtype=np.float32)
+            return snap, self._put_full(snap), True
+        N, R = host.shape
+        Bmax = ROW_BUCKETS[-1]
+        changed = np.nonzero(np.any(last != host, axis=1))[0]
+        if changed.size == 0:
+            self.stats["clean_hits"] += 1
+            return last, dev, False
+        if not force_scatter and changed.size > min(N // 4, Bmax):
             snap = np.array(host, dtype=np.float32)
             return snap, self._put_full(snap), True
         # read the dirty rows ONCE: `host` may be live (node churn mutates
         # it concurrently) and the snapshot must equal what shipped, not
         # what the row holds a moment later
         changed_vals = np.array(host[changed], dtype=np.float32)
-        rows = np.full(B, N, np.int32)           # pad slots drop
-        rows[:changed.size] = changed
-        vals = np.zeros((B, host.shape[1]), np.float32)
-        vals[:changed.size] = changed_vals
+        set_fn, _, add_fn = _scatter_fns(self.mesh)
+        if force_scatter:
+            fn, ship = add_fn, changed_vals - last[changed]
+            self.stats["chained_rows_added"] += int(changed.size)
+        else:
+            fn, ship = set_fn, changed_vals
+        # chunks past the largest bucket (chained churn only): every
+        # chunk a warmed compile variant
+        for off in range(0, changed.size, Bmax):
+            cr = changed[off:off + Bmax]
+            b = next(b for b in ROW_BUCKETS if b >= cr.size)
+            rows = np.full(b, N, np.int32)           # pad slots drop
+            rows[:cr.size] = cr
+            vals = np.zeros((b, R), np.float32)
+            vals[:cr.size] = ship[off:off + Bmax]
+            rows_dev, vals_dev = self._put_operands(rows, vals)
+            dev = fn(dev, rows_dev, vals_dev)
         snap = last.copy()
         snap[changed] = changed_vals
         self.stats["rows_scattered"] += int(changed.size)
-        return snap, self._set_rows(dev, rows, vals), False
+        return snap, dev, False
 
     # ------------------------------------------------------------- public
 
@@ -263,9 +271,10 @@ class DeviceWorld:
         returns (capacity_dev, basis_dev).  `capacity` may be the LIVE
         cm.capacity (it is snapshot-copied before any caching decision);
         `basis` must already be a private copy (engine._basis_for).
-        `force_scatter` (chained donated-carry dispatches only) forbids
-        the basis full-upload fallback: the resident basis carries
-        in-flight placements a host-snapshot upload would erase."""
+        `force_scatter` (chained donated-carry dispatches only) says the
+        resident basis carries in-flight placements the host lacks: the
+        basis is brought up to date by adding each dirty row's change,
+        never by a row set or a full upload, which would erase them."""
         with self.lock:
             shape = (capacity.shape, basis.shape)
             if shape != self.shape:              # new cluster epoch
@@ -372,11 +381,7 @@ class DeviceWorld:
                 return
             if self._basis_dev is None:
                 return                   # loaned out: next update ships
-            if self.mesh is None:
-                _, fn = _single_device_fns()
-            else:
-                from nomad_tpu.parallel.sharded import serving_update_fns
-                _, fn = serving_update_fns(self.mesh)
+            _, fn, _ = _scatter_fns(self.mesh)
             rows_dev, counts_dev, d_dev = self._put_operands(
                 rows, counts, d)
             self._basis_dev = fn(self._basis_dev, rows_dev, counts_dev,
