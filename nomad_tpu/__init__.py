@@ -11,9 +11,9 @@ hollowsunsets/nomad, surveyed in SURVEY.md) designed TPU-first:
   selection -> preemption; Nomad's RankIterator stack and structs.AllocsFit,
   reference scheduler/rank.go:193-551, structs/funcs.go:166-297) is a dense
   batched engine in `nomad_tpu.ops`: cluster state is encoded as fixed-shape
-  node x resource matrices (`nomad_tpu.encode`), and a single jitted
-  `lax.scan` places every task-group instance of an evaluation while vmapping
-  feasibility + scoring across all candidate nodes at once.
+  node x resource matrices (`nomad_tpu.encode`), and a single jitted loop
+  over the slots places every task-group instance of an evaluation while
+  vmapping feasibility + scoring across all candidate nodes at once.
 - Multi-chip scale-out shards the node axis and the evaluation batch over a
   `jax.sharding.Mesh` (`nomad_tpu.parallel`).
 """

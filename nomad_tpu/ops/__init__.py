@@ -5,8 +5,9 @@ Replaces the reference's per-node lazy iterator chain
 scheduler/select.go Limit/MaxScore) with batched fixed-shape kernels:
 
 - fit.py        vectorized AllocsFit + BestFit-v3 scoring over the node axis
-- place.py      the placement engine: lax.scan over placement slots with a
-                proposed-usage carry, scoring every node at every step
+- place.py      the placement engine: a loop over an eval's placement slots,
+                up to its last active one, with a proposed-usage carry,
+                scoring every node at every step
 - constraints.py device-side constraint-program evaluation over hashed
                 attribute code matrices (host numpy twin lives in
                 scheduler/feasible.py)

@@ -1,13 +1,15 @@
 """The dense placement engine.
 
-One jitted `lax.scan` places every missing allocation of an evaluation:
-each step scores ALL candidate nodes at once (feasibility mask -> resource
-fit -> binpack/spread fit score -> anti-affinity / reschedule-penalty /
-affinity / spread scoring -> normalization -> masked argmax) and the carry
-threads the proposed usage matrix, per-taskgroup co-placement counts,
-per-spread-attribute value counts and, for distinct_hosts and
-distinct_property, the rows a scope has taken and its allocations a
-value, so sequential placement coupling (reference
+One jitted loop over an evaluation's placement slots places every missing
+allocation (`_scan_slots`: it runs to the eval's last active slot, not to
+the end of the padded slot axis, and an eval with no active slot runs no
+step): each step scores ALL candidate nodes at once (feasibility mask ->
+resource fit -> binpack/spread fit score -> anti-affinity /
+reschedule-penalty / affinity / spread scoring -> normalization -> masked
+argmax) and the carry threads the proposed usage matrix, per-taskgroup
+co-placement counts, per-spread-attribute value counts and, for
+distinct_hosts and distinct_property, the rows a scope has taken and its
+allocations a value, so sequential placement coupling (reference
 scheduler/context.go:173-210 ProposedAllocs) is preserved.
 
 This single kernel replaces the reference's entire iterator stack for one
@@ -313,14 +315,45 @@ def unpack_outputs(packed: np.ndarray):
     return node, score, fit_s, n_eval, n_exh, top_n, top_s
 
 
+def _scan_slots(step, carry0, slot_active: jax.Array):
+    """`lax.scan(step, carry0, arange(S))` cut at the eval's last active
+    slot: the loop runs n = (index of the last active slot) + 1 steps, read
+    from `slot_active` on the device, so a 300-slot service in the 1,024
+    bucket runs 300 steps and an eval that only pads a batch runs none.
+    The rows above n hold what an inactive step writes (it leaves the
+    carry as it found it, every update being gated by `ok`, and emits no
+    node, zero scores and counts, and `lax.top_k` of an all -inf vector),
+    so carry and outputs are the full scan's, padded rows included.  The
+    bound is the last active index and not the count of active slots: an
+    inactive slot under it is stepped and masked, so callers need not pad
+    a prefix.  Returns (carry, the seven per-slot outputs at [S, ...])."""
+    S = slot_active.shape[0]
+    n = jnp.max(jnp.where(slot_active, jnp.arange(1, S + 1), 0), initial=0)
+    outs0 = (
+        jnp.full(S, -1, jnp.int32),
+        jnp.zeros(S, jnp.float32),
+        jnp.zeros(S, jnp.float32),
+        jnp.zeros(S, jnp.int32),
+        jnp.zeros(S, jnp.int32),
+        jnp.broadcast_to(jnp.arange(TOP_K, dtype=jnp.int32), (S, TOP_K)),
+        jnp.full((S, TOP_K), -jnp.inf, jnp.float32),
+    )
+
+    def body(i, c):
+        carry, outs = c
+        carry, out = step(carry, i)
+        return carry, tuple(o.at[i].set(v) for o, v in zip(outs, out))
+
+    return jax.lax.fori_loop(0, n, body, (carry0, outs0))
+
+
 @functools.partial(jax.jit, static_argnames=("spread_algorithm",))
 def place_eval_packed_jit(inp: PlaceInputs, spread_algorithm: bool = False):
     """Single-eval kernel with packed output: returns (f32[S, 5+2K]
     packed outputs, f32[N, R] final usage)."""
-    S = inp.demand.shape[0]
     step = functools.partial(_place_step, inp, spread_algorithm)
-    carry, outs = jax.lax.scan(step, place_carry0(inp, inp.used),
-                               jnp.arange(S))
+    carry, outs = _scan_slots(step, place_carry0(inp, inp.used),
+                              inp.slot_active)
     return _pack_outputs(*outs), carry[0]
 
 
@@ -328,10 +361,9 @@ def place_eval_packed_jit(inp: PlaceInputs, spread_algorithm: bool = False):
 def place_eval_jit(inp: PlaceInputs, spread_algorithm: bool = False) -> PlaceResult:
     """Place all slots of one evaluation.  Shapes are static; callers bucket
     N/G/S/K/V so the jit cache stays small."""
-    S = inp.demand.shape[0]
     step = functools.partial(_place_step, inp, spread_algorithm)
-    carry, outs = jax.lax.scan(step, place_carry0(inp, inp.used),
-                               jnp.arange(S))
+    carry, outs = _scan_slots(step, place_carry0(inp, inp.used),
+                              inp.slot_active)
     node, score, fit_s, n_eval, n_exh, top_n, top_s = outs
     return PlaceResult(node=node, score=score, fit_score=fit_s,
                        nodes_evaluated=n_eval, nodes_exhausted=n_exh,
@@ -442,8 +474,10 @@ def pack_light(inp: PlaceInputs, deltas, D: int,
     """Flatten one eval's slot tensors + sparse usage deltas.  `deltas` is
     [(row, f32[R])]; inactive delta slots encode row = N (dropped by the
     in-kernel scatter's mode='drop').  `S` pads the slot axis to a
-    canonical bucket (padded slots are inactive) so the engine's compile
-    variants stay fixed regardless of per-eval slot counts."""
+    canonical bucket so the engine's compile variants stay fixed
+    regardless of per-eval slot counts; the padded slots are inactive and
+    lie above the last active one, so the kernel does not step them
+    (`_scan_slots`)."""
     S_in, R = inp.demand.shape
     S = S_in if S is None else S
     N = inp.feasible.shape[1]
@@ -515,8 +549,10 @@ def place_batch_packed_jit(capacity: jax.Array,     # f32[N, R]
         inp = PlaceInputs(capacity=capacity, used=used, demand=demand,
                           slot_tg=slot_tg, slot_active=slot_active, **f)
         step = functools.partial(_place_step, inp, spread_algorithm)
-        carry, outs = jax.lax.scan(step, place_carry0(inp, used),
-                                   jnp.arange(S))
+        # each chained eval runs to its own last active slot; an inert
+        # pad eval (a light block of zeros) runs no step
+        carry, outs = _scan_slots(step, place_carry0(inp, used),
+                                  slot_active)
         return carry[0], _pack_outputs(*outs)
 
     used_final, packed = jax.lax.scan(eval_step, used0, (hstack, light))

@@ -82,6 +82,14 @@ def _s_bucket(n: int) -> int:
     return next((b for b in _S_BUCKETS if b >= n), pad_to_bucket(n))
 
 
+def _scan_bound(slot_active) -> int:
+    """Slot steps the scan kernel runs for one eval: the index of its
+    last active slot + 1 (the host's reading of `ops.place._scan_slots`'
+    bound)."""
+    on = np.flatnonzero(slot_active)
+    return int(on[-1]) + 1 if on.size else 0
+
+
 def _fold_overflow(basis: "np.ndarray", deltas):
     """Apply an oversized delta list directly into a PRIVATE basis copy
     (the fixed delta bucket cannot carry it without forking an XLA
@@ -288,12 +296,14 @@ class PlacementEngine:
     _RACE_TRACED = {"_overlays": "_overlay_lock"}
 
     # eval-axis compile buckets: lax.scan compile cost is E-independent
-    # (one While body), so buckets only bound padding waste — scan-path
-    # pad evals still run their S slot steps, bulk pads exit immediately.
-    # Bulk chains run longer (pads are free and each dispatch pays a
+    # (one While body), so buckets only bound padding waste, and a pad
+    # eval costs no slot step on either path: a scan-path pad has no
+    # active slot, so its slot loop runs 0 steps (ops.place._scan_slots;
+    # on a mesh the node-sharded scan still runs all S), and bulk pads
+    # exit immediately.  Bulk chains run longer (each dispatch pays a
     # fixed launch + fetch cost, so more evals per dispatch wins at
-    # C2M-1M rates); scan chains stay shorter (pad evals still scan S
-    # slots).
+    # C2M-1M rates); the scan buckets date from when a pad eval ran its
+    # S steps and have not been retuned since (ROADMAP S34).
     E_BUCKETS = (1, 8, 16, 48)
     BULK_E_BUCKETS = (1, 8, 16, 48, 128, 512)
 
@@ -360,6 +370,10 @@ class PlacementEngine:
                       # fused steady state holds parts == groups, and
                       # bench --smoke gates on the ratio
                       "bulk_groups": 0, "bulk_parts": 0,
+                      # how far the scan's bound engages: slot steps the
+                      # scan kernel ran (each eval to its last active
+                      # slot) over the E x S of its dispatches
+                      "scan_steps_run": 0, "scan_steps_bucket": 0,
                       # donated-carry / 2-D-mesh health: donated_carries
                       # counts dispatches whose basis was donated (the
                       # steady state holds this == bulk_parts),
@@ -1190,6 +1204,9 @@ class PlacementEngine:
                 mesh, cap_dev, basis_dev, fields_dev,
                 drows, dvals, spread_algorithm=reqs[0].spread_algorithm)
         self.stats["put_s"] += sp.seconds
+        # the node-sharded scan runs every slot of its bucket
+        self.stats["scan_steps_run"] += E * S
+        self.stats["scan_steps_bucket"] += E * S
         self.stats["sharded_evals"] = (
             self.stats.get("sharded_evals", 0) + len(reqs))
         return packed
@@ -1572,6 +1589,9 @@ class PlacementEngine:
                 heavy_dims(i0) + (S, D),
                 spread_algorithm=reqs[0].spread_algorithm)
         self.stats["put_s"] += sp.seconds
+        self.stats["scan_steps_run"] += sum(
+            _scan_bound(r.inputs.slot_active) for r in reqs)
+        self.stats["scan_steps_bucket"] += E * S
         return packed
 
 

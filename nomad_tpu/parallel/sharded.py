@@ -276,7 +276,13 @@ def _place_step_sharded(inp: PlaceInputs, spread_algorithm: bool,
 
 
 def _shard_body(inp: PlaceInputs, spread_algorithm: bool):
-    """Runs inside shard_map for one eval: scan over slots."""
+    """Runs inside shard_map for one eval: scan over slots.
+
+    The full `lax.scan` over the padded slot axis stays here, where the
+    one-device kernels cut theirs at the eval's last active slot
+    (`ops.place._scan_slots`): this body is vmapped over the lane's
+    evals, and a batched `while` runs every lane to the longest one with
+    a collective in every step (ROADMAP S33)."""
     idx = jax.lax.axis_index(NODE_AXIS_NAME)
     n_local = inp.used.shape[0]
     shard_offset = idx * n_local
@@ -469,6 +475,9 @@ def place_batch_sharded(mesh: Mesh, capacity, used0, fields: dict,
             S = inp.demand.shape[0]
             step = functools.partial(_place_step_sharded, inp,
                                      spread_algorithm, shard_offset)
+            # all S steps, pads and inert evals included: the bound of
+            # ops.place._scan_slots is not taken here yet (no benchmark
+            # cell runs the mesh; ROADMAP S33)
             carry, outs = jax.lax.scan(step, place_carry0(inp, used),
                                        jnp.arange(S))
             return carry[0], _pack_outputs(*outs)

@@ -365,3 +365,34 @@ def test_serving_reaches_two_kernel_families(warmed, form):
         eng.complete(t)
     assert {k: eng.stats[k] - before[k] for k in want} == want
     assert budget.violations() == []
+
+
+def test_scan_steps_counted_and_no_variant_added(warmed):
+    """`scan_steps_run` over `scan_steps_bucket` says how far the slot
+    loop's bound engages: each eval's last active slot + 1 against the
+    E x S of its dispatch.  The bound is read on the device from
+    `slot_active`, so another slot count is the same compiled kernel and
+    the registry is what it was."""
+    from nomad_tpu.analysis import recompile
+
+    eng, cm, _bulk, _grew = warmed
+    assert {k for k in recompile.cache_sizes() if k.startswith("place.")} \
+        == {"place.eval_packed", "place.eval", "place.batch_packed",
+            "place.bulk", "place.bulk_batch_donate"}
+    budget = recompile.Budget()
+
+    def steps(counts):
+        before = dict(eng.stats)
+        reqs = [_request(cm, count=c) for c in counts]
+        eng._dispatch(reqs)
+        for r in reqs:
+            res, ticket = r.future.result(timeout=60)
+            assert (res.node[:r.inputs.slot_active.sum()] >= 0).all()
+            eng.complete(ticket)
+        return tuple(eng.stats[k] - before[k]
+                     for k in ("scan_steps_run", "scan_steps_bucket"))
+
+    assert steps([5]) == (5, 16)
+    assert steps([3, 5, 2]) == (3 + 5 + 2, 8 * 16)
+    assert steps([7]) == (7, 16)
+    assert budget.violations() == []

@@ -1,10 +1,16 @@
 """Placement engine behavior tests (dense analog of scheduler/rank_test.go,
 feasible_test.go, spread_test.go cases)."""
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from nomad_tpu import mock
 from nomad_tpu.encode import ClusterMatrix
+from nomad_tpu.ops import place as P
 from nomad_tpu.parallel.engine import get_engine
 from nomad_tpu.scheduler.stack import DenseStack
 from nomad_tpu.structs.config import SchedulerConfiguration
@@ -205,3 +211,122 @@ def test_regex_constraint():
     j.constraints.append(Constraint("${node.unique.name}", "^web-", Operand.REGEX))
     res, _, _ = run_place(cm, j, count=1)
     assert res.node[0] == cm.row_of[a.id]
+
+
+# --- the slot loop's bound (ops.place._scan_slots) ------------------------
+#
+# The jitted entry points run an eval's slot loop to its last active slot.
+# They are held, byte for byte over the whole padded [S, 5 + 2 * TOP_K]
+# block and the final usage, to the loop they replaced: the full
+# `lax.scan` over arange(S), kept here and sharing nothing with the helper.
+
+
+@functools.partial(jax.jit, static_argnames=("spread_algorithm",))
+def _full_scan(inp, spread_algorithm=False):
+    S = inp.demand.shape[0]
+    carry, outs = jax.lax.scan(
+        functools.partial(P._place_step, inp, spread_algorithm),
+        P.place_carry0(inp, inp.used), jnp.arange(S))
+    return P._pack_outputs(*outs), carry[0]
+
+
+@pytest.fixture(scope="module")
+def bound_world():
+    cm = ClusterMatrix()
+    # room for a full 128 bucket under distinct_hosts (160 hosts) and
+    # distinct_property (16 racks of at most 10)
+    for i in range(160):
+        nd = mock.node()
+        nd.attributes["rack"] = f"r{i % 16}"
+        cm.upsert_node(nd)
+    return cm
+
+
+def _bound_inputs(cm, kind, S, active):
+    """One group's PlaceInputs with the slot axis at S: every slot
+    carries the group's demand, so an inactive one is a real ask switched
+    off."""
+    job = mock.job()
+    tg = job.task_groups[0]
+    if kind == "spread":
+        tg.spreads = [Spread("${attr.rack}", 100, ())]
+    elif kind == "distinct":
+        job.constraints.append(Constraint("", "", Operand.DISTINCT_HOSTS))
+        job.constraints.append(
+            Constraint("${attr.rack}", "10", Operand.DISTINCT_PROPERTY))
+    stack = DenseStack(cm)
+    groups = [stack.compile_group(job, tg)]
+    inp = stack.build_inputs(job, groups, [0], {})
+    return dataclasses.replace(inp, demand=np.tile(inp.demand[:1], (S, 1)),
+                               slot_tg=np.zeros(S, np.int32),
+                               slot_active=np.asarray(active, bool))
+
+
+def _active(S, n, layout):
+    on = np.arange(S) < n
+    if layout == "holes":
+        on[[1, n // 2]] = False
+    return on
+
+
+_BOUND_CASES = [
+    pytest.param(kind, S, n, layout, id=f"{kind}-S{S}-n{n}-{layout}")
+    for kind in ("plain", "spread", "distinct")
+    for S in (16, 128)
+    for n in (0, 1, S // 3, S - 1, S)
+    for layout in ("prefix", "holes")
+    if layout == "prefix" or n >= 3
+] + [pytest.param("spread", 1024, 300, "holes", id="spread-S1024-n300-holes")]
+
+
+@pytest.mark.parametrize("kind,S,n,layout", _BOUND_CASES)
+def test_bounded_slot_loop_is_the_full_scan(bound_world, kind, S, n, layout):
+    inp = _bound_inputs(bound_world, kind, S, _active(S, n, layout))
+    want_packed, want_used = jax.device_get(_full_scan(inp))
+    placed = want_packed[:, 0] >= 0
+    assert not placed[~np.asarray(inp.slot_active)].any()
+    if n:
+        assert placed[n - 1]        # the slot behind the holes is placed
+
+    packed, used = jax.device_get(P.place_eval_packed_jit(inp))
+    assert packed.tobytes() == want_packed.tobytes()
+    assert used.tobytes() == want_used.tobytes()
+
+    res = P.place_eval_jit(inp)
+    repacked = jax.device_get(P._pack_outputs(
+        res.node, res.score, res.fit_score, res.nodes_evaluated,
+        res.nodes_exhausted, res.top_nodes, res.top_scores))
+    assert repacked.tobytes() == want_packed.tobytes()
+    assert jax.device_get(res.used).tobytes() == want_used.tobytes()
+
+
+def test_batch_pads_run_no_step_and_chain_like_single_evals(bound_world):
+    """E = 8: three live evals and five inert pads (a light block of
+    zeros, as the engine pads) chain as three sequential single evals."""
+    S, D, E = 16, 4, 8
+    live = [_bound_inputs(bound_world, "spread", S, _active(S, n, "prefix"))
+            for n in (5, 1, 16)]
+    used = np.asarray(live[0].used, np.float32)
+    want = []
+    for inp in live:
+        packed, used = jax.device_get(_full_scan(
+            dataclasses.replace(inp, used=used)))
+        want.append(packed)
+    assert (np.concatenate(want)[:, 0] >= 0).sum() == 5 + 1 + 16
+
+    heavy = [jnp.asarray(P.pack_heavy(inp)) for inp in live]
+    lights = [P.pack_light(inp, [], D, S) for inp in live]
+    lights += [np.zeros_like(lights[0])] * (E - len(live))
+    packed, used_final = jax.device_get(P.place_batch_packed_jit(
+        jnp.asarray(live[0].capacity), jnp.asarray(live[0].used),
+        tuple(heavy + [heavy[0]] * (E - len(live))),
+        jnp.asarray(np.concatenate(lights)),
+        P.heavy_dims(live[0]) + (S, D)))
+    for e, w in enumerate(want):
+        assert packed[e].tobytes() == w.tobytes()
+    assert used_final.tobytes() == used.tobytes()
+    # a pad eval's rows are what S inactive steps write
+    inert, _ = jax.device_get(_full_scan(
+        _bound_inputs(bound_world, "spread", S, np.zeros(S, bool))))
+    for e in range(len(live), E):
+        assert packed[e].tobytes() == inert.tobytes()
